@@ -24,8 +24,9 @@ CHECKER = "host-sync"
 
 # callback-family primitive names across jax versions
 _CALLBACKS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "python_callback",
-    "callback", "host_callback_call", "outside_call", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "python_callback", "callback", "host_callback_call", "outside_call",
+    "infeed", "outfeed",
 })
 
 

@@ -18,7 +18,7 @@ from __future__ import annotations
 from typing import Iterator, List, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _as_jaxpr(obj):

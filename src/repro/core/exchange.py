@@ -168,7 +168,10 @@ def mix_exchange(X: Tree, noise_n: Tree, noise_m: Tree, c, eta, W, *,
     def one(x, n, m):
         xf = x.astype(jnp.float32)
         nf = n.astype(jnp.float32) / c
-        mixed = jnp.einsum("ij,j...->i...", Wj, xf + nf)
+        # full f32 on the MXU: n/c cancels against the self-correction
+        # term, which one bf16 pass (TPU's default) would not preserve
+        mixed = jnp.einsum("ij,j...->i...", Wj, xf + nf,
+                           precision=jax.lax.Precision.HIGHEST)
         selfs = _vec(self_scale, N, x.ndim)
         upd = mixed - xf - (nf if selfs is None else selfs * nf)
         if m is not None:

@@ -38,6 +38,7 @@ the speedup).
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -341,12 +342,16 @@ class ChunkRunner:
         self._body = body
         self._donate = donate
         self._cache = {}
+        # wall seconds of each length's FIRST call: trace + compile (or a
+        # persistent-cache load) + enqueue — the set-up cost a run pays
+        # once per chunk length
+        self.compile_seconds = {}
 
     def trace_counts(self):
         """{chunk_length: lifetime compilation count} over the cached scan
         programs — each distinct length legitimately compiles exactly once;
         any count above 1 is a retrace (obs.retrace_guard sums these)."""
-        return {k: fn._cache_size() for k, fn in self._cache.items()}
+        return {k: fn._cache_size() for k, (fn, _) in self._cache.items()}
 
     def program(self, k: int) -> Callable:
         """The un-jitted k-round chunk program ``carry -> (carry', ys)`` —
@@ -372,14 +377,49 @@ class ChunkRunner:
 
         return scan_k
 
+    def _jit(self, k: int, carry: TrajCarry):
+        """``(fn, arrays)``: the jitted k-round program taking the device
+        arrays the body closes over (the data store, simulator tables) as
+        arguments, ``fn(carry, arrays)``. Closed over, jit would embed them
+        as constants: a copy to the host at every compile, which an
+        accelerator's transfer guard refuses, and the whole dataset baked
+        into the program. Host (NumPy) constants stay embedded."""
+        from jax.extend.core import ClosedJaxpr, jaxpr_as_fun
+        closed, out_shape = jax.make_jaxpr(self.program(k),
+                                           return_shape=True)(carry)
+        on_dev = [isinstance(c, jax.Array) for c in closed.consts]
+        arrays = [c for c, d in zip(closed.consts, on_dev) if d]
+        host = [c for c, d in zip(closed.consts, on_dev) if not d]
+        out_tree = jax.tree_util.tree_structure(out_shape)
+
+        def program(c, dev):
+            dev, hst = iter(dev), iter(host)
+            consts = [next(dev) if d else next(hst) for d in on_dev]
+            outs = jaxpr_as_fun(ClosedJaxpr(closed.jaxpr, consts))(
+                *jax.tree_util.tree_leaves(c))
+            return jax.tree_util.tree_unflatten(out_tree, outs)
+
+        return (jax.jit(program, donate_argnums=(0,) if self._donate else ()),
+                arrays)
+
     def run(self, carry: TrajCarry, k: int) -> Tuple[TrajCarry, Any]:
         k = int(k)
-        fn = self._cache.get(k)
-        if fn is None:
-            fn = jax.jit(self.program(k),
-                         donate_argnums=(0,) if self._donate else ())
-            self._cache[k] = fn
-        return fn(carry)
+        if k not in self._cache:
+            t0 = time.perf_counter()
+            fn, arrays = self._cache[k] = self._jit(k, carry)
+            out = fn(carry, arrays)
+            self.compile_seconds[k] = time.perf_counter() - t0
+            return out
+        fn, arrays = self._cache[k]
+        return fn(carry, arrays)
+
+    def lowered_text(self, carry: TrajCarry, k: int) -> str:
+        """StableHLO text of the k-round program ``run`` dispatches, at
+        ``carry``'s shapes — where a Pallas kernel shows up as a
+        ``tpu_custom_call``. Lowering neither compiles nor counts in
+        trace_counts()."""
+        fn, arrays = self._cache[int(k)]
+        return fn.lower(carry, arrays).as_text()
 
 
 def run_per_round(body: Callable, carry: TrajCarry, k: int

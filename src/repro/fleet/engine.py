@@ -217,18 +217,14 @@ class FleetEngine:
         if mesh is None:
             return batched
         from jax.sharding import PartitionSpec
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError as e:          # pragma: no cover - very old jax
-            raise RuntimeError("shard_map unavailable in this jax") from e
         n_dev = int(np.prod(mesh.devices.shape))
         if self.replicates % n_dev:
             raise ValueError(f"replicates={self.replicates} not divisible by "
                              f"mesh size {n_dev}")
         spec = PartitionSpec(mesh.axis_names[0])
-        return shard_map(batched, mesh=mesh,
-                         in_specs=(spec, spec, spec, spec, spec),
-                         out_specs=(spec, spec), check_rep=False)
+        return jax.shard_map(batched, mesh=mesh,
+                             in_specs=(spec, spec, spec, spec, spec),
+                             out_specs=(spec, spec), check_vma=False)
 
     def make_fleet_round(self, cfg, mesh=None, flat: bool = False,
                          unravel_row=None, spec=None):

@@ -17,9 +17,12 @@ CPU and MXU-friendly on TPU) over 24-bit uniforms in the OPEN interval
 (0, 1), drawn from the Pallas TPU PRNG (pltpu.prng_seed /
 prng_random_bits) seeded per (call, program).
 
-Grid: 1-D over column blocks of the flat buffer; each program handles the
-full worker axis (N is small — padded to the f32 sublane multiple) times a
-(BLOCK_D)-column VMEM tile, so the [N, N] mixing matrix stays resident.
+Grid: (replicate, column block) over the flat buffer — one replicate for
+a single network, the fleet's R under vmap. Each program handles the full
+worker axis (N is small — padded to the f32 sublane multiple) times a
+(BLOCK_D)-column VMEM tile, so the [N, N] mixing matrix stays resident;
+``MAX_NP`` records how far N can grow before it no longer fits
+(DESIGN.md §9).
 All channel quantities (c, σ_m, per-worker amplitudes, the mixing matrix
 itself) are runtime OPERANDS — one compiled kernel serves every fading /
 geometry / churn realization with zero retraces.
@@ -43,6 +46,10 @@ from repro.kernels.dp_perturb.dp_perturb import _hash_bits
 
 LANES = 128     # last-dim tile multiple (f32)
 SUBLANES = 8    # worker-axis pad multiple (f32 sublane)
+# The mix must run in full f32 on the MXU: the noise terms reach |n/c| ~ 70
+# and cancel against the self-correction column, so one bf16 pass would
+# leave ~0.3 of noise in every parameter. (XLA CPU's dot is f32 anyway.)
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _normal_from_bits(bits):
@@ -53,8 +60,11 @@ def _normal_from_bits(bits):
     ±1 (the naive (k + ½)/2²⁴ lattice ROUNDS to 1.0 at the top point and
     erf⁻¹(1) = inf — one poisoned draw per ~16M). Tails truncate at
     ≈ 5.4σ, the resolution of any 24-bit inverse-CDF sampler."""
-    t = ((bits >> 8).astype(jnp.float32) - (float(1 << 23) - 0.5)) \
-        * (1.0 / (1 << 23))
+    # the 24-bit count goes through int32 (exact: < 2²⁴): Mosaic has no
+    # uint32 -> f32 cast, and int32 -> f32 is the same value on every
+    # backend, so the CPU counter-hash stream is bit-for-bit unchanged
+    k = (bits >> 8).astype(jnp.int32)
+    t = (k.astype(jnp.float32) - (float(1 << 23) - 0.5)) * (1.0 / (1 << 23))
     return math.sqrt(2.0) * jax.lax.erf_inv(t)
 
 
@@ -110,9 +120,10 @@ def _round_math(p, g, normal_pair, c, sigma_m, amp, selfs, mscale, listen, w,
         blocks = jnp.concatenate(
             [w, w - eye * selfs, eye * (mscale * sigma_m)], axis=1)
         z3 = jnp.concatenate([x, nf, g_m], axis=0)
-        upd_px = jnp.dot(blocks, z3, preferred_element_type=jnp.float32)
+        upd_px = jnp.dot(blocks, z3, precision=_F32,
+                         preferred_element_type=jnp.float32)
         return x + eta * listen * (upd_px - x)
-    mixed = jnp.dot(w, x, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(w, x, precision=_F32, preferred_element_type=jnp.float32)
     return x + eta * listen * (mixed - x)
 
 
@@ -175,41 +186,115 @@ def dp_mix_sparse_jnp(p2, g2, seed, off, scal, amp, selfs, mscale, listen,
     return out.astype(p2.dtype)
 
 
-def _dp_mix_kernel(seed_ref, off_ref, scal_ref, amp_ref, selfs_ref,
-                   mscale_ref, listen_ref, w_ref, p_ref, g_ref, out_ref, *,
-                   gamma, eta, noisy, d_padded, interpret):
-    pid = pl.program_id(0)
+def _dp_mix_kernel(seed_ref, off_ref, scal_ref, vec_ref, w_ref, p_ref,
+                   g_ref, out_ref, *, gamma, eta, noisy, d_padded,
+                   interpret):
+    r = pl.program_id(0)                     # replicate
+    j = pl.program_id(1)                     # column block
     p = p_ref[...].astype(jnp.float32)       # [Np, BD]
     g = g_ref[...].astype(jnp.float32)
-    col = lambda v: v[...].reshape(p.shape[0], 1)
+    vecs = vec_ref[...]                      # [Np, 4]: amp, self, m_scale, listen
+    col = lambda i: vecs[:, i:i + 1]
 
     def normal_pair():
         if interpret:
-            # off_ref[0]: global column offset of this CALL's window
+            # off_ref[r]: global column offset of this CALL's window
             # (repro.shard — 0 for the whole-buffer round); counters use
             # the canonical stride d_padded so shard streams tile the
             # single-device stream exactly.
             return _normal_pair_hash(p.shape, d_padded,
-                                     off_ref[0] + pid * p.shape[1],
-                                     seed_ref[0])
+                                     off_ref[r] + j * p.shape[1],
+                                     seed_ref[r])
         from jax.experimental.pallas import tpu as pltpu
         # hash-mix the GLOBAL block index into the seed (NOT seed + pid:
         # with a ~1000-program grid, additive seeding lets nearby round
         # seeds reproduce bitwise-identical DP-noise blocks across
         # rounds/replicates, breaking the independent-Gaussian assumption
         # of the accounting). The block index counts from the window's
-        # global column offset so sharded calls draw disjoint streams.
-        blk = off_ref[0] // p.shape[1] + pid
-        pltpu.prng_seed(_hash_bits(blk, seed_ref[0]).astype(jnp.int32))
+        # global column offset so sharded calls draw disjoint streams;
+        # each replicate brings its own seed.
+        blk = off_ref[r] // p.shape[1] + j
+        pltpu.prng_seed(_hash_bits(blk, seed_ref[r]).astype(jnp.int32))
         b1 = pltpu.prng_random_bits(p.shape).astype(jnp.uint32)
         b2 = pltpu.prng_random_bits(p.shape).astype(jnp.uint32)
         return _normal_from_bits(b1), _normal_from_bits(b2)
 
-    out = _round_math(p, g, normal_pair, scal_ref[0], scal_ref[1],
-                      col(amp_ref), col(selfs_ref), col(mscale_ref),
-                      col(listen_ref), w_ref[...].astype(jnp.float32),
+    out = _round_math(p, g, normal_pair, scal_ref[2 * r], scal_ref[2 * r + 1],
+                      col(0), col(1), col(2), col(3),
+                      w_ref[...].astype(jnp.float32),
                       gamma=gamma, eta=eta, noisy=noisy)
     out_ref[...] = out.astype(out_ref.dtype)
+
+
+# Scoped-VMEM limit the dense kernel compiles under: v5e has 128 MiB of
+# VMEM per core, and Mosaic's default scoped limit (16 MiB) would cap the
+# resident W well below the ceilings in MAX_NP.
+VMEM_LIMIT = 64 * 1024 * 1024
+
+# {noisy: largest padded worker count Np that compiles} for v5e under
+# VMEM_LIMIT at column tiles of up to 512 lanes (DESIGN.md §9). The
+# resident W and, on the noisy path, the [Np, 3Np] block operand grow as
+# Np²; each ceiling is the last Np that compiles at a 512-column tile, one
+# sublane step (8) below the first that runs out of VMEM — pinned against
+# the compiler by tests/test_tpu_compile.py. Narrower tiles fit at least
+# as far.
+MAX_NP = {True: 1024, False: 1504}
+
+
+def _pallas_batched(seed, off, scal, vecs, W, p2, g2, *, gamma, eta, noisy,
+                    block_d, d_padded, interpret):
+    """The kernel over a leading replicate axis R: grid (R, Dp/BD). The
+    per-replicate scalars (seed, column offset, c, σ_m) sit whole in SMEM
+    and are indexed by the replicate program id; the [R, Np, 4] vectors,
+    [R, Np, Np] W and [R, Np, Dp] buffers are blocked per replicate."""
+    from jax.experimental.pallas import tpu as pltpu
+    R, Np, Dp = p2.shape
+    kernel = functools.partial(
+        _dp_mix_kernel, gamma=gamma, eta=eta, noisy=noisy,
+        d_padded=d_padded, interpret=interpret)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((None, Np, block_d), lambda r, i: (r, 0, i))
+    return pl.pallas_call(
+        kernel,
+        grid=(R, Dp // block_d),
+        in_specs=[
+            smem, smem, smem,                                   # seed, off, scal
+            pl.BlockSpec((None, Np, 4), lambda r, i: (r, 0, 0)),   # vecs
+            # W: one buffer — it changes only between replicates
+            pl.BlockSpec((None, Np, Np), lambda r, i: (r, 0, 0),
+                         pipeline_mode=pl.Buffered(1)),
+            tile, tile,
+        ],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(seed, off, scal.reshape(-1), vecs, W, p2, g2)
+
+
+def _replicated_kernel(**statics):
+    """``batched(seed[R], off[R], scal[R, 2], vecs[R, Np, 4], W[R, Np, Np],
+    p2[R, Np, Dp], g2[R, Np, Dp])`` under a custom vmap rule: a vmapped
+    caller (the fleet's replicate axis) folds its axis into R instead of
+    letting pallas_call's generic batching block the 1-D operands, which
+    the TPU lowering refuses. Nested vmaps fold into R the same way."""
+
+    @jax.custom_batching.custom_vmap
+    def batched(*args):
+        return _pallas_batched(*args, **statics)
+
+    @batched.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        R = args[0].shape[1]
+        out = batched(*[a.reshape((axis_size * R,) + a.shape[2:])
+                        for a in args])
+        return out.reshape((axis_size, R) + out.shape[1:]), True
+
+    return batched
 
 
 def dp_mix_2d(p2, g2, seed, off, scal, amp, selfs, mscale, listen, W, *,
@@ -220,30 +305,17 @@ def dp_mix_2d(p2, g2, seed, off, scal, amp, selfs, mscale, listen, W, *,
     = [c, σ_m]; ``off`` the [1] int32 global column offset of this window
     (0 for the whole buffer) and ``counter_width`` the canonical noise-
     counter stride (defaults to Dp — the whole-buffer layout). Returns the
-    updated [Np, Dp] buffer (same dtype as p2)."""
+    updated [Np, Dp] buffer (same dtype as p2). Under vmap the replicate
+    axis becomes the kernel's own grid axis (``_replicated_kernel``)."""
     Np, Dp = p2.shape
-    grid = (Dp // block_d,)
-    kernel = functools.partial(
-        _dp_mix_kernel, gamma=gamma, eta=eta, noisy=noisy,
+    batched = _replicated_kernel(
+        gamma=gamma, eta=eta, noisy=noisy, block_d=block_d,
         d_padded=Dp if counter_width is None else counter_width,
         interpret=interpret)
-    vec = pl.BlockSpec((Np,), lambda i: (0,))
-    tile = pl.BlockSpec((Np, block_d), lambda i: (0, i))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),    # seed
-            pl.BlockSpec((1,), lambda i: (0,)),    # column offset
-            pl.BlockSpec((2,), lambda i: (0,)),    # (c, sigma_m)
-            vec, vec, vec, vec,                    # amp, self, m_scale, listen
-            pl.BlockSpec((Np, Np), lambda i: (0, 0)),  # W
-            tile, tile,
-        ],
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-        interpret=interpret,
-    )(seed, off, scal, amp, selfs, mscale, listen, W, p2, g2)
+    vecs = jnp.stack([amp, selfs, mscale, listen], axis=-1)
+    out = batched(seed.reshape(1), off.reshape(1), scal.reshape(1, 2),
+                  vecs[None], W[None], p2[None], g2[None])
+    return out[0]
 
 
 def dp_mix_fused_jnp(p2, g2, seed, off, scal, amp, selfs, mscale, listen, W,

@@ -25,8 +25,28 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def lowering(sparse: bool = False) -> str:
+    """What a round with ``impl=None`` runs on this backend: "pallas"
+    (the dense kernel, TPU), "jnp" (its fused-jnp lowering elsewhere) or
+    "xla-gather" (the sparse neighbor-list round, every backend)."""
+    if sparse:
+        return "xla-gather"
+    return "pallas" if _on_tpu() else "jnp"
+
+
 def _roundup(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def _pallas_block_d(d: int, counter_width) -> int:
+    """The TPU column tile: 512 lanes, or for a sharded window
+    (counter_width set) the widest of 512/256/128 that DIVIDES the window
+    width, so the global block index (col0 // block_d + pid) tiles without
+    collisions across shards."""
+    if counter_width is not None and d % K.LANES == 0:
+        lanes = d // K.LANES
+        return next(c * K.LANES for c in (4, 2, 1) if lanes % c == 0)
+    return 4 * K.LANES
 
 
 def seed_from_key(key) -> jnp.ndarray:
@@ -73,27 +93,27 @@ def dp_mix_round(p, g, seed, W, amp, c, sigma_m, *, gamma: float, eta: float,
     the exact single-device stream. Defaults (0, None) are the
     whole-buffer round.
 
-    impl: None (auto: "pallas" on TPU, "jnp" elsewhere) | "pallas" |
+    impl: None (auto — ``lowering()``: "pallas" on TPU, "jnp" elsewhere)
+    | "pallas" |
     "pallas_interpret" (the Pallas body executed by the interpreter —
     slow; kernel-validation only) | "jnp" (the fused-jnp CPU lowering,
     bitwise-identical draws to "pallas_interpret").
     """
     N, d = p.shape
     if impl is None:
-        impl = "pallas" if _on_tpu() else "jnp"
+        impl = lowering()
     Np = _roundup(N, K.SUBLANES)
+    if impl == "pallas" and Np > K.MAX_NP[noisy]:
+        # the resident [Np, Np] W no longer fits in VMEM (DESIGN.md §9):
+        # refuse here rather than hand the compiler a kernel it cannot place
+        raise ValueError(
+            f"dense dp_mix at N={N} is past the kernel's VMEM ceiling "
+            f"(N <= {K.MAX_NP[noisy]} {'with' if noisy else 'without'} "
+            f"noise: the [N, N] mixing matrix is VMEM-resident); mix "
+            f"through a sparse neighbor list (--sparse-neighbors) at this N")
     if block_d is None:
         if impl == "pallas":
-            # a fixed VMEM-sized tile on TPU; for a sharded window
-            # (counter_width set) the tile must DIVIDE the window width so
-            # the global block index (col0 // block_d + pid) tiles without
-            # collisions across shards — take the largest lane multiple of
-            # {4, 2, 1} that does
-            block_d = 4 * K.LANES
-            if counter_width is not None and d % K.LANES == 0:
-                lanes = d // K.LANES
-                block_d = next(c * K.LANES for c in (4, 2, 1)
-                               if lanes % c == 0)
+            block_d = _pallas_block_d(d, counter_width)
         else:
             # one program off-TPU (no grid to amortize)
             block_d = _roundup(d, K.LANES)
@@ -141,14 +161,16 @@ def dp_mix_round_sparse(p, g, seed, sw, amp, c, sigma_m, *, gamma: float,
     window hooks, and the seed→counter mapping are IDENTICAL to the dense
     wrapper, so both paths draw bitwise-equal noise fields and the dense
     round remains the small-N reference (sparse results differ only by
-    slot-order summation ULPs — tests/test_sparse.py). ``impl`` accepts
-    "jnp"/None; the gather accumulation lowers through XLA on every
-    backend (no Pallas body — see dp_mix.dp_mix_sparse_jnp).
+    slot-order summation ULPs — tests/test_sparse.py). It runs as an XLA
+    gather on every backend, TPU included: there is no Pallas body (see
+    dp_mix.dp_mix_sparse_jnp), so ``impl`` accepts "jnp"/None and refuses
+    a Pallas request rather than silently running the gather instead.
     """
     N, d = p.shape
     if impl not in (None, "jnp"):
         raise NotImplementedError(
-            f"sparse dp_mix has no {impl!r} lowering; use impl=None")
+            f"sparse dp_mix runs as an XLA gather and has no {impl!r} "
+            f"lowering; use impl=None")
     Np = _roundup(N, K.SUBLANES)
     if block_d is None:
         block_d = _roundup(d, K.LANES)
@@ -184,15 +206,15 @@ def dp_mix_round_plan(p, g, seed, plan, *, gamma: float, eta: float,
                       impl=None, col0=0, counter_width=None):
     """MixPlan front end (exchange.plan_* → one fused round). Dispatches
     on the plan's W: a dense [N, N] array runs the dense kernel, a
-    repro.net.sparse.SparseW neighbor list runs the O(N·k) sparse round."""
+    repro.net.sparse.SparseW neighbor list runs the O(N·k) sparse round
+    (an XLA gather: a Pallas ``impl`` is refused there)."""
     from repro.net.sparse import SparseW
     if isinstance(plan.W, SparseW):
         return dp_mix_round_sparse(
             p, g, seed, plan.W, plan.amp, plan.c, plan.sigma_m,
             gamma=gamma, eta=eta, self_scale=plan.self_scale,
             m_scale=plan.m_scale, listen=plan.listen, noisy=plan.noisy,
-            impl=None if impl in ("pallas", "pallas_interpret") else impl,
-            col0=col0, counter_width=counter_width)
+            impl=impl, col0=col0, counter_width=counter_width)
     return dp_mix_round(
         p, g, seed, plan.W, plan.amp, plan.c, plan.sigma_m,
         gamma=gamma, eta=eta, self_scale=plan.self_scale,

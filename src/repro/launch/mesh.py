@@ -12,18 +12,17 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: the ``axis_types`` kwarg (and
-    jax.sharding.AxisType) only exist on newer jax; plain Auto axes are the
-    default there, so the two-argument call is equivalent everywhere."""
-    try:
-        return jax.make_mesh(shape, axes)
-    except (TypeError, AttributeError):  # very old jax: no jax.make_mesh
-        from jax.sharding import Mesh
-        from jax.experimental import mesh_utils
-        return Mesh(mesh_utils.create_device_mesh(shape), axes)
+    """jax.make_mesh with every axis Auto. jax.make_mesh defaults to
+    Explicit axes, under which slicing a sharded flat buffer (the padded
+    [:, :d] view) is a ShardingTypeError; every engine here places its
+    buffers with NamedSharding and lets shard_map/XLA propagate the rest,
+    which is the Auto contract."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

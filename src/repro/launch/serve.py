@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_arch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
 
 
@@ -43,6 +44,7 @@ def splice_cache(full, prefill):
 
 
 def main(argv=None):
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=list(ARCHS))
     ap.add_argument("--reduced", action="store_true", default=True)

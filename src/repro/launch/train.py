@@ -29,6 +29,7 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,9 +44,28 @@ from repro.core import protocol as P
 from repro.core import trajectory as TJ
 from repro.data import (FederatedBatcher, LMBatcher, classification_dataset,
                         dirichlet_partition, lm_dataset, store_from_batcher)
+from repro.kernels.dp_mix import ops as mix_ops
+from repro.launch.compile_cache import setup_compile_cache
 
 
-def main(argv=None):
+class TrainResult(NamedTuple):
+    """What a finished run hands back to an in-process caller.
+
+    ``params``: the final worker tree or flat buffer. ``epsilon``: the
+    run's ε quote — composed from the scan carry's ledger under
+    ``--accountant`` when telemetry keeps one, else the end-of-run
+    report's (per round on the static channel). ``runner``: the scan engine's
+    ChunkRunner (None under --no-scan), whose trace_counts(),
+    compile_seconds and lowered_text() expose each compiled chunk length;
+    ``carry``: its final TrajCarry, from which ``runner.run`` continues."""
+    params: Any
+    epsilon: float
+    runner: Optional[TJ.ChunkRunner]
+    carry: Optional[TJ.TrajCarry]
+
+
+def main(argv=None) -> TrainResult:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dwfl-paper", choices=list(ARCHS))
     ap.add_argument("--reduced", action="store_true",
@@ -129,8 +149,9 @@ def main(argv=None):
                          "fused dp_mix round on its own [N, d/S] slice. "
                          "Uses a real device mesh when >= S devices exist "
                          "(CPU: XLA_FLAGS=--xla_force_host_platform_"
-                         "device_count=S), else shards logically on one "
-                         "device. Requires --flat-buffer.")
+                         "device_count=S); with fewer, shards logically on "
+                         "one device on CPU and refuses on an accelerator. "
+                         "Requires --flat-buffer.")
     ap.add_argument("--max-chunk-cols", type=int, default=0,
                     help="cap (in columns) on each collective of the "
                          "sharded round's gather-free grad pass "
@@ -355,6 +376,11 @@ def main(argv=None):
                 spec, shard_mesh,
                 replicate_axis="replicas" if fleet is not None else None))
             where = f"{spec.n_shards}-device model mesh"
+        elif jax.default_backend() != "cpu":
+            raise SystemExit(f"--model-shards {spec.n_shards} needs "
+                             f"{spec.n_shards} {jax.default_backend()} "
+                             f"devices; have {jax.device_count()} (one-"
+                             f"device logical sharding is a CPU test mode)")
         else:
             where = (f"1 device (logical — set XLA_FLAGS=--xla_force_host_"
                      f"platform_device_count={spec.n_shards} or run on a "
@@ -369,6 +395,9 @@ def main(argv=None):
               + (f", cap {cap} cols" if cap else " (unbounded)"))
     print(f"[train] params/worker: {n_params/1e6:.2f}M"
           + (" (flat dp_mix buffer)" if proto.flat_buffer else ""))
+    if proto.flat_buffer:
+        print(f"[train] mix lowering: "
+              f"{mix_ops.lowering(sparse=proto.sparse_neighbors > 0)}")
 
     net_state = None
     if fleet is not None:
@@ -730,8 +759,22 @@ def main(argv=None):
         print(f"[train] run log closed: {runlog.dir} "
               f"({runlog.n_events} events, {runlog.n_warnings} warnings) — "
               f"summarize with `python -m repro.obs.report {runlog.dir}`")
-    return 0
+    if not args.no_scan and carry.eps is not None:
+        m = np.asarray(carry.eps)
+        e_c, _ = privacy.compose_from_moments(
+            m, proto.delta,
+            accountant=args.accountant if m.shape[-1] > 4 else "composition")
+        epsilon = float(np.max(e_c))
+    elif fleet is not None:
+        epsilon = float(rep["epsilon_total_mean"])
+    elif sim is not None:
+        epsilon = float(rep["epsilon_total"])
+    else:
+        epsilon = float(rep["epsilon_worst"])
+    if args.no_scan:
+        return TrainResult(wp, epsilon, None, None)
+    return TrainResult(wp, epsilon, runner, carry)
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

@@ -288,13 +288,12 @@ def make_sharded_flat_train_step(cfg, proto, spec: FlatSpec, mesh=None,
                                    axis=None, impl=impl, remat=remat)
         return lambda flat, batch, key: run(flat, batch, key)
     _check_mesh(spec, mesh, axis)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     run = _local_round_factory(cfg, proto, spec, dynamic=False, axis=axis,
                                impl=impl, remat=remat)
-    return shard_map(lambda flat, batch, key: run(flat, batch, key),
-                     mesh=mesh, in_specs=(P(None, axis), P(), P()),
-                     out_specs=(P(None, axis), P()), check_rep=False)
+    return jax.shard_map(lambda flat, batch, key: run(flat, batch, key),
+                         mesh=mesh, in_specs=(P(None, axis), P(), P()),
+                         out_specs=(P(None, axis), P()), check_vma=False)
 
 
 def make_sharded_dynamic_flat_train_step(cfg, proto, spec: FlatSpec,
@@ -313,14 +312,13 @@ def make_sharded_dynamic_flat_train_step(cfg, proto, spec: FlatSpec,
         return lambda flat, batch, key, chan, W: run(flat, batch, key,
                                                      chan, W)
     _check_mesh(spec, mesh, axis)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     run = _local_round_factory(cfg, proto, spec, dynamic=True, axis=axis,
                                impl=impl, remat=remat)
-    return shard_map(
+    return jax.shard_map(
         lambda flat, batch, key, chan, W: run(flat, batch, key, chan, W),
         mesh=mesh, in_specs=(P(None, axis), P(), P(), P(), P()),
-        out_specs=(P(None, axis), P()), check_rep=False)
+        out_specs=(P(None, axis), P()), check_vma=False)
 
 
 def make_fleet_sharded_step(cfg, proto, spec: FlatSpec, mesh,
@@ -346,7 +344,6 @@ def make_fleet_sharded_step(cfg, proto, spec: FlatSpec, mesh,
     if replicate_axis not in sizes:
         raise ValueError(f"mesh has no {replicate_axis!r} axis: "
                          f"{mesh.axis_names}")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     run = _local_round_factory(cfg, proto, spec, dynamic=True, axis=axis,
                                impl=impl, remat=remat)
@@ -355,8 +352,8 @@ def make_fleet_sharded_step(cfg, proto, spec: FlatSpec, mesh,
         return jax.vmap(run)(flat, batch, keys, chans, Ws)
 
     rspec = P(replicate_axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(replicate_axis, None, axis), rspec, rspec, rspec,
                   rspec),
-        out_specs=(P(replicate_axis, None, axis), rspec), check_rep=False)
+        out_specs=(P(replicate_axis, None, axis), rspec), check_vma=False)

@@ -184,8 +184,7 @@ def make_worker_sharded_dynamic_flat_train_step(cfg, proto, spec: FlatSpec,
                    "param_norm": jnp.sqrt(sq)}
         return flat_loc, metrics
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    return shard_map(run, mesh=mesh,
-                     in_specs=(P(axis, None), P(axis), P(), P(), P()),
-                     out_specs=(P(axis, None), P()), check_rep=False)
+    return jax.shard_map(run, mesh=mesh,
+                         in_specs=(P(axis, None), P(axis), P(), P(), P()),
+                         out_specs=(P(axis, None), P()), check_vma=False)

@@ -12,6 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+# Entry points under test place JAX's persistent compile cache in the
+# checkout (repro.launch.compile_cache); tests write nothing there.
+jax.config.update("jax_enable_compilation_cache", False)
+
 
 @pytest.fixture(scope="session")
 def rng():

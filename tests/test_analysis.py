@@ -163,7 +163,7 @@ def test_weak_closure_ignores_structural_constants():
 
 
 def test_dtype_checker_fires_on_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cj = jax.make_jaxpr(lambda x: x * 2.0)(np.ones(3, np.float64))
     errs = _errors(check_dtype_discipline(cj, "fix"))
     assert errs and "f64" in " ".join(f.message for f in errs)
@@ -240,7 +240,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.analysis import (Severity, analyze_program, build_programs,
                             check_gather_free)
@@ -254,8 +253,8 @@ def gathered(flat):     # adversarial: the old gather-compute-slice round
     def body(fl):
         full = jax.lax.all_gather(fl, "model", axis=1, tiled=True)
         return full.sum(axis=1, keepdims=True) * jnp.ones_like(fl)
-    return shard_map(body, mesh=mesh, in_specs=(P(None, "model"),),
-                     out_specs=P(None, "model"), check_rep=False)(flat)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(None, "model"),),
+                         out_specs=P(None, "model"), check_vma=False)(flat)
 
 cj = jax.make_jaxpr(gathered)(jnp.zeros((W, width), jnp.float32))
 fs = check_gather_free(cj, "adversarial", sharded=True,

@@ -104,7 +104,7 @@ def test_sampled_plan_full_participation_matches_reference():
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(N=st.integers(3, 9), d=st.integers(4, 64),
        eta=st.floats(0.05, 1.0), seed=st.integers(0, 10_000))
 def test_property_arbitrary_doubly_stochastic_W(N, d, eta, seed):
@@ -124,7 +124,7 @@ def test_property_arbitrary_doubly_stochastic_W(N, d, eta, seed):
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(N=st.integers(3, 9), d=st.integers(8, 200),
        eta=st.floats(0.05, 1.0), seed=st.integers(0, 10_000))
 def test_property_flat_buffer_mean_descent(N, d, eta, seed):

@@ -179,6 +179,73 @@ def test_dp_mix_gossip_noiseless_path():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("d,counter_width,block_d", [
+    (855_050, None, 512), (4 * 128 * 3, 4 * 128 * 12, 512),
+    (2 * 128 * 3, 2 * 128 * 12, 256), (128 * 3, 128 * 12, 128)])
+def test_dp_mix_tile_width_from_vmem_ceiling(d, counter_width, block_d):
+    """The TPU tile is 512 columns — the width the VMEM ceiling
+    (dp_mix.MAX_NP) is measured at — narrowed for a sharded window only
+    as far as it takes to divide the window."""
+    assert mix_ops._pallas_block_d(d, counter_width) == block_d
+
+
+@pytest.mark.parametrize("N,noisy,block_d", [
+    (1025, True, None), (1505, False, None), (1025, True, 128)])
+def test_dp_mix_refuses_dense_past_vmem_ceiling(N, noisy, block_d):
+    """Past the ceiling the Pallas round raises (naming the sparse path)
+    instead of handing the TPU compiler a kernel it runs out of VMEM on,
+    whatever tile it is asked for."""
+    p = jnp.zeros((N, 256), jnp.float32)
+    with pytest.raises(ValueError, match="sparse-neighbors"):
+        mix_ops.dp_mix_round(p, p, 0, jnp.eye(N), jnp.ones(N), 1.0, 0.0,
+                             gamma=0.0, eta=0.5, noisy=noisy, impl="pallas",
+                             block_d=block_d)
+
+
+def test_dp_mix_vmapped_kernel_matches_per_replicate():
+    """Under vmap (the fleet's replicate axis, nested or with a shared
+    operand) the kernel takes the replicates as its own grid axis and
+    reproduces each replicate's own call bitwise."""
+    R, N, d = 3, 5, 300
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    p = jax.random.normal(k[0], (R, N, d))
+    g = jax.random.normal(k[1], (R, N, d))
+    W = jax.random.uniform(k[2], (R, N, N))
+    W = W / W.sum(-1, keepdims=True)
+    amp = jnp.linspace(0.5, 1.5, R * N).reshape(R, N)
+    c, sm = jnp.array([1.0, 2.0, 3.0]), jnp.array([0.1, 0.3, 0.5])
+    seed = jnp.arange(R, dtype=jnp.int32) + 7
+
+    def f(p, g, s, W, a, c, sm):
+        return mix_ops.dp_mix_round(p, g, s, W, a, c, sm, gamma=0.1, eta=0.4,
+                                    impl="pallas_interpret", block_d=128)
+    want = np.stack([np.asarray(f(p[r], g[r], seed[r], W[r], amp[r], c[r],
+                                  sm[r])) for r in range(R)])
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(f)(p, g, seed, W, amp, c, sm)), want)
+    nested = jax.vmap(jax.vmap(f))(p[None], g[None], seed[None], W[None],
+                                   amp[None], c[None], sm[None])
+    np.testing.assert_array_equal(np.asarray(nested[0]), want)
+    shared = jax.vmap(f, in_axes=(0, 0, None, 0, 0, 0, 0))(
+        p, g, seed[0], W, amp, c, sm)
+    np.testing.assert_array_equal(
+        np.asarray(shared[1]),
+        np.asarray(f(p[1], g[1], seed[0], W[1], amp[1], c[1], sm[1])))
+
+
+def test_dp_mix_sparse_plan_refuses_pallas():
+    """The sparse round is an XLA gather on every backend: a Pallas
+    request is refused, not silently run as the gather."""
+    import dataclasses
+    from repro.net.sparse import sparsify_dense
+    chan, p, g, plan = _mix_setup()
+    splan = dataclasses.replace(plan, W=sparsify_dense(plan.W, 3))
+    with pytest.raises(NotImplementedError, match="XLA gather"):
+        mix_ops.dp_mix_round_plan(p, g, 7, splan, gamma=0.05, eta=0.4,
+                                  impl="pallas")
+    assert mix_ops.lowering(sparse=True) == "xla-gather"
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

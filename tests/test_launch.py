@@ -71,3 +71,31 @@ def test_mesh_helpers():
     assert mesh_lib.model_size(m) == 1
     assert mesh_lib.worker_axes(False) == ("data",)
     assert mesh_lib.worker_axes(True) == ("pod", "data")
+    # every axis Auto: slicing a sharded buffer is then no sharding error
+    assert set(m.axis_types) == {jax.sharding.AxisType.Auto}
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed"])
+def test_compile_cache_placement(env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is honoured and nothing else is
+    set; otherwise the cache is the checkout's fixed .jax_cache."""
+    import pathlib
+    from repro.launch import compile_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        got = compile_cache.setup_compile_cache()
+        if env_dir is None:
+            root = pathlib.Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

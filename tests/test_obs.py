@@ -337,12 +337,13 @@ def test_train_quickstart_runlog_epsilon_consistency(tmp_path):
     epsilon_report (the host-side Thm 4.1 accounting), and the composed
     budget in the epsilon events matches composing the JSONL ε trajectory."""
     from repro.launch import train
-    rc = train.main([
+    res = train.main([
         "--steps", "24", "--workers", "6", "--eval-every", "12",
         "--channel-model", "dynamic", "--scenario", "iot_dense",
         "--runlog-dir", str(tmp_path), "--eps-budget", "5.0",
     ])
-    assert rc == 0
+    assert res.runner is not None
+    assert all(n == 1 for n in res.runner.trace_counts().values())
     runs = report_lib.find_runs(tmp_path)
     assert len(runs) == 1
     man = obs.RunLog.read_manifest(runs[0])

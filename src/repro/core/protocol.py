@@ -341,7 +341,8 @@ def _make_local_pass(cfg: ModelConfig, proto: ProtocolConfig):
             loss, g = jax.value_and_grad(M.loss_fn)(p, b, cfg)
             g, gnorm = privacy.clip_gradient_tree(g, proto.clip)
             return loss, g, gnorm
-        return jax.vmap(one)(worker_params, batch)
+        with jax.named_scope("dwfl.grad"):
+            return jax.vmap(one)(worker_params, batch)
 
     def local_step(worker_params, grads):
         if proto.use_pallas:
@@ -364,6 +365,7 @@ def _bucket(X):
     return {"flat": flat}, unravel_full
 
 
+@jax.named_scope("dwfl.telemetry")
 def _metrics(losses, gnorms, X):
     return {
         "loss": jnp.mean(losses),
@@ -474,11 +476,13 @@ def _make_flat_local_pass(cfg: ModelConfig, proto: ProtocolConfig,
             loss, g = jax.value_and_grad(target)(fv)
             g, gnorm = privacy.clip_gradient_tree(g, clip)
             return loss, g, gnorm
-        return jax.vmap(one)(flat, batch)
+        with jax.named_scope("dwfl.grad"):
+            return jax.vmap(one)(flat, batch)
 
     return local_grads
 
 
+@jax.named_scope("dwfl.telemetry")
 def _flat_metrics(losses, gnorms, flat):
     return {
         "loss": jnp.mean(losses),
@@ -520,7 +524,8 @@ def make_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
         if proto.n_workers < 2:
             flat = flat - gamma * g
             return flat, _flat_metrics(losses, gnorms, flat)
-        plan = spec.plan(proto, chan, k_x)
+        with jax.named_scope("dwfl.mix"):
+            plan = spec.plan(proto, chan, k_x)
         flat = mix_ops.dp_mix_round_plan(
             flat, g, mix_ops.seed_from_key(k_n), plan, gamma=gamma, eta=eta)
         return flat, _flat_metrics(losses, gnorms, flat)
@@ -548,7 +553,8 @@ def make_dynamic_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
         if proto.n_workers < 2:
             flat = flat - gamma * g
             return flat, _flat_metrics(losses, gnorms, flat)
-        plan = spec.plan(proto, chan, k_x, W_arg=W)
+        with jax.named_scope("dwfl.mix"):
+            plan = spec.plan(proto, chan, k_x, W_arg=W)
         flat = mix_ops.dp_mix_round_plan(
             flat, g, mix_ops.seed_from_key(k_n), plan, gamma=gamma, eta=eta)
         return flat, _flat_metrics(losses, gnorms, flat)

@@ -38,13 +38,13 @@ the speedup).
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import protocol as protocol_lib
+from repro.obs.spans import span
 
 
 class TrajCarry(NamedTuple):
@@ -264,17 +264,19 @@ def _maybe_instrument(body: Callable, tele, proto, *, fleet=None) -> Callable:
         new_carry, out = body(carry)
         if not in_fields:
             return new_carry, out
-        vals = {}
-        if tele.loss:
-            vals["loss"] = out["metrics"]["loss"]
-        if tele.grad_norm:
-            vals["grad_norm"] = out["metrics"]["grad_norm"]
-        if tele.consensus:
-            vals["consensus"] = tele_lib.consensus_distance(
-                carry.params, worker_axis=worker_axis)
-        cols = [jnp.asarray(vals[f], jnp.float32) for f in in_fields]
-        return new_carry, dict(out, telemetry=jnp.stack(cols, axis=-1))
+        with jax.named_scope("dwfl.telemetry"):
+            vals = {}
+            if tele.loss:
+                vals["loss"] = out["metrics"]["loss"]
+            if tele.grad_norm:
+                vals["grad_norm"] = out["metrics"]["grad_norm"]
+            if tele.consensus:
+                vals["consensus"] = tele_lib.consensus_distance(
+                    carry.params, worker_axis=worker_axis)
+            cols = [jnp.asarray(vals[f], jnp.float32) for f in in_fields]
+            return new_carry, dict(out, telemetry=jnp.stack(cols, axis=-1))
 
+    @jax.named_scope("dwfl.telemetry")
     def chunk_epilogue(carry: TrajCarry, ys):
         k = jax.tree_util.tree_leaves(ys)[0].shape[0]
         lead = (k,) if R is None else (k, R)
@@ -344,8 +346,9 @@ class ChunkRunner:
         self._cache = {}
         # wall seconds of each length's FIRST call: trace + compile (or a
         # persistent-cache load) + enqueue — the set-up cost a run pays
-        # once per chunk length
+        # once per chunk length, read from its ``dwfl.chunk.compile`` span
         self.compile_seconds = {}
+        self._calls = 0
 
     def trace_counts(self):
         """{chunk_length: lifetime compilation count} over the cached scan
@@ -404,14 +407,23 @@ class ChunkRunner:
 
     def run(self, carry: TrajCarry, k: int) -> Tuple[TrajCarry, Any]:
         k = int(k)
+        self._calls += 1
         if k not in self._cache:
-            t0 = time.perf_counter()
-            fn, arrays = self._cache[k] = self._jit(k, carry)
-            out = fn(carry, arrays)
-            self.compile_seconds[k] = time.perf_counter() - t0
+            with span("dwfl.chunk.compile", rounds=k) as s:
+                fn, arrays = self._cache[k] = self._jit(k, carry)
+                out = fn(carry, arrays)
+            self.compile_seconds[k] = s.seconds
             return out
         fn, arrays = self._cache[k]
-        return fn(carry, arrays)
+        with span("dwfl.chunk.dispatch", rounds=k, chunk=self._calls):
+            return fn(carry, arrays)
+
+    def compiled_text(self, carry: TrajCarry, k: int) -> str:
+        """HLO text of the k-round program ``run`` dispatches, compiled
+        for the backend at ``carry``'s shapes, with each op's ``op_name``
+        metadata (the named scopes a device trace attributes it by)."""
+        fn, arrays = self._cache[int(k)]
+        return fn.lower(carry, arrays).compile().as_text()
 
     def lowered_text(self, carry: TrajCarry, k: int) -> str:
         """StableHLO text of the k-round program ``run`` dispatches, at

@@ -271,6 +271,7 @@ def _pallas_batched(seed, off, scal, vecs, W, p2, g2, *, gamma, eta, noisy,
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="dp_mix_round",
     )(seed, off, scal.reshape(-1), vecs, W, p2, g2)
 
 

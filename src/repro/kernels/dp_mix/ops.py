@@ -207,16 +207,14 @@ def dp_mix_round_plan(p, g, seed, plan, *, gamma: float, eta: float,
     """MixPlan front end (exchange.plan_* → one fused round). Dispatches
     on the plan's W: a dense [N, N] array runs the dense kernel, a
     repro.net.sparse.SparseW neighbor list runs the O(N·k) sparse round
-    (an XLA gather: a Pallas ``impl`` is refused there)."""
+    (an XLA gather: a Pallas ``impl`` is refused there). Everything it
+    runs (the pads to [Np, Dp], the kernel, the slice back) sits in the
+    ``dwfl.mix`` named scope."""
     from repro.net.sparse import SparseW
-    if isinstance(plan.W, SparseW):
-        return dp_mix_round_sparse(
-            p, g, seed, plan.W, plan.amp, plan.c, plan.sigma_m,
-            gamma=gamma, eta=eta, self_scale=plan.self_scale,
-            m_scale=plan.m_scale, listen=plan.listen, noisy=plan.noisy,
-            impl=impl, col0=col0, counter_width=counter_width)
-    return dp_mix_round(
-        p, g, seed, plan.W, plan.amp, plan.c, plan.sigma_m,
-        gamma=gamma, eta=eta, self_scale=plan.self_scale,
-        m_scale=plan.m_scale, listen=plan.listen, noisy=plan.noisy,
-        impl=impl, col0=col0, counter_width=counter_width)
+    mix = dp_mix_round_sparse if isinstance(plan.W, SparseW) else dp_mix_round
+    with jax.named_scope("dwfl.mix"):
+        return mix(p, g, seed, plan.W, plan.amp, plan.c, plan.sigma_m,
+                   gamma=gamma, eta=eta, self_scale=plan.self_scale,
+                   m_scale=plan.m_scale, listen=plan.listen,
+                   noisy=plan.noisy, impl=impl, col0=col0,
+                   counter_width=counter_width)

@@ -65,6 +65,11 @@ class TrainResult(NamedTuple):
 
 
 def main(argv=None) -> TrainResult:
+    with obs.span("dwfl.main"):
+        return _main(argv)
+
+
+def _main(argv) -> TrainResult:
     setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dwfl-paper", choices=list(ARCHS))
@@ -322,15 +327,19 @@ def main(argv=None) -> TrainResult:
               f"(orthogonal would be eps={rep['epsilon_orthogonal_worst']:.3g})")
 
     key = jax.random.PRNGKey(args.seed)
-    if cfg.family == "mlp":
-        x, y = classification_dataset(args.dataset_size, seed=args.seed)
-        parts = dirichlet_partition(y, W, alpha=args.dirichlet_alpha,
-                                    seed=args.seed)
-        batcher = FederatedBatcher(x, y, parts, args.batch_size, seed=args.seed)
-    else:
-        toks = lm_dataset(W * 200_000, cfg.vocab_size, seed=args.seed)
-        batcher = LMBatcher(toks, W, args.batch_size, args.seq_len,
-                            seed=args.seed)
+    with obs.span("dwfl.main.data"):
+        if cfg.family == "mlp":
+            x, y = classification_dataset(args.dataset_size, seed=args.seed)
+            parts = dirichlet_partition(y, W, alpha=args.dirichlet_alpha,
+                                        seed=args.seed)
+            batcher = FederatedBatcher(x, y, parts, args.batch_size,
+                                       seed=args.seed)
+        else:
+            toks = lm_dataset(W * 200_000, cfg.vocab_size, seed=args.seed)
+            batcher = LMBatcher(toks, W, args.batch_size, args.seq_len,
+                                seed=args.seed)
+        # the scan path samples on the device from this store
+        store = None if args.no_scan else store_from_batcher(batcher)
 
     # spec: flat-buffer mode only — the layout-aware buffer contract
     # (exchange.FlatSpec); unravel maps the persistent [.., W, width]
@@ -496,7 +505,6 @@ def main(argv=None) -> TrainResult:
     if not args.no_scan:
         # scan-fused trajectory: one dispatch per chunk, on-device batch
         # sampling, eval/log at chunk boundaries only
-        store = store_from_batcher(batcher)
         body = TJ.make_round_body(
             cfg, proto, store, sim=None if fleet is not None else sim,
             fleet=fleet, flat=proto.flat_buffer, unravel_row=unravel_row,

@@ -13,8 +13,11 @@ Two halves (DESIGN.md §13):
   ``python -m repro.obs.report`` summarizer.
 
 ``obs.guard.retrace_guard`` is the reusable zero-retrace checker the
-kernel benchmarks and CI smokes assert with.
+kernel benchmarks and CI smokes assert with. ``obs.spans.span`` times the
+driver's host phases on the profiler's clock and counts them in memory.
 """
+from repro.obs import spans
+from repro.obs.spans import span
 from repro.obs.guard import (RetraceError, no_implicit_transfers,
                              retrace_guard)
 from repro.obs.runlog import (EpsilonBudgetWatchdog, RetraceWatchdog, RunLog,
@@ -28,5 +31,5 @@ __all__ = [
     "TelemetrySpec", "accumulate_eps", "channel_scalars",
     "config_hash", "console", "consensus_distance", "epsilon_round",
     "git_sha", "init_eps_moments", "no_implicit_transfers",
-    "retrace_guard",
+    "retrace_guard", "span", "spans",
 ]

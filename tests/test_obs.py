@@ -374,3 +374,143 @@ def test_train_quickstart_runlog_epsilon_consistency(tmp_path):
     s = report_lib.summarize_run(runs[0])
     assert s["telemetry"]["epsilon"]["n"] == 25
     assert math.isfinite(s["telemetry"]["consensus"]["last"])
+
+
+# ---------------------------------------------------------------------------
+# host spans and device scopes (obs.spans; jax.named_scope in the round)
+# ---------------------------------------------------------------------------
+
+
+def test_span_nests_counts_and_keeps_max_and_total():
+    obs.spans.reset()
+    with obs.span("outer") as outer:
+        for _ in range(3):
+            with obs.span("inner", rounds=5) as inner:
+                pass
+            assert inner.seconds is not None and inner.seconds >= 0.0
+    got = obs.spans.counters()
+    assert set(got) == {"outer", "inner"}
+    assert got["outer"]["count"] == 1 and got["inner"]["count"] == 3
+    assert got["outer"]["total_s"] == pytest.approx(outer.seconds)
+    # nested: the inner spans lie within the outer one
+    assert got["inner"]["total_s"] <= got["outer"]["total_s"]
+    assert got["inner"]["max_s"] <= got["inner"]["total_s"]
+    assert got["inner"]["max_s"] >= got["inner"]["total_s"] / 3
+    # counters() hands out a copy; reset() clears
+    got["outer"]["count"] = 99
+    assert obs.spans.counters()["outer"]["count"] == 1
+    obs.spans.reset()
+    assert obs.spans.counters() == {}
+
+
+def test_span_counts_with_no_profile_running():
+    obs.spans.reset()
+    with pytest.raises(RuntimeError):
+        with obs.span("failing"):
+            raise RuntimeError("the span still closes")
+    with obs.span("quiet", chunk=1):
+        pass
+    got = obs.spans.counters()
+    assert got["failing"]["count"] == 1 and got["quiet"]["count"] == 1
+    obs.spans.reset()
+
+
+def test_span_is_written_into_a_running_profile(tmp_path):
+    """Under a profile the span is a host event of that name, carrying
+    its attributes, on the trace's own clock."""
+    from jax.profiler import ProfileData
+    obs.spans.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("dwfl.chunk.dispatch", rounds=5, chunk=7):
+            jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              for line in plane.lines for e in line.events
+              if e.name == "dwfl.chunk.dispatch"]
+    assert len(events) == 1
+    assert dict(events[0].stats) == {"rounds": 5, "chunk": 7}
+    assert obs.spans.counters()["dwfl.chunk.dispatch"]["count"] == 1
+    obs.spans.reset()
+
+
+def _flat_chunk_runner():
+    """A ChunkRunner over the flat static round of a tiny MLP, telemetry
+    on, with its first carry."""
+    from repro.configs.registry import get_arch
+    from repro.core import exchange as X
+    from repro.core import protocol as P
+    from repro.core import trajectory as TJ
+    from repro.data.device import ClassificationStore
+    import repro.models.mlp as mlp
+    W, dim = 4, 12
+    cfg = get_arch("dwfl-paper").replace(d_model=8)
+    proto = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
+                             eta=0.4, clip=1.0, p_dbm=60.0, sigma=0.7,
+                             sigma_m=0.5, flat_buffer=True)
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(80, dim)).astype(np.float32)
+    y = rng.integers(0, 10, 80).astype(np.int32)
+    store = ClassificationStore.build(
+        x, y, [np.arange(w, 80, W) for w in range(W)], 4)
+    params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=dim)
+    wp = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (W,) + a.shape), params)
+    _unravel, unravel_row = X.worker_unravelers(wp)
+    body = TJ.make_round_body(cfg, proto, store, flat=True,
+                              unravel_row=unravel_row,
+                              telemetry=obs.TelemetrySpec())
+    carry = TJ.TrajCarry(jax.random.PRNGKey(1), X.flatten_worker_tree(wp),
+                         None, obs.init_eps_moments())
+    return TJ.ChunkRunner(body, donate=False), carry
+
+
+def test_chunk_runner_compile_seconds_is_the_compile_span():
+    runner, carry = _flat_chunk_runner()
+    obs.spans.reset()
+    carry, _ = runner.run(carry, 3)
+    for _ in range(2):
+        carry, _ = runner.run(carry, 3)
+    got = obs.spans.counters()
+    assert got["dwfl.chunk.compile"]["count"] == 1
+    assert runner.compile_seconds[3] == got["dwfl.chunk.compile"]["total_s"]
+    assert got["dwfl.chunk.dispatch"]["count"] == 2
+    assert runner.trace_counts() == {3: 1}
+    obs.spans.reset()
+
+
+def test_flat_chunk_program_carries_the_round_scopes():
+    """The compiled static flat chunk program names the gradient pass, the
+    mix and the telemetry in its ops' op_name metadata, which is what a
+    device trace attributes each op by."""
+    import re
+    runner, carry = _flat_chunk_runner()
+    carry, _ = runner.run(carry, 2)
+    text = runner.compiled_text(carry, 2)
+    names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("dwfl.grad", "dwfl.mix", "dwfl.telemetry"):
+        assert scope + "/" in names, scope
+    assert runner.trace_counts() == {2: 1}
+
+
+def test_train_main_spans_its_set_up():
+    """train.main opens dwfl.main around everything it does and
+    dwfl.main.data around the data it builds, and its one chunk compile
+    lies inside both counts."""
+    from repro.launch import train
+    obs.spans.reset()
+    res = train.main(["--steps", "1", "--workers", "3", "--hidden", "8",
+                      "--dataset-size", "240", "--batch-size", "4",
+                      "--flat-buffer", "--eval-every", "0",
+                      "--chunk-rounds", "2"])
+    got = obs.spans.counters()
+    assert got["dwfl.main"]["count"] == 1
+    assert got["dwfl.main.data"]["count"] == 1
+    assert got["dwfl.chunk.compile"]["count"] == 1
+    assert got["dwfl.main.data"]["total_s"] < got["dwfl.main"]["total_s"]
+    assert got["dwfl.chunk.compile"]["total_s"] < got["dwfl.main"]["total_s"]
+    assert res.runner.compile_seconds == {
+        2: got["dwfl.chunk.compile"]["total_s"]}
+    obs.spans.reset()

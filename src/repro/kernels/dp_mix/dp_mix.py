@@ -19,10 +19,11 @@ prng_random_bits) seeded per (call, program).
 
 Grid: (replicate, column block) over the flat buffer — one replicate for
 a single network, the fleet's R under vmap. Each program handles the full
-worker axis (N is small — padded to the f32 sublane multiple) times a
-(BLOCK_D)-column VMEM tile, so the [N, N] mixing matrix stays resident;
-``MAX_NP`` records how far N can grow before it no longer fits
-(DESIGN.md §9).
+worker axis (all N rows of the buffer as it is: no pad, no slice) times
+a BLOCK_D-column VMEM tile, the last one ragged, so the [Np, Np] mixing
+matrix (Np: N padded to the f32 sublane multiple) stays resident;
+``MAX_NP`` records how far N can grow before it no longer fits and
+ops._pallas_block_d how wide a tile is at a given N (DESIGN.md §9).
 All channel quantities (c, σ_m, per-worker amplitudes, the mixing matrix
 itself) are runtime OPERANDS — one compiled kernel serves every fading /
 geometry / churn realization with zero retraces.
@@ -96,9 +97,13 @@ def _normal_pair_hash(shape, d_padded, col0, seed, row0=0):
 def _round_math(p, g, normal_pair, c, sigma_m, amp, selfs, mscale, listen, w,
                 *, gamma, eta, noisy):
     """The fused-round arithmetic, shared verbatim by the Pallas kernel
-    body and the jnp lowering. All vector args are [N]-columns already
-    reshaped to [N, 1]; ``normal_pair`` lazily yields the two noise
-    fields.
+    body and the jnp lowering. p, g: [N, BD]; w: [Np, Np] and the vector
+    args [Np, 1] columns, Np the sublane multiple of N (padded rows and
+    columns zero). The kernel reads the buffer's N rows as they are, the
+    jnp lowering its padded view (N = Np): either way each operand of the
+    mix is padded to Np rows here, so both contract the same [Np, 3Np]
+    block operand in the same order. ``normal_pair`` lazily yields the
+    two [N, BD] noise fields.
 
     The noisy branch is written as ONE block matmul
 
@@ -112,19 +117,28 @@ def _round_math(p, g, normal_pair, c, sigma_m, amp, selfs, mscale, listen, w,
     a kLoop-fusion performance cliff (~3-7x at sharded window widths —
     ``lax.optimization_barrier`` is stripped by the CPU backend, so the
     operand boundary is the only reliable materialization point)."""
+    n, Np = p.shape[0], w.shape[0]
+
+    def rows(v):                             # [n, BD] -> [Np, BD], zeros
+        if n == Np:
+            return v
+        return jnp.concatenate(
+            [v, jnp.zeros((Np - n, v.shape[1]), v.dtype)], axis=0)
+
     x = p - gamma * g
     if noisy:
         g_n, g_m = normal_pair()
-        nf = (amp / c) * g_n                 # n/c: pre-scaled DP noise
-        eye = jnp.eye(p.shape[0], dtype=jnp.float32)
+        nf = (amp[:n] / c) * g_n             # n/c: pre-scaled DP noise
+        eye = jnp.eye(Np, dtype=jnp.float32)
         blocks = jnp.concatenate(
             [w, w - eye * selfs, eye * (mscale * sigma_m)], axis=1)
-        z3 = jnp.concatenate([x, nf, g_m], axis=0)
+        z3 = jnp.concatenate([rows(x), rows(nf), rows(g_m)], axis=0)
         upd_px = jnp.dot(blocks, z3, precision=_F32,
-                         preferred_element_type=jnp.float32)
-        return x + eta * listen * (upd_px - x)
-    mixed = jnp.dot(w, x, precision=_F32, preferred_element_type=jnp.float32)
-    return x + eta * listen * (mixed - x)
+                         preferred_element_type=jnp.float32)[:n]
+        return x + eta * listen[:n] * (upd_px - x)
+    mixed = jnp.dot(w, rows(x), precision=_F32,
+                    preferred_element_type=jnp.float32)[:n]
+    return x + eta * listen[:n] * (mixed - x)
 
 
 def _sparse_round_math(p, g, normal_pair, c, sigma_m, amp, selfs, mscale,
@@ -191,7 +205,7 @@ def _dp_mix_kernel(seed_ref, off_ref, scal_ref, vec_ref, w_ref, p_ref,
                    interpret):
     r = pl.program_id(0)                     # replicate
     j = pl.program_id(1)                     # column block
-    p = p_ref[...].astype(jnp.float32)       # [Np, BD]
+    p = p_ref[...].astype(jnp.float32)       # [N, BD]
     g = g_ref[...].astype(jnp.float32)
     vecs = vec_ref[...]                      # [Np, 4]: amp, self, m_scale, listen
     col = lambda i: vecs[:, i:i + 1]
@@ -232,31 +246,39 @@ def _dp_mix_kernel(seed_ref, off_ref, scal_ref, vec_ref, w_ref, p_ref,
 VMEM_LIMIT = 64 * 1024 * 1024
 
 # {noisy: largest padded worker count Np that compiles} for v5e under
-# VMEM_LIMIT at column tiles of up to 512 lanes (DESIGN.md §9). The
+# VMEM_LIMIT at column tiles of CEILING_BLOCK_D lanes (DESIGN.md §9). The
 # resident W and, on the noisy path, the [Np, 3Np] block operand grow as
 # Np²; each ceiling is the last Np that compiles at a 512-column tile, one
 # sublane step (8) below the first that runs out of VMEM — pinned against
-# the compiler by tests/test_tpu_compile.py. Narrower tiles fit at least
-# as far.
+# the compiler by tests/test_tpu_compile.py. Below the ceiling the tile
+# widens at constant area (ops._pallas_block_d), up to BLOCK_D_CAP lanes.
 MAX_NP = {True: 1024, False: 1504}
+CEILING_BLOCK_D = 512
+# Widest column tile: the width with the shortest kernel time in a sweep of
+# {4096, 8192, 16384, 32768} on a v5e at N = 10, d = 855,050 (PERF.md §6).
+BLOCK_D_CAP = 16384
 
 
-def _pallas_batched(seed, off, scal, vecs, W, p2, g2, *, gamma, eta, noisy,
+def _pallas_batched(seed, off, scal, vecs, W, p, g, *, gamma, eta, noisy,
                     block_d, d_padded, interpret):
-    """The kernel over a leading replicate axis R: grid (R, Dp/BD). The
-    per-replicate scalars (seed, column offset, c, σ_m) sit whole in SMEM
-    and are indexed by the replicate program id; the [R, Np, 4] vectors,
-    [R, Np, Np] W and [R, Np, Dp] buffers are blocked per replicate."""
+    """The kernel over a leading replicate axis R: grid (R, cdiv(d, BD)).
+    The per-replicate scalars (seed, column offset, c, σ_m) sit whole in
+    SMEM and are indexed by the replicate program id; the [R, Np, 4]
+    vectors, [R, Np, Np] W and [R, N, d] buffers are blocked per replicate.
+    The buffers are read and written as they are: a tile spans all N rows
+    (the full dimension), and the last column tile may overhang d — its
+    columns past d are never written back, and no column reads another."""
     from jax.experimental.pallas import tpu as pltpu
-    R, Np, Dp = p2.shape
+    R, N, d = p.shape
+    Np = W.shape[1]
     kernel = functools.partial(
         _dp_mix_kernel, gamma=gamma, eta=eta, noisy=noisy,
         d_padded=d_padded, interpret=interpret)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    tile = pl.BlockSpec((None, Np, block_d), lambda r, i: (r, 0, i))
+    tile = pl.BlockSpec((None, N, block_d), lambda r, i: (r, 0, i))
     return pl.pallas_call(
         kernel,
-        grid=(R, Dp // block_d),
+        grid=(R, pl.cdiv(d, block_d)),
         in_specs=[
             smem, smem, smem,                                   # seed, off, scal
             pl.BlockSpec((None, Np, 4), lambda r, i: (r, 0, 0)),   # vecs
@@ -266,18 +288,22 @@ def _pallas_batched(seed, off, scal, vecs, W, p2, g2, *, gamma, eta, noisy,
             tile, tile,
         ],
         out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+        out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        # the round updates the buffer in place: each tile writes only the
+        # columns it read, so the output may take p's buffer, and a caller
+        # whose p dies here (the scan body) needs no copy of it for the call
+        input_output_aliases={5: 0},
         name="dp_mix_round",
-    )(seed, off, scal.reshape(-1), vecs, W, p2, g2)
+    )(seed, off, scal.reshape(-1), vecs, W, p, g)
 
 
 def _replicated_kernel(**statics):
     """``batched(seed[R], off[R], scal[R, 2], vecs[R, Np, 4], W[R, Np, Np],
-    p2[R, Np, Dp], g2[R, Np, Dp])`` under a custom vmap rule: a vmapped
+    p[R, N, d], g[R, N, d])`` under a custom vmap rule: a vmapped
     caller (the fleet's replicate axis) folds its axis into R instead of
     letting pallas_call's generic batching block the 1-D operands, which
     the TPU lowering refuses. Nested vmaps fold into R the same way."""
@@ -298,24 +324,28 @@ def _replicated_kernel(**statics):
     return batched
 
 
-def dp_mix_2d(p2, g2, seed, off, scal, amp, selfs, mscale, listen, W, *,
+def dp_mix_2d(p, g, seed, off, scal, amp, selfs, mscale, listen, W, *,
               gamma, eta, noisy, block_d, counter_width=None,
               interpret=True):
-    """Pallas entry point. p2, g2: [Np, Dp] padded views (Np multiple of
-    SUBLANES, Dp multiple of block_d). Vector operands are [Np]; ``scal``
-    = [c, σ_m]; ``off`` the [1] int32 global column offset of this window
-    (0 for the whole buffer) and ``counter_width`` the canonical noise-
-    counter stride (defaults to Dp — the whole-buffer layout). Returns the
-    updated [Np, Dp] buffer (same dtype as p2). Under vmap the replicate
-    axis becomes the kernel's own grid axis (``_replicated_kernel``)."""
-    Np, Dp = p2.shape
+    """Pallas entry point. p, g: the [N, d] buffers as they are — no pad,
+    no slice: the grid is cdiv(d, block_d) column tiles of all N rows,
+    the last one ragged. Vector operands are [Np] and W is [Np, Np], Np
+    the sublane multiple of N (zero-padded); ``scal`` = [c, σ_m]; ``off``
+    the [1] int32 global column offset of this window (0 for the whole
+    buffer) and ``counter_width`` the canonical noise-counter stride of
+    the interpreter's draws (defaults to roundup(d, LANES), the jnp
+    lowering's, so the two draw the same fields). Returns the updated
+    [N, d] buffer (same dtype as p). Under vmap the replicate axis becomes
+    the kernel's own grid axis (``_replicated_kernel``)."""
+    d = p.shape[1]
     batched = _replicated_kernel(
         gamma=gamma, eta=eta, noisy=noisy, block_d=block_d,
-        d_padded=Dp if counter_width is None else counter_width,
+        d_padded=(-(-d // LANES) * LANES if counter_width is None
+                  else counter_width),
         interpret=interpret)
     vecs = jnp.stack([amp, selfs, mscale, listen], axis=-1)
     out = batched(seed.reshape(1), off.reshape(1), scal.reshape(1, 2),
-                  vecs[None], W[None], p2[None], g2[None])
+                  vecs[None], W[None], p[None], g[None])
     return out[0]
 
 

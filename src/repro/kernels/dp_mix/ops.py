@@ -38,15 +38,23 @@ def _roundup(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _pallas_block_d(d: int, counter_width) -> int:
-    """The TPU column tile: 512 lanes, or for a sharded window
-    (counter_width set) the widest of 512/256/128 that DIVIDES the window
-    width, so the global block index (col0 // block_d + pid) tiles without
-    collisions across shards."""
+def _pallas_block_d(Np: int, d: int, noisy: bool, counter_width=None) -> int:
+    """The TPU column tile, from the shape: as wide as the VMEM the ceiling
+    ``MAX_NP`` was measured in allows — a tile of Np rows holds at most the
+    area of the ceiling's (MAX_NP[noisy], CEILING_BLOCK_D) tile — capped at
+    ``K.BLOCK_D_CAP`` lanes, never below CEILING_BLOCK_D, and never wider
+    than the buffer. At Np = 16 that is the cap; at the ceiling it is 512.
+    For a sharded window (counter_width set) it is the widest multiple of
+    128 within that bound that DIVIDES the window width, so the global
+    block index (col0 // block_d + pid) tiles without collisions across
+    shards."""
+    area = K.MAX_NP[noisy] * K.CEILING_BLOCK_D
+    lanes = max(min(K.BLOCK_D_CAP, area // Np),
+                K.CEILING_BLOCK_D) // K.LANES
     if counter_width is not None and d % K.LANES == 0:
-        lanes = d // K.LANES
-        return next(c * K.LANES for c in (4, 2, 1) if lanes % c == 0)
-    return 4 * K.LANES
+        n = d // K.LANES
+        return K.LANES * max(c for c in range(1, lanes + 1) if n % c == 0)
+    return K.LANES * min(lanes, -(-d // K.LANES))
 
 
 def seed_from_key(key) -> jnp.ndarray:
@@ -98,6 +106,12 @@ def dp_mix_round(p, g, seed, W, amp, c, sigma_m, *, gamma: float, eta: float,
     "pallas_interpret" (the Pallas body executed by the interpreter —
     slow; kernel-validation only) | "jnp" (the fused-jnp CPU lowering,
     bitwise-identical draws to "pallas_interpret").
+
+    The Pallas kernel (and its interpreter) reads and writes p, g as they
+    are: column tiles of all N rows, ``block_d`` wide (default: from the
+    shape, ``_pallas_block_d``), the last one ragged; only W and the [N]
+    vectors are padded to Np = roundup(N, 8). The jnp lowering runs one
+    program on the padded [Np, Dp] view and slices the result back.
     """
     N, d = p.shape
     if impl is None:
@@ -111,16 +125,6 @@ def dp_mix_round(p, g, seed, W, amp, c, sigma_m, *, gamma: float, eta: float,
             f"(N <= {K.MAX_NP[noisy]} {'with' if noisy else 'without'} "
             f"noise: the [N, N] mixing matrix is VMEM-resident); mix "
             f"through a sparse neighbor list (--sparse-neighbors) at this N")
-    if block_d is None:
-        if impl == "pallas":
-            block_d = _pallas_block_d(d, counter_width)
-        else:
-            # one program off-TPU (no grid to amortize)
-            block_d = _roundup(d, K.LANES)
-    Dp = _roundup(d, block_d)
-
-    p2 = jnp.pad(p, ((0, Np - N), (0, Dp - d)))
-    g2 = jnp.pad(g, ((0, Np - N), (0, Dp - d)))
     W2 = jnp.pad(jnp.asarray(W, jnp.float32), ((0, Np - N), (0, Np - N)))
     c = jnp.asarray(c, jnp.float32).reshape(())
     scal = jnp.stack([c, jnp.asarray(sigma_m, jnp.float32).reshape(())])
@@ -135,15 +139,21 @@ def dp_mix_round(p, g, seed, W, amp, c, sigma_m, *, gamma: float, eta: float,
     off = jnp.asarray(col0, jnp.int32).reshape(1)
 
     if impl == "jnp":
-        out2 = K.dp_mix_fused_jnp(p2, g2, seed, off, scal, amp2, selfs,
-                                  mscale, lst, W2, gamma=gamma, eta=eta,
-                                  noisy=noisy, counter_width=counter_width)
-    else:
-        out2 = K.dp_mix_2d(p2, g2, seed, off, scal, amp2, selfs, mscale,
-                           lst, W2, gamma=gamma, eta=eta, noisy=noisy,
-                           block_d=block_d, counter_width=counter_width,
-                           interpret=(impl == "pallas_interpret"))
-    return out2[:N, :d].astype(p.dtype)
+        # one program off-TPU (no grid to amortize), on the padded [Np, Dp]
+        # view; the Pallas kernel reads and writes the [N, d] buffer as it is
+        Dp = _roundup(d, block_d or K.LANES)
+        pad = ((0, Np - N), (0, Dp - d))
+        out = K.dp_mix_fused_jnp(jnp.pad(p, pad), jnp.pad(g, pad), seed, off,
+                                 scal, amp2, selfs, mscale, lst, W2,
+                                 gamma=gamma, eta=eta, noisy=noisy,
+                                 counter_width=counter_width)
+        return out[:N, :d]
+    if block_d is None:
+        block_d = _pallas_block_d(Np, d, noisy, counter_width)
+    return K.dp_mix_2d(p, g, seed, off, scal, amp2, selfs, mscale, lst, W2,
+                       gamma=gamma, eta=eta, noisy=noisy, block_d=block_d,
+                       counter_width=counter_width,
+                       interpret=(impl == "pallas_interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("gamma", "eta", "noisy",
@@ -159,9 +169,9 @@ def dp_mix_round_sparse(p, g, seed, sw, amp, c, sigma_m, *, gamma: float,
     Same contract as :func:`dp_mix_round` with ``sw`` replacing ``W``.
     The [Np, Dp] padding, counter stride, ``col0``/``counter_width``
     window hooks, and the seed→counter mapping are IDENTICAL to the dense
-    wrapper, so both paths draw bitwise-equal noise fields and the dense
-    round remains the small-N reference (sparse results differ only by
-    slot-order summation ULPs — tests/test_sparse.py). It runs as an XLA
+    jnp lowering, so both paths draw bitwise-equal noise fields and the
+    dense round remains the small-N reference (sparse results differ only
+    by slot-order summation ULPs — tests/test_sparse.py). It runs as an XLA
     gather on every backend, TPU included: there is no Pallas body (see
     dp_mix.dp_mix_sparse_jnp), so ``impl`` accepts "jnp"/None and refuses
     a Pallas request rather than silently running the gather instead.
@@ -208,8 +218,8 @@ def dp_mix_round_plan(p, g, seed, plan, *, gamma: float, eta: float,
     on the plan's W: a dense [N, N] array runs the dense kernel, a
     repro.net.sparse.SparseW neighbor list runs the O(N·k) sparse round
     (an XLA gather: a Pallas ``impl`` is refused there). Everything it
-    runs (the pads to [Np, Dp], the kernel, the slice back) sits in the
-    ``dwfl.mix`` named scope."""
+    runs (the kernel and its small operands; off-TPU the pads to
+    [Np, Dp] and the slice back) sits in the ``dwfl.mix`` named scope."""
     from repro.net.sparse import SparseW
     mix = dp_mix_round_sparse if isinstance(plan.W, SparseW) else dp_mix_round
     with jax.named_scope("dwfl.mix"):

@@ -179,14 +179,28 @@ def test_dp_mix_gossip_noiseless_path():
                                rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("d,counter_width,block_d", [
-    (855_050, None, 512), (4 * 128 * 3, 4 * 128 * 12, 512),
-    (2 * 128 * 3, 2 * 128 * 12, 256), (128 * 3, 128 * 12, 128)])
-def test_dp_mix_tile_width_from_vmem_ceiling(d, counter_width, block_d):
-    """The TPU tile is 512 columns — the width the VMEM ceiling
-    (dp_mix.MAX_NP) is measured at — narrowed for a sharded window only
-    as far as it takes to divide the window."""
-    assert mix_ops._pallas_block_d(d, counter_width) == block_d
+@pytest.mark.parametrize("Np,d,noisy,counter_width,block_d", [
+    # at the VMEM ceiling the tile is the 512 columns it was measured at
+    (1024, 855_050, True, None, 512), (1504, 855_050, False, None, 512),
+    # sharded windows: the widest multiple of 128 within the bound that
+    # divides the window
+    (1024, 4 * 128 * 3, True, 4 * 128 * 12, 512),
+    (1024, 2 * 128 * 3, True, 2 * 128 * 12, 384),
+    (1024, 128 * 3, True, 128 * 12, 384),
+    (16, 213_760, True, 855_168, 1280),        # the paper's 4-way shard
+    # the paper's buffer (N = 10): the widest tile, noisy or gossip
+    (16, 855_050, True, None, 16384), (16, 855_050, False, None, 16384),
+    # constant area below the ceiling; never wider than the buffer
+    (512, 855_050, True, None, 1024), (16, 300, True, None, 384)])
+def test_dp_mix_tile_width_from_vmem_ceiling(Np, d, noisy, counter_width,
+                                            block_d):
+    """The TPU tile holds at most the area of the ceiling's (MAX_NP, 512)
+    tile (dp_mix.MAX_NP is measured at 512 columns), up to BLOCK_D_CAP
+    columns, narrowed for a sharded window only as far as it takes to
+    divide the window."""
+    from repro.kernels.dp_mix import dp_mix as K
+    assert K.BLOCK_D_CAP == 16384
+    assert mix_ops._pallas_block_d(Np, d, noisy, counter_width) == block_d
 
 
 @pytest.mark.parametrize("N,noisy,block_d", [
@@ -231,6 +245,46 @@ def test_dp_mix_vmapped_kernel_matches_per_replicate():
     np.testing.assert_array_equal(
         np.asarray(shared[1]),
         np.asarray(f(p[1], g[1], seed[0], W[1], amp[1], c[1], sm[1])))
+
+
+@pytest.mark.parametrize("noisy", [True, False], ids=["noisy", "gossip"])
+def test_dp_mix_unpadded_kernel_matches_jnp_windows(noisy):
+    """The Pallas path reads the [N, d] buffer as it is (N = 10 is no
+    sublane multiple; d = 1000 leaves a ragged last tile of 232 of 256
+    columns). Each column tile draws the jnp lowering's counters (stride
+    roundup(d, 128)) and computes its arithmetic: bitwise the jnp
+    lowering of that window (``col0``/``counter_width``, the shard
+    hooks), alone and under vmap with R = 3 replicates."""
+    N, d, bd = 10, 1000, 256
+    k = jax.random.split(jax.random.PRNGKey(1), 3)
+    R = 3
+    p = jax.random.normal(k[0], (R, N, d))
+    g = jax.random.normal(k[1], (R, N, d))
+    W = jax.random.uniform(k[2], (R, N, N))
+    W = W / W.sum(-1, keepdims=True)
+    amp = jnp.linspace(0.5, 1.5, R * N).reshape(R, N)
+    c, sm = jnp.array([1.0, 2.0, 3.0]), jnp.array([0.1, 0.3, 0.5])
+    seed = jnp.arange(R, dtype=jnp.int32) + 7
+    kw = dict(gamma=0.1, eta=0.4, noisy=noisy)
+
+    def kernel(p, g, s, W, a, c, sm):
+        return mix_ops.dp_mix_round(p, g, s, W, a, c, sm, block_d=bd,
+                                    impl="pallas_interpret", **kw)
+
+    def jnp_windows(p, g, s, W, a, c, sm):
+        return np.concatenate([np.asarray(mix_ops.dp_mix_round(
+            p[:, j:j + bd], g[:, j:j + bd], s, W, a, c, sm, impl="jnp",
+            block_d=bd, col0=j, counter_width=1024, **kw))
+            for j in range(0, d, bd)], axis=1)
+
+    want = np.stack([jnp_windows(p[r], g[r], seed[r], W[r], amp[r], c[r],
+                                 sm[r]) for r in range(R)])
+    assert want.shape == (R, N, d)
+    one = kernel(p[0], g[0], seed[0], W[0], amp[0], c[0], sm[0])
+    assert one.shape == (N, d)
+    np.testing.assert_array_equal(np.asarray(one), want[0])
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(kernel)(p, g, seed, W, amp, c, sm)), want)
 
 
 def test_dp_mix_sparse_plan_refuses_pallas():

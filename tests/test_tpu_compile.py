@@ -10,6 +10,7 @@ persistent compilation cache is off around the compiles (an entry compiled
 for a described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,46 @@ def test_dense_round_compiles_at_paper_width(S, N, noisy):
     assert "tpu_custom_call" in _hlo(step, *_round_args(S, N, D_PAPER))
 
 
+def _shapes(text):
+    """{instruction name: its result shape} of an HLO module's text."""
+    out = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])", ln)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+@pytest.mark.parametrize("noisy", [True, False], ids=["noisy", "gossip"])
+def test_dense_round_reads_buffer_in_place(S, noisy):
+    """At the paper's buffer the kernel takes p and g as they are, at the
+    tile width ops._pallas_block_d picks: the custom call's operands and
+    result are the [10, 855050] buffer (with the kernel's replicate axis),
+    nothing pads that buffer around it, and a caller that gives up p (as
+    the scan body does) gets the update in p's buffer, with no copy."""
+    N = 10
+
+    def step(p, g, seed, W, amp, c, sm):
+        return ops.dp_mix_round(p, g, seed, W, amp, c, sm, gamma=0.01,
+                                eta=0.4, noisy=noisy, impl="pallas")
+    text = jax.jit(step, donate_argnums=0).lower(
+        *_round_args(S, N, D_PAPER)).compile().as_text()
+    shapes = _shapes(text)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert len(calls) == 1
+    buf = {f"f32[{N},{D_PAPER}]", f"f32[1,{N},{D_PAPER}]"}
+    operands = re.findall(r"%([\w.\-]+)",
+                          calls[0].split(" custom-call(", 1)[1].split(")")[0])
+    assert shapes[re.match(r"\s*(?:ROOT )?%(\S+)", calls[0]).group(1)] in buf
+    assert sum(shapes[o] in buf for o in operands) == 2, operands
+    around = [ln for ln in text.splitlines()
+              if re.search(r" (pad|copy|copy-start)\(", ln)
+              and str(D_PAPER) in ln.split("(")[0]]
+    assert not around, around
+    assert ops._pallas_block_d(16, D_PAPER, noisy) == K.BLOCK_D_CAP
+
+
 def test_fleet_round_compiles_at_r8(S):
     """The fleet vmaps the round over its replicates; the kernel takes the
     replicate axis as its own grid axis."""
@@ -117,7 +158,8 @@ def test_dense_vmem_ceiling_matches_compiler(S, noisy, past):
     compiler runs out of VMEM (the round refuses that N before compiling —
     tests/test_kernels.py — so this calls the kernel directly). Four
     column tiles, so the tiles are double-buffered as in a real round."""
-    Np, bd = K.MAX_NP[noisy] + (K.SUBLANES if past else 0), 512
+    Np = K.MAX_NP[noisy] + (K.SUBLANES if past else 0)
+    bd = K.CEILING_BLOCK_D
 
     def kernel(p, g, W, vec, seed, scal):
         return K.dp_mix_2d(p, g, seed, seed, scal, vec, vec, vec, vec, W,

@@ -7,9 +7,11 @@
 Run from the root of a checkout on a machine with the TPU chips the cell
 asks for. Everything about a cell is data found by name: the workload in
 ``BENCHMARK.json`` names a configuration (``bench/configs/<config>.json``:
-the sizes, the protocol, the channel, the reference and work-count modules)
-and a traffic mix (``bench/traffic/<traffic>.json``: chunk length and what
-the window reads back); its limits are ``bench/limits/<workload>.json`` and
+the sizes, the protocol, the channel, the reference and work-count
+modules; ``FLAGS`` says which of its keys reach the trainer, and the
+file's own ``described`` lists the keys no flag takes) and a traffic
+mix (``bench/traffic/<traffic>.json``: chunk length and what the window
+reads back); its limits are ``bench/limits/<workload>.json`` and
 each per-layer metric is a reader ``bench/metrics/<metric>.py``.
 
 A run:
@@ -112,25 +114,80 @@ def part(cell, kind, name):
     return load_module(cell["root"] / "bench" / kind / f"{name}.py")
 
 
+# What of a configuration's groups reaches the trainer: each key with its
+# flag, in the order of the command line, and its kind. A required key
+# must be in the file; an optional one maps to its flag only where the
+# file gives it, and the trainer keeps its own default otherwise (without
+# ``hidden`` the architecture keeps its own width); a switch is true or
+# false, and true gives the bare flag.
+REQUIRED, OPTIONAL, SWITCH = "required", "optional", "switch"
+FLAGS = (
+    ("model", "arch", "--arch", REQUIRED),
+    ("model", "hidden", "--hidden", OPTIONAL),
+    ("model", "seq_len", "--seq-len", OPTIONAL),
+    ("protocol", "scheme", "--scheme", REQUIRED),
+    ("protocol", "workers", "--workers", REQUIRED),
+    ("protocol", "batch_size", "--batch-size", REQUIRED),
+    ("data", "size", "--dataset-size", OPTIONAL),
+    ("data", "dirichlet_alpha", "--dirichlet-alpha", OPTIONAL),
+    ("protocol", "gamma", "--gamma", REQUIRED),
+    ("protocol", "eta", "--eta", REQUIRED),
+    ("protocol", "clip", "--clip", REQUIRED),
+    ("protocol", "epsilon", "--epsilon", REQUIRED),
+    ("protocol", "sigma_m", "--sigma-m", REQUIRED),
+    ("protocol", "p_dbm", "--p-dbm", REQUIRED),
+    ("protocol", "accountant", "--accountant", REQUIRED),
+    ("protocol", "model_shards", "--model-shards", OPTIONAL),
+    ("protocol", "max_chunk_cols", "--max-chunk-cols", OPTIONAL),
+    ("channel", "model", "--channel-model", REQUIRED),
+    ("protocol", "remat", "--remat", SWITCH),
+)
+
+
+def check_keys(cfg):
+    """Every key of the groups the trainer reads is a flag's or one that
+    the configuration's own ``described`` lists (the sizes and precisions
+    that the reference and the work counts read and no flag takes): a
+    misspelt key would otherwise leave the trainer at its default and
+    measure something else."""
+    described = cfg.get("described", {})
+    for group in dict.fromkeys(g for g, *_ in FLAGS):
+        if not isinstance(cfg.get(group), dict):
+            raise BenchError(f"the configuration has no {group!r} group")
+        known = {k for g, k, *_ in FLAGS if g == group}
+        unknown = sorted(set(cfg[group]) - known
+                         - set(described.get(group, ())))
+        if unknown:
+            raise BenchError(f"the configuration's {group!r} group has keys "
+                             f"that no flag takes and its 'described' does "
+                             f"not list: {unknown}")
+
+
 def train_argv(cfg, traffic, seed):
-    """``launch/train.py``'s command line for the configuration: the flat
-    buffer, the in-scan telemetry and RDP ledger, chunks of the traffic's
-    length, no eval, and no round run (``--steps -1``: rounds are steps + 1)
-    so that the first chunk goes through the window's own call."""
-    pr, model, data = cfg["protocol"], cfg["model"], cfg["data"]
-    return ["--arch", model["arch"], "--hidden", str(model["hidden"]),
-            "--scheme", pr["scheme"], "--workers", str(pr["workers"]),
-            "--batch-size", str(pr["batch_size"]),
-            "--dataset-size", str(data["size"]),
-            "--dirichlet-alpha", str(data["dirichlet_alpha"]),
-            "--gamma", str(pr["gamma"]), "--eta", str(pr["eta"]),
-            "--clip", str(pr["clip"]), "--epsilon", str(pr["epsilon"]),
-            "--sigma-m", str(pr["sigma_m"]), "--p-dbm", str(pr["p_dbm"]),
-            "--accountant", pr["accountant"],
-            "--channel-model", cfg["channel"]["model"],
-            "--flat-buffer", "--telemetry", "on",
-            "--seed", str(seed), "--steps", "-1", "--eval-every", "0",
-            "--chunk-rounds", str(traffic["chunk_rounds"])]
+    """``launch/train.py``'s command line for the configuration
+    (``FLAGS``): the flat buffer, the in-scan telemetry and RDP ledger,
+    chunks of the traffic's length, no eval, and no round run (``--steps
+    -1``: rounds are steps + 1) so that the first chunk goes through the
+    window's own call."""
+    check_keys(cfg)
+    argv = []
+    for group, key, flag, kind in FLAGS:
+        if key not in cfg[group]:
+            if kind == REQUIRED:
+                raise BenchError(f"the configuration gives no {group}.{key} "
+                                 f"({flag})")
+            continue
+        value = cfg[group][key]
+        if kind != SWITCH:
+            argv += [flag, str(value)]
+        elif not isinstance(value, bool):
+            raise BenchError(f"{group}.{key} is {value!r}, not true or "
+                             f"false")
+        elif value:
+            argv.append(flag)
+    return argv + ["--flat-buffer", "--telemetry", "on",
+                   "--seed", str(seed), "--steps", "-1", "--eval-every", "0",
+                   "--chunk-rounds", str(traffic["chunk_rounds"])]
 
 
 # -------------------------------------------------------------- set-up
@@ -198,14 +255,18 @@ def setup(cell, seed):
 
 def program_layout(cfg):
     """The program's flat-buffer layout for the configuration, through
-    which its buffer is read leaf by leaf."""
+    which its buffer is read leaf by leaf: the architecture resolved as the
+    trainer resolves it. Under ``model_shards`` the buffer is padded at its
+    end, past every leaf, so this unsharded layout reads its leaves too."""
     import dataclasses
     import jax
     from repro.configs.registry import get_arch
     from repro.core import exchange as X
     from repro.core import protocol as P
-    arch = dataclasses.replace(get_arch(cfg["model"]["arch"]),
-                               d_model=cfg["model"]["hidden"])
+    model = cfg["model"]
+    arch = get_arch(model["arch"])
+    if "hidden" in model:
+        arch = dataclasses.replace(arch, d_model=model["hidden"])
     return X.make_flat_spec(jax.eval_shape(
         lambda: P.init_worker_params(jax.random.PRNGKey(0), arch,
                                      cfg["protocol"]["workers"])))
@@ -290,7 +351,7 @@ def traced_window(live, traffic, seconds, keep=None):
         finally:
             jax.profiler.stop_trace()
         path = trace.find_trace(tmp)
-        res["summary"] = trace.summarize(trace.load(path))
+        res["summary"] = trace.read(path)
         res["trace_bytes"] = path.stat().st_size
         if keep:
             shutil.copy(path, keep)

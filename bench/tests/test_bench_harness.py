@@ -122,3 +122,120 @@ def test_a_new_config_traffic_and_metric_are_found_by_name(tmp_path):
     assert argv[argv.index("--workers") + 1] == "30"
     assert argv[argv.index("--chunk-rounds") + 1] == "7"
     assert argv[argv.index("--steps") + 1] == "-1"
+
+
+# launch/train.py's command line for dwfl-paper-n10-static with 5-round
+# chunks and seed 7, as the harness built it before the configuration's
+# keys became optional: the paper's cells must keep it to the element
+PAPER_ARGV = [
+    "--arch", "dwfl-paper", "--hidden", "256", "--scheme", "dwfl",
+    "--workers", "10", "--batch-size", "32", "--dataset-size", "20000",
+    "--dirichlet-alpha", "0.5", "--gamma", "0.01", "--eta", "0.4",
+    "--clip", "1.0", "--epsilon", "1.0", "--sigma-m", "0.0",
+    "--p-dbm", "60.0", "--accountant", "rdp", "--channel-model", "static",
+    "--flat-buffer", "--telemetry", "on", "--seed", "7", "--steps", "-1",
+    "--eval-every", "0", "--chunk-rounds", "5"]
+
+
+def test_the_paper_configuration_keeps_its_command_line():
+    cell = run.load_cell("paper_n10.curve")
+    assert run.train_argv(cell["cfg"], cell["traffic"], 7) == PAPER_ARGV
+
+
+def lm_config():
+    cfg = json.loads(json.dumps(PAPER))
+    del cfg["model"]["hidden"], cfg["data"]["size"]
+    del cfg["data"]["dirichlet_alpha"]
+    cfg["model"] = {"arch": "deepseek-moe-16b", "seq_len": 32,
+                    "state": "float32"}
+    cfg["protocol"].update(workers=4, model_shards=4, max_chunk_cols=4096,
+                           remat=True)
+    return cfg
+
+
+def test_optional_keys_map_to_their_flags_only_where_given():
+    argv = run.train_argv(lm_config(), {"chunk_rounds": 3}, 11)
+
+    def flag(name):
+        return argv[argv.index(name) + 1]
+    assert flag("--seq-len") == "32"
+    assert flag("--model-shards") == "4"
+    assert flag("--max-chunk-cols") == "4096"
+    assert "--remat" in argv
+    for absent in ("--hidden", "--dataset-size", "--dirichlet-alpha"):
+        assert absent not in argv
+    cfg = lm_config()
+    cfg["protocol"]["remat"] = False
+    assert "--remat" not in run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+
+
+@pytest.mark.parametrize("group,key", [("model", "seqlen"),
+                                       ("protocol", "model_shard"),
+                                       ("data", "sizes"),
+                                       ("channel", "modle")])
+def test_a_key_the_harness_does_not_know_is_refused_by_name(group, key):
+    cfg = lm_config()
+    cfg[group][key] = 1
+    with pytest.raises(run.BenchError, match=key):
+        run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+
+
+def test_a_key_the_configuration_describes_reaches_no_flag():
+    """A key no flag takes is the configuration's to list in its own
+    ``described``: listed, it is kept out of the command line; not
+    listed, it is refused by name."""
+    cfg = lm_config()
+    cfg["model"]["kv_lora_rank"] = 512
+    cfg["described"]["model"] = [*cfg["described"]["model"], "kv_lora_rank"]
+    argv = run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+    assert "512" not in argv
+    assert argv == run.train_argv(lm_config(), {"chunk_rounds": 3}, 11)
+    cfg["described"]["model"].remove("kv_lora_rank")
+    with pytest.raises(run.BenchError, match="kv_lora_rank"):
+        run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+
+
+def test_a_switch_that_is_not_true_or_false_is_refused():
+    cfg = lm_config()
+    cfg["protocol"]["remat"] = "false"
+    with pytest.raises(run.BenchError, match="remat"):
+        run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+
+
+def test_a_required_key_that_is_missing_is_refused_by_name():
+    cfg = lm_config()
+    del cfg["protocol"]["workers"]
+    with pytest.raises(run.BenchError, match="protocol.workers"):
+        run.train_argv(cfg, {"chunk_rounds": 3}, 11)
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+def test_the_layout_reads_the_leaves_of_a_model_sharded_buffer(shards):
+    """--model-shards pads the [W, d] buffer at its end, past every leaf
+    (shard/layout.py); the unsharded layout the harness builds reads the
+    sharded buffer's leaves as the sharded layout does."""
+    import jax
+    import numpy as np
+    sys.path.insert(0, str(ROOT / "src"))
+    from repro.core import exchange as X
+    cfg = json.loads(json.dumps(PAPER))
+    cfg["model"]["hidden"] = 40
+    cfg["protocol"]["workers"] = 3
+    spec = run.program_layout(cfg)
+    rng = np.random.default_rng(shards)
+    tree = jax.tree_util.tree_map(
+        lambda x: rng.standard_normal(x.shape).astype(np.float32),
+        jax.eval_shape(spec.unravel,
+                       jax.ShapeDtypeStruct((3, spec.d), np.float32)))
+    sharded = X.make_flat_spec(tree, n_shards=shards)
+    buf = sharded.flatten(tree)
+    assert buf.shape == (3, sharded.width) and sharded.width > spec.d
+    want = jax.tree_util.tree_leaves(tree)
+    for got in (jax.tree_util.tree_leaves(spec.unravel(buf)),
+                jax.tree_util.tree_leaves(sharded.unravel(buf))):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), w)
+    # one row, as the set-up reads the shared initial row
+    for g, w in zip(jax.tree_util.tree_leaves(spec.unravel(buf[0])), want):
+        np.testing.assert_array_equal(np.asarray(g), w[0])

@@ -43,6 +43,19 @@ class ModelConfig:
     qkv_bias: bool = False  # qwen2 / glm4
     sliding_window: Optional[int] = None  # if set: sliding-window attention
     learned_pos_emb: bool = False  # whisper decoder/encoder
+    # latent attention (deepseek-v2 MLA; repro.models.mla), on when
+    # kv_lora_rank > 0: keys and values come from one compressed latent
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # rotated part of q, and the one shared k
+    v_head_dim: int = 0
+    # YaRN rope scaling (rope_scaling.type == "yarn"), on when rope_factor > 1
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # -- MoE ----------------------------------------------------------------
     num_experts: int = 0
@@ -50,8 +63,14 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0  # per-expert hidden dim (fine-grained experts)
     first_dense_layers: int = 0  # deepseek-moe: layer 0 is a dense FFN
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
+    router_aux_weight: float = 0.01  # per MoE layer, summed over layers
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0
+    # the chip's share of an expert-parallel layer: experts
+    # [first_expert, first_expert + experts_held) are held here, the router
+    # keeps all num_experts outputs (0 = every expert held)
+    experts_held: int = 0
+    first_expert: int = 0
 
     # -- SSM (mamba2 / xlstm) -----------------------------------------------
     ssm_state: int = 0  # N, state dim per head
@@ -86,6 +105,11 @@ class ModelConfig:
         return self.head_dim if self.head_dim is not None else self.d_model // self.num_heads
 
     @property
+    def experts_local(self) -> int:
+        """Routed experts this layer holds (all of them unless cut)."""
+        return self.experts_held or self.num_experts
+
+    @property
     def is_subquadratic(self) -> bool:
         """Can this config serve a 500k-token context (O(S) state, no dense KV)?"""
         if self.family in ("ssm", "hybrid"):
@@ -112,7 +136,8 @@ class ModelConfig:
             small.update(num_experts=4,
                          num_experts_per_tok=min(2, self.num_experts_per_tok),
                          num_shared_experts=min(1, self.num_shared_experts),
-                         moe_d_ff=min(self.moe_d_ff, 128))
+                         moe_d_ff=min(self.moe_d_ff, 128),
+                         experts_held=0, first_expert=0)
         if self.ssm_state:
             small.update(ssm_state=16, ssm_heads=0, ssm_chunk=32)
         if self.slstm_every:
@@ -125,6 +150,38 @@ class ModelConfig:
             small.update(sliding_window=32)
         small.update(kw)
         return self.replace(**small)
+
+    def cut(self, *, moe_layers: int, experts_held: int, vocab_size: int,
+            first_expert: int = 0) -> "ModelConfig":
+        """One chip's share of an expert-parallel deployment at the
+        published widths (model-configs guide §4): the leading dense layers
+        and ``moe_layers`` of the MoE layers after them (the rest lie on
+        further pipeline stages), experts [first_expert, first_expert +
+        experts_held) of each MoE layer (the router keeps every output) and
+        a ``vocab_size`` slice of the vocabulary. No width changes. Refuses
+        a cut below the guide's floors: 4 MoE layers, 8 routed experts, an
+        eighth of the vocabulary."""
+        if not self.num_experts:
+            raise ValueError(f"{self.name} has no routed experts to hold")
+        n_moe = self.num_layers - self.first_dense_layers
+        if not 4 <= moe_layers <= n_moe:
+            raise ValueError(f"moe_layers={moe_layers}: keep 4 to {n_moe}")
+        if not 8 <= experts_held <= self.num_experts:
+            raise ValueError(f"experts_held={experts_held}: hold 8 to "
+                             f"{self.num_experts}")
+        if not 0 <= first_expert <= self.num_experts - experts_held:
+            raise ValueError(f"experts {first_expert}.."
+                             f"{first_expert + experts_held - 1} are not all "
+                             f"among the {self.num_experts}")
+        if not self.vocab_size <= 8 * vocab_size <= 8 * self.vocab_size:
+            raise ValueError(f"vocab_size={vocab_size}: keep an eighth "
+                             f"({-(-self.vocab_size // 8)}) to "
+                             f"{self.vocab_size}")
+        return self.replace(
+            num_layers=self.first_dense_layers + moe_layers,
+            experts_held=(0 if experts_held == self.num_experts
+                          else experts_held),
+            first_expert=first_expert, vocab_size=vocab_size)
 
 
 @dataclass(frozen=True)

@@ -15,6 +15,7 @@ from repro.configs import (
     glm4_9b,
     whisper_medium,
     deepseek_moe_16b,
+    deepseek_v2_lite,
     dwfl_paper,
 )
 
@@ -29,11 +30,18 @@ ARCHS: Dict[str, ModelConfig] = {
     "glm4-9b": glm4_9b.CONFIG,
     "whisper-medium": whisper_medium.CONFIG,
     "deepseek-moe-16b": deepseek_moe_16b.CONFIG,
+    # latent attention; the cut is one chip's share of an expert-parallel
+    # worker (the benchmark's deepseek-v2-lite-w4 configuration)
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
+    "deepseek-v2-lite-ep8": deepseek_v2_lite.EP8,
     # the paper's own experimental scale (small model, N workers on CIFAR-like data)
     "dwfl-paper": dwfl_paper.CONFIG,
 }
 
-ASSIGNED = [a for a in ARCHS if a != "dwfl-paper"]
+# the assigned grid: neither the paper's scale nor the training-only
+# latent-attention configs (no serving cache to prefill or decode)
+ASSIGNED = [a for a in ARCHS
+            if a != "dwfl-paper" and not ARCHS[a].kv_lora_rank]
 
 # (arch, shape) combinations that are skipped BY DESIGN (recorded in DESIGN.md):
 # long_500k needs sub-quadratic attention or recurrent state.

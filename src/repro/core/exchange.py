@@ -655,10 +655,18 @@ class FlatSpec:
         return flat[..., :self.d]
 
     def unravel(self, flat) -> Tree:
+        """The worker-stacked tree of a buffer, as one compiled program (a
+        slice and a reshape a leaf); a buffer whose columns are sharded is
+        first laid out with whole rows on each device."""
+        if getattr(self, "_unravel_jit", None) is None:
+            self._unravel_jit = jax.jit(self._unravel)
+        return self._unravel_jit(_whole_columns(flat))
+
+    def _unravel(self, flat) -> Tree:
         out, off = [], 0
         lead = flat.shape[:-1]
         for s, dt, n in zip(self._shapes, self._dtypes, self._sizes):
-            out.append(flat[..., off:off + n]
+            out.append(jax.lax.slice_in_dim(flat, off, off + n, axis=-1)
                        .reshape(lead + s[self.lead_axes:]).astype(dt))
             off += n
         return jax.tree_util.tree_unflatten(self._treedef, out)
@@ -682,6 +690,27 @@ class FlatSpec:
         if self.layout is not None:
             meta["chunk_plan"] = self.chunk_plan.to_meta()
         return meta
+
+
+def _whole_columns(flat):
+    """A buffer whose columns are sharded over devices (the model axis),
+    laid out anew with whole rows on each device (one row: on every
+    device), so the leaves ``unravel`` slices out of it stay where their
+    rows are; slicing the sharded columns would gather every leaf onto
+    every device. Anything else passes through unchanged."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    sh = getattr(flat, "sharding", None)
+    if isinstance(flat, jax.core.Tracer) or not isinstance(sh, NamedSharding):
+        return flat
+    spec = tuple(sh.spec) + (None,) * (flat.ndim - len(sh.spec))
+    if spec[-1] is None:
+        return flat
+    axes = spec[-1] if isinstance(spec[-1], tuple) else (spec[-1],)
+    size = int(np.prod([sh.mesh.shape[a] for a in axes]))
+    rows = (None,) * (flat.ndim - 2) + (spec[-1], None)
+    ok = flat.ndim >= 2 and flat.shape[-2] % size == 0
+    return jax.device_put(flat, NamedSharding(
+        sh.mesh, PartitionSpec(*rows) if ok else PartitionSpec()))
 
 
 def make_flat_spec(template: Tree, lead_axes: int = 1, layout=None,

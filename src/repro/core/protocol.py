@@ -462,17 +462,19 @@ def _make_flat_local_pass(cfg: ModelConfig, proto: ProtocolConfig,
     """Per-worker clipped gradients ON THE FLAT BUFFER: each worker's loss
     is a function of its flat [d] row (autodiff carries the ravel — no
     explicit per-round concatenate), and the L2 clip is one vector norm.
-    ``remat`` wraps the per-worker value_and_grad target in
-    jax.checkpoint: activations are recomputed in the backward pass, so
-    the grad pass's live set stays ~O(params + one layer) per worker —
-    the knob the sharded round exposes for big models."""
+    ``remat`` turns on the model's layer-level checkpoint (``cfg.remat``:
+    each block of the layer scans is recomputed in the backward pass), so
+    the saved activations are one residual-stream input per layer plus
+    the one layer being differentiated — the knob the sharded round
+    exposes for big models. A model without layer scans (the MLP) saves
+    nothing by it."""
     clip = proto.clip
+    if remat:
+        cfg = cfg.replace(remat=True)
 
     def local_grads(flat, batch):
         def one(fv, b):
             target = lambda v: M.loss_fn(unravel_row(v), b, cfg)
-            if remat:
-                target = jax.checkpoint(target)
             loss, g = jax.value_and_grad(target)(fv)
             g, gnorm = privacy.clip_gradient_tree(g, clip)
             return loss, g, gnorm

@@ -105,9 +105,9 @@ def make_round_body(cfg, proto, store, *, sim=None, fleet=None,
     and the store's batches are row-sharded; channel/W stay replicated.
     Mutually exclusive with a model-sharded ``spec`` for now.
 
-    ``remat`` (sharded specs only) rematerializes each worker's forward
-    in the backward pass of the gather-free grad block — the big-model
-    knob; a no-op on unsharded paths.
+    ``remat`` (sharded specs only) turns on the model's layer-level
+    checkpoint in the gather-free grad block (each layer recomputed in
+    the backward pass) — the big-model knob; a no-op on unsharded paths.
 
     ``telemetry`` (obs.telemetry.TelemetrySpec) wraps the built body in
     pure read-only instrumentation: the enabled per-round scalars are

@@ -64,6 +64,29 @@ class TrainResult(NamedTuple):
     carry: Optional[TJ.TrajCarry]
 
 
+def _sharded_initial_buffer(key, cfg, n_workers, spec, mesh):
+    """The initial [W, width] flat buffer, built straight into its
+    model-sharded layout: the workers' shared initial weights once, then
+    on each device its own column window of their row, broadcast over the
+    W rows, so no device ever holds the whole buffer. The same values as
+    ``spec.flatten(init_worker_params(key, cfg, W))``. The weights are
+    drawn outside the compiled window program: fused into its
+    concatenation, the draws take minutes to compile at 535 M
+    parameters."""
+    from jax.sharding import PartitionSpec
+    sw = spec.layout.shard_width
+
+    def window(tree):
+        row = spec.flatten(tree)[0]
+        col0 = jax.lax.axis_index("model") * sw
+        win = jax.lax.dynamic_slice_in_dim(row, col0, sw)
+        return jnp.broadcast_to(win[None], (n_workers, sw))
+    return jax.jit(jax.shard_map(
+        window, mesh=mesh, in_specs=PartitionSpec(),
+        out_specs=PartitionSpec(None, "model"), check_vma=False))(
+        P.init_worker_params(key, cfg, 1))
+
+
 def main(argv=None) -> TrainResult:
     with obs.span("dwfl.main"):
         return _main(argv)
@@ -165,11 +188,12 @@ def _main(argv) -> TrainResult:
                          "0 = unbounded (one chunk per leaf x window "
                          "intersection). Requires --model-shards > 1.")
     ap.add_argument("--remat", action="store_true",
-                    help="rematerialize each worker's forward in the "
-                         "backward pass of the sharded grad block "
-                         "(jax.checkpoint): trades compute for activation "
+                    help="checkpoint each layer of the model's layer scans "
+                         "in the sharded grad block (its activations are "
+                         "recomputed in the backward pass, only the layer "
+                         "inputs are saved): trades compute for activation "
                          "memory on big configs. Requires "
-                         "--model-shards > 1.")
+                         "--model-shards > 1 or --worker-shards > 1.")
     ap.add_argument("--chunk-rounds", type=int, default=0,
                     help="scan-fused trajectory engine: rounds compiled "
                          "into one lax.scan dispatch (0 = auto: one "
@@ -357,15 +381,24 @@ def _main(argv) -> TrainResult:
             n_params = (sum(int(x.size)
                             for x in jax.tree_util.tree_leaves(wp))
                         // (W * fleet.replicates))
+    elif proto.flat_buffer:
+        from repro.core import exchange as X
+        spec = X.make_flat_spec(
+            jax.eval_shape(lambda: P.init_worker_params(key, cfg, W)),
+            n_shards=n_shards, max_chunk_cols=max_chunk_cols)
+        unravel, unravel_row = spec.unravel, spec.unravel_row
+        n_params = spec.d
+        with obs.span("dwfl.main.params"):
+            if n_shards > 1 and jax.device_count() >= n_shards:
+                from repro.launch import mesh as mesh_lib
+                shard_mesh = mesh_lib.make_shard_mesh(n_shards)
+                wp = _sharded_initial_buffer(key, cfg, W, spec, shard_mesh)
+            else:
+                wp = spec.flatten(P.init_worker_params(key, cfg, W))
     else:
-        wp = P.init_worker_params(key, cfg, W)
+        with obs.span("dwfl.main.params"):
+            wp = P.init_worker_params(key, cfg, W)
         n_params = sum(int(x.size) for x in jax.tree_util.tree_leaves(wp)) // W
-        if proto.flat_buffer:
-            from repro.core import exchange as X
-            spec = X.make_flat_spec(wp, n_shards=n_shards,
-                                    max_chunk_cols=max_chunk_cols)
-            unravel, unravel_row = spec.unravel, spec.unravel_row
-            wp = spec.flatten(wp)
     if worker_mesh is not None:
         from jax.sharding import NamedSharding
         from repro.shard.worker import worker_partition_spec
@@ -376,14 +409,15 @@ def _main(argv) -> TrainResult:
         # exist; otherwise shard logically inside one device's program
         from repro.launch import mesh as mesh_lib
         from repro.launch import shardings as shardings_lib
-        if jax.device_count() >= spec.n_shards:
+        if shard_mesh is not None:
+            where = f"{spec.n_shards}-device model mesh"
+        elif jax.device_count() >= spec.n_shards:
             # fleet: 2-D (replicas=1, model=S) mesh — replicates stay
             # vmapped within each model group
-            shard_mesh = mesh_lib.make_shard_mesh(
-                spec.n_shards, n_replicas=1 if fleet is not None else None)
+            shard_mesh = mesh_lib.make_shard_mesh(spec.n_shards,
+                                                  n_replicas=1)
             wp = jax.device_put(wp, shardings_lib.flat_buffer_sharding(
-                spec, shard_mesh,
-                replicate_axis="replicas" if fleet is not None else None))
+                spec, shard_mesh, replicate_axis="replicas"))
             where = f"{spec.n_shards}-device model mesh"
         elif jax.default_backend() != "cpu":
             raise SystemExit(f"--model-shards {spec.n_shards} needs "
@@ -520,6 +554,13 @@ def _main(argv) -> TrainResult:
         eps0 = (obs.init_eps_moments(
                     fleet.replicates if fleet is not None else None)
                 if tele is not None and tele.epsilon else None)
+        if shard_mesh is not None and fleet is None:
+            # the chunk program hands the key and the ledger back
+            # replicated over the mesh: hand them in so, or the second
+            # chunk compiles again for the new placement of its inputs
+            from jax.sharding import NamedSharding, PartitionSpec
+            key, eps0 = jax.device_put(
+                (key, eps0), NamedSharding(shard_mesh, PartitionSpec()))
         carry = TJ.TrajCarry(key, wp, net_state, eps0)
         eps_dog = (obs.EpsilonBudgetWatchdog(
                        args.eps_budget,

@@ -128,6 +128,9 @@ def apply_mrope(x, positions_thw, theta: float, sections: Tuple[int, ...]):
 
 
 def attention_init(key, cfg: ModelConfig, dtype):
+    if cfg.kv_lora_rank:
+        from repro.models import mla
+        return mla.init(key, cfg, dtype)
     hd = cfg.resolved_head_dim
     kq, kk, kv, ko = jax.random.split(key, 4)
     p = {
@@ -190,9 +193,11 @@ def _gqa_out(probs, v, H):
     return o.reshape(B, Sq, H, v.shape[-1])
 
 
-def _plain_causal_attention(q, k, v, cfg: ModelConfig, q_offset=0):
-    hd = q.shape[-1]
-    scores = _gqa_scores(q, k) / math.sqrt(hd)  # [B,H,Sq,Sk]
+def _plain_causal_attention(q, k, v, cfg: ModelConfig, q_offset=0,
+                            scale=None):
+    """``scale`` multiplies the scores (default 1/sqrt(head dim))."""
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+    scores = _gqa_scores(q, k) * scale  # [B,H,Sq,Sk]
     Sq, Sk = scores.shape[-2], scores.shape[-1]
     qpos = jnp.arange(Sq) + q_offset
     kpos = jnp.arange(Sk)
@@ -204,7 +209,8 @@ def _plain_causal_attention(q, k, v, cfg: ModelConfig, q_offset=0):
     return _gqa_out(probs, v, cfg.num_heads)
 
 
-def _chunked_causal_attention(q, k, v, cfg: ModelConfig, q_block: int = 1024):
+def _chunked_causal_attention(q, k, v, cfg: ModelConfig, q_block: int = 1024,
+                              scale=None):
     """Memory-efficient exact-causal attention.
 
     Outer query-block loop is a Python loop (static), so block i attends only
@@ -212,6 +218,7 @@ def _chunked_causal_attention(q, k, v, cfg: ModelConfig, q_block: int = 1024):
     With a sliding window, each block attends only to its window extent.
     """
     B, S, H, hd = q.shape
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     n_blocks = S // q_block
     assert n_blocks * q_block == S, (S, q_block)
     outs = []
@@ -222,7 +229,7 @@ def _chunked_causal_attention(q, k, v, cfg: ModelConfig, q_block: int = 1024):
             lo = max(0, (i + 1) * q_block - cfg.sliding_window - q_block)
         hi = (i + 1) * q_block
         ks, vs = k[:, lo:hi], v[:, lo:hi]
-        scores = _gqa_scores(qs, ks) / math.sqrt(hd)
+        scores = _gqa_scores(qs, ks) * scale
         qpos = jnp.arange(q_block) + i * q_block
         kpos = jnp.arange(lo, hi)
         mask = kpos[None, :] <= qpos[:, None]
@@ -267,8 +274,12 @@ def attention_apply(
     """mode: 'train' | 'prefill' | 'decode'.
 
     prefill additionally returns the filled KV cache; decode consumes/returns
-    the cache (functional update).
+    the cache (functional update). Latent attention (``kv_lora_rank`` > 0,
+    repro.models.mla) runs in training mode only.
     """
+    if cfg.kv_lora_rank:
+        from repro.models import mla
+        return mla.apply(params, x, cfg, positions, mode=mode), None
     B, S = x.shape[0], x.shape[1]
     q, k, v = _project_qkv(params, x, cfg)
     q, k = _rotate(q, k, cfg, positions)

@@ -2,8 +2,11 @@
 
 Identical attention trunk to the dense transformer; the FFN is a routed MoE
 (repro.models.moe), with optional shared experts and optional leading dense
-layers (deepseek-moe: first layer dense). Aux (load-balance) loss is
-accumulated through the layer scan and returned next to the logits.
+layers (deepseek-moe: first layer dense). Attention is the config's kind
+(latent attention when ``kv_lora_rank`` > 0). The balance loss is summed
+over the MoE layers through the layer scan and returned next to the
+logits. ``cfg.remat`` checkpoints each block of both layer scans in
+training.
 """
 from __future__ import annotations
 
@@ -65,6 +68,7 @@ def forward(params, batch, cfg: ModelConfig, *, mode="train",
     new_cache = {"dense": None, "moe": None}
 
     # --- leading dense blocks ---------------------------------------------
+    remat = cfg.remat and mode == "train"
     if n_dense:
         if mode == "decode":
             def dense_scan(h, bc):
@@ -75,9 +79,13 @@ def forward(params, batch, cfg: ModelConfig, *, mode="train",
             x, new_cache["dense"] = jax.lax.scan(
                 dense_scan, x, (params["dense_blocks"], cache["dense"]))
         else:
+            def dense_blk(h, blk):
+                return T.block_apply(blk, h, cfg, positions, mode)
+            if remat:
+                dense_blk = jax.checkpoint(dense_blk)
+
             def dense_scan(h, blk):
-                h, c = T.block_apply(blk, h, cfg, positions, mode)
-                return h, c
+                return dense_blk(h, blk)
             x, dc = jax.lax.scan(dense_scan, x, params["dense_blocks"])
             new_cache["dense"] = dc if mode == "prefill" else None
 
@@ -88,7 +96,7 @@ def forward(params, batch, cfg: ModelConfig, *, mode="train",
                                    cache=c, cache_index=cache_index)
         return (h, aux + a), c2
 
-    if cfg.remat and mode == "train":
+    if remat:
         def _blk(h, blk):
             h2, _, a = moe_block_apply(blk, h, cfg, positions, "train")
             return h2, a
@@ -100,7 +108,7 @@ def forward(params, batch, cfg: ModelConfig, *, mode="train",
         (x, aux), new_cache["moe"] = jax.lax.scan(
             moe_scan, (x, jnp.float32(0.0)), (params["moe_blocks"], cache["moe"]))
     else:
-        if cfg.remat and mode == "train":
+        if remat:
             def moe_scan(carry, blk):
                 h, aux = carry
                 h2, a = body(h, blk)
@@ -113,5 +121,4 @@ def forward(params, batch, cfg: ModelConfig, *, mode="train",
 
     x = L.norm_apply(params["final_norm"], x, cfg)
     logits = L.unembed_apply(params["embed"], x, cfg)
-    aux = aux / cfg.num_layers
     return logits, (new_cache if mode != "train" else None), aux

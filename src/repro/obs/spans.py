@@ -15,13 +15,17 @@ Nothing is written to disk. The spans the program opens:
 ==========================  ==============================================
 ``dwfl.main``               all of ``launch.train.main``
 ``dwfl.main.data``          its data set, Dirichlet split, batcher, store
+``dwfl.main.params``        the initial weights or flat buffer (on a model
+                            mesh: built in its sharded layout)
 ``dwfl.chunk.compile``      ``ChunkRunner.run``'s first call for a length
 ``dwfl.chunk.dispatch``     every later call (``rounds``, ``chunk`` attrs);
                             ends when the asynchronous call returns
 ==========================  ==============================================
 
-The device side carries ``jax.named_scope``s instead (``dwfl.grad``,
-``dwfl.mix``, ``dwfl.telemetry``), which XLA keeps in each op's
+The device side carries ``jax.named_scope``s instead (``dwfl.grad`` with
+``dwfl.grad.mla``, ``dwfl.grad.moe`` and ``dwfl.grad.moe.experts`` inside
+the model, ``dwfl.shard``, ``dwfl.mix``, ``dwfl.telemetry``), which XLA
+keeps in each op's
 ``op_name`` metadata at no run-time cost.
 """
 from __future__ import annotations

@@ -18,21 +18,28 @@ zero-padded to divisibility), shard s owns worker block
   gather, discarded after the transpose);
 * the clipped gradients run on the local [Wb, d] row block — the exact
   unsharded subprogram (protocol._make_flat_local_pass) on W/S workers,
-  optionally rematerialized (``remat=True``);
+  optionally with the model's layer-level checkpoint (``remat=True``);
 * the reverse ``all_to_all`` scatters each chunk's gradient columns
   straight into the owning shard's window (the reduce in reduce-scatter
   is a no-op here: worker-split grads are disjoint, never summed), and
   the O(d) dp_mix round stays fully local as before.
 
 Memory contract: the persistent buffer is d/S per device AND the round's
-peak is ~(W·d)/S per device — the [Wb, d] row block plus transients
-bounded by the chunk budget (~W·max_chunk_cols elements per collective).
+peak is ~(W·d)/S per device — the [Wb, d] row block, its gradient, plus
+transients bounded by the chunk budget (~W·max_chunk_cols elements per
+collective) — and the activations of the grad pass on top (one input per
+layer and the one layer being differentiated under ``remat``).
 No full [W, d] materialization exists anywhere in the sharded program
 (statically enforced: repro.analysis's ``gather`` checker ERRORs on any
 full-width all_gather of the buffer). Compute also drops to W/S
 grad-pass workers per device — on a single-socket host the sharded round
 therefore WINS throughput instead of paying an S-fold redundant gather
 (BENCH_shard.json).
+
+Device scopes: the ``all_to_all`` pair (and the worker-axis pad) under
+``dwfl.shard``, the gradient pass under ``dwfl.grad`` (protocol), the
+mix under ``dwfl.mix``, the metrics and their [W] gathers under
+``dwfl.telemetry``.
 
 Two execution modes share one window primitive (``shard_window_round``):
 
@@ -113,9 +120,11 @@ def _padded_local_grads(cfg, proto, spec: FlatSpec, *, remat: bool = False):
     their gradient IS zero). Row count is free — the mesh path calls this
     on its [Wb, width] worker block, the logical path on all W rows —
     because the base pass vmaps over whatever leading axis it gets.
-    ``remat`` rematerializes the per-worker forward in the backward pass
-    (jax.checkpoint) — activation memory for the price of a second
-    forward, for configs whose loss activations dominate the row block."""
+    ``remat`` turns on the model's layer-level checkpoint (``cfg.remat``):
+    each layer's activations are recomputed in the backward pass, so only
+    the layer inputs are saved — activation memory for the price of a
+    second forward, for configs whose activations dominate the row
+    block."""
     base = protocol_lib._make_flat_local_pass(cfg, proto, spec.unravel_row,
                                               remat=remat)
     d, width = spec.d, spec.width
@@ -219,9 +228,10 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
             idx = jax.lax.axis_index(axis)
             col0 = idx.astype(jnp.int32) * sw
             segs = spec.chunk_plan.exec_segments()
-            fl_p = flat if Wp == Wn else jnp.pad(flat,
-                                                 ((0, Wp - Wn), (0, 0)))
-            rows = _gather_block_rows(fl_p, axis, layout, segs)
+            with jax.named_scope("dwfl.shard"):
+                fl_p = flat if Wp == Wn else jnp.pad(
+                    flat, ((0, Wp - Wn), (0, 0)))
+                rows = _gather_block_rows(fl_p, axis, layout, segs)
 
             def _block(a):
                 # zero-pad the worker axis BEFORE slicing: a clamped
@@ -235,25 +245,30 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
 
             losses_b, g_rows, gnorms_b = local_grads(
                 rows, jax.tree_util.tree_map(_block, batch))
-            g_own = _scatter_grad_cols(g_rows, axis, layout, segs)[:Wn]
-            losses = jax.lax.all_gather(losses_b, axis, axis=0,
-                                        tiled=True)[:Wn]
-            gnorms = jax.lax.all_gather(gnorms_b, axis, axis=0,
-                                        tiled=True)[:Wn]
+            with jax.named_scope("dwfl.shard"):
+                g_own = _scatter_grad_cols(g_rows, axis, layout, segs)[:Wn]
+            with jax.named_scope("dwfl.telemetry"):
+                losses = jax.lax.all_gather(losses_b, axis, axis=0,
+                                            tiled=True)[:Wn]
+                gnorms = jax.lax.all_gather(gnorms_b, axis, axis=0,
+                                            tiled=True)[:Wn]
         if proto.n_workers < 2:
             # degenerate federation: plain local SGD on the local slab
             flat = flat - gamma * g_own
             return flat, _metrics(losses, gnorms, flat)
-        plan = xspec.plan(proto, ch, k_x, W_arg=W_t)
-        seed = mix_ops.seed_from_key(k_n)
-        if axis is None:
-            flat = dp_mix_round_sharded(flat, g_own, seed, plan, layout,
-                                        gamma=gamma, eta=eta, impl=impl)
-        else:
-            flat = shard_window_round(flat, g_own, seed, plan, col0, layout,
-                                      gamma=gamma, eta=eta, impl=impl)
+        with jax.named_scope("dwfl.mix"):
+            plan = xspec.plan(proto, ch, k_x, W_arg=W_t)
+            seed = mix_ops.seed_from_key(k_n)
+            if axis is None:
+                flat = dp_mix_round_sharded(flat, g_own, seed, plan, layout,
+                                            gamma=gamma, eta=eta, impl=impl)
+            else:
+                flat = shard_window_round(flat, g_own, seed, plan, col0,
+                                          layout, gamma=gamma, eta=eta,
+                                          impl=impl)
         return flat, _metrics(losses, gnorms, flat)
 
+    @jax.named_scope("dwfl.telemetry")
     def _metrics(losses, gnorms, flat):
         # padding columns are exact zeros; in logical mode reduce over the
         # canonical [:, :d] view so param_norm matches the unsharded step

@@ -9,9 +9,9 @@ fused flat step (the baseline), S>1 the gather-free shard_map round on an
 S-device model mesh (this module forces
 ``--xla_force_host_platform_device_count=4`` when no device count was
 requested, so the mesh is real even on a laptop). Every sharded case is
-cross-checked bitwise against the unsharded round on the canonical
-columns before timing — a throughput number for a wrong round is
-worthless.
+cross-checked against the unsharded round on the canonical columns
+before timing, to float32 rounding (the mesh round's clip norm sums per
+leaf) — a throughput number for a wrong round is worthless.
 
 What the columns mean:
 
@@ -27,7 +27,7 @@ What the columns mean:
 * ``peak_bytes_per_device`` — XLA's compiled memory analysis
   (args + outputs + temps − donation aliasing): the live-set contract.
   Falls with S — the persistent buffer is width/S columns per device and
-  the grad pass materializes only the [ceil(W/S), width] row block plus
+  the grad pass materializes only the ceil(W/S) workers' leaves plus
   chunk-bounded transients, never a full [W, width] replica.
 """
 from __future__ import annotations
@@ -201,10 +201,12 @@ def main(smoke: bool = False):
             shardings_lib.flat_buffer_sharding(spec, mesh))
         got, _ = step(flat, batch, jax.random.PRNGKey(3))
         got = np.asarray(spec.unpad(got))
-        if not np.array_equal(got, ref):
-            raise AssertionError(
-                f"S={S} sharded round diverged from the unsharded one "
-                f"(max |diff| {np.abs(got - ref).max()})")
+        # the mesh round clips on the worker's leaves, so its clip norm
+        # rounds differently from the row's: float32 agreement, not bits
+        np.testing.assert_allclose(
+            got, ref, rtol=1e-6,
+            atol=4 * float(np.spacing(np.abs(ref).max())),
+            err_msg=f"S={S} sharded round diverged from the unsharded one")
         shard_call = lambda i: step(flat, batch,
                                     jax.random.fold_in(key, i))[0]
         t_b, t_s, speedup = _paired_speedup(base_call, shard_call)

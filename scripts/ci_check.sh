@@ -185,9 +185,9 @@ XLA_FLAGS=--xla_force_host_platform_device_count=2 python -m repro.launch.train 
     --chunk-rounds 4 --eval-every 5
 
 echo "== ISSUE 8 smoke: shard perf artifact (4 forced devices, S in 1/2/4) =="
-# shard_bench forces a 4-device host platform itself and bitwise
-# cross-checks every sharded case against the unsharded round before
-# timing anything
+# shard_bench forces a 4-device host platform itself and cross-checks
+# every sharded case against the unsharded round (to float32 rounding)
+# before timing anything
 python -m benchmarks.shard_bench --smoke
 python - <<'EOF'
 import json

@@ -678,6 +678,19 @@ class FlatSpec:
             off += n
         return jax.tree_util.tree_unflatten(self._treedef, out)
 
+    def leaf_shapes(self) -> list:
+        """Per-leaf shapes of ONE worker (the lead axes dropped), in ravel
+        order."""
+        return [s[self.lead_axes:] for s in self._shapes]
+
+    def unravel_leaves(self, leaves) -> Tree:
+        """ONE worker's tree from its float32 leaves in ravel order, each
+        already in its own shape (``leaf_shapes``) — the leaf-addressed
+        twin of ``unravel_row`` for a pass that never builds the row."""
+        return jax.tree_util.tree_unflatten(
+            self._treedef, [l.astype(dt) for l, dt in zip(leaves,
+                                                          self._dtypes)])
+
     def layout_meta(self) -> dict:
         """JSON-able layout record for checkpoint manifests."""
         meta = {

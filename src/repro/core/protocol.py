@@ -460,14 +460,14 @@ def make_dynamic_train_step(cfg: ModelConfig, proto: ProtocolConfig) -> Callable
 def _make_flat_local_pass(cfg: ModelConfig, proto: ProtocolConfig,
                           unravel_row, remat: bool = False):
     """Per-worker clipped gradients ON THE FLAT BUFFER: each worker's loss
-    is a function of its flat [d] row (autodiff carries the ravel — no
-    explicit per-round concatenate), and the L2 clip is one vector norm.
+    is a function of its flat [d] row (autodiff carries the ravel), and
+    the L2 clip is one vector norm. Given ``FlatSpec.unravel_leaves`` it
+    runs on a list of each worker's leaves instead (the model-sharded mesh
+    round: gradients in the leaves' shapes, one norm sum per leaf).
     ``remat`` turns on the model's layer-level checkpoint (``cfg.remat``:
     each block of the layer scans is recomputed in the backward pass), so
     the saved activations are one residual-stream input per layer plus
-    the one layer being differentiated — the knob the sharded round
-    exposes for big models. A model without layer scans (the MLP) saves
-    nothing by it."""
+    the one layer being differentiated (the MLP has no layer scans)."""
     clip = proto.clip
     if remat:
         cfg = cfg.replace(remat=True)

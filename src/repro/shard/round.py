@@ -6,35 +6,45 @@ dp_mix pipeline — local SGD, on-chip DP noise, the [N, N]×[N, d_shard]
 mixing matmul, self-correction, AWGN — on its own column window, with the
 noise counters offset to the window's global columns so the union of the
 per-shard CPU streams IS the single-device stream (bitwise; DESIGN.md
-§11). Only the per-worker gradient pass needs full rows, and the mesh
-step obtains them GATHER-FREE by splitting the WORKER axis instead of
-replicating the model: with S shards and Wp = S·ceil(W/S) (worker rows
-zero-padded to divisibility), shard s owns worker block
-[s·Wb, (s+1)·Wb) and
+§11). Only the per-worker gradient pass needs whole workers, and the
+mesh step obtains them GATHER-FREE by splitting the WORKER axis instead
+of replicating the model: with S shards and Wp = S·ceil(W/S) (worker
+rows zero-padded to divisibility), shard s owns worker block
+[s·Wb, (s+1)·Wb) and (``worker_split_grads``)
 
 * a chunk-scheduled ``all_to_all`` (spec.chunk_plan — leaf x window
   chunks capped at ``max_chunk_cols``) trades its column window for its
-  worker block's full rows, one chunk segment at a time (just-in-time
-  gather, discarded after the transpose);
-* the clipped gradients run on the local [Wb, d] row block — the exact
-  unsharded subprogram (protocol._make_flat_local_pass) on W/S workers,
-  optionally with the model's layer-level checkpoint (``remat=True``);
-* the reverse ``all_to_all`` scatters each chunk's gradient columns
-  straight into the owning shard's window (the reduce in reduce-scatter
-  is a no-op here: worker-split grads are disjoint, never summed), and
-  the O(d) dp_mix round stays fully local as before.
+  worker block's pieces, one chunk segment at a time. The plan cuts
+  every segment at leaf boundaries, so each piece lies in one leaf (or
+  the padding, dropped), and each leaf [Wb, *leaf_shape] is assembled
+  from its own pieces in global column order;
+* each worker is differentiated and clipped on its leaves — the flat
+  pass's per-worker value_and_grad and clip
+  (protocol._make_flat_local_pass over ``FlatSpec.unravel_leaves``) on
+  W/S workers, optionally with the model's layer-level checkpoint
+  (``remat=True``); the clip norm is the root of the sum of per-leaf
+  sums of squares;
+* the reverse ``all_to_all`` takes each piece cut straight from its
+  clipped leaf into the owning shard's window (the reduce in
+  reduce-scatter is a no-op here: worker-split grads are disjoint,
+  never summed), and the O(d) dp_mix round stays fully local as before.
+
+No worker's [Wb, d] row is built: with one worker a device it is
+[1, d], which the TPU tiles one sublane deep, and every pass over it ran
+far under HBM speed. A segment whose ends and leaves are lane-aligned
+travels as [S, Wb·k, 128], so its pieces stay in whole (8, 128) tiles.
 
 Memory contract: the persistent buffer is d/S per device AND the round's
-peak is ~(W·d)/S per device — the [Wb, d] row block, its gradient, plus
-transients bounded by the chunk budget (~W·max_chunk_cols elements per
-collective) — and the activations of the grad pass on top (one input per
-layer and the one layer being differentiated under ``remat``).
-No full [W, d] materialization exists anywhere in the sharded program
-(statically enforced: repro.analysis's ``gather`` checker ERRORs on any
-full-width all_gather of the buffer). Compute also drops to W/S
-grad-pass workers per device — on a single-socket host the sharded round
-therefore WINS throughput instead of paying an S-fold redundant gather
-(BENCH_shard.json).
+peak is ~(W·d)/S per device — the Wb workers' leaves, their gradients,
+plus transients bounded by the chunk budget (~W·max_chunk_cols elements
+per collective) — and the activations of the grad pass on top (one
+input per layer and the one layer being differentiated under
+``remat``). No full [W, d] materialization exists anywhere in the
+sharded program (statically enforced: repro.analysis's ``gather``
+checker ERRORs on any full-width all_gather of the buffer). Compute also
+drops to W/S grad-pass workers per device — on a single-socket host the
+sharded round therefore WINS throughput instead of paying an S-fold
+redundant gather (BENCH_shard.json).
 
 Device scopes: the ``all_to_all`` pair (and the worker-axis pad) under
 ``dwfl.shard``, the gradient pass under ``dwfl.grad`` (protocol), the
@@ -52,12 +62,17 @@ Two execution modes share one window primitive (``shard_window_round``):
   Composable with the fleet's replicate axis into a 2-D
   ("replicas", "model") mesh (``make_fleet_sharded_step``).
 
-Both modes reproduce the unsharded round bitwise on the real columns
-(CPU), because every column's arithmetic is independent and the noise
-stream is counter-addressed (tests/test_shard.py).
+The logical mode reproduces the unsharded round bitwise on the real
+columns (CPU), because every column's arithmetic is independent, the
+noise stream is counter-addressed and its gradient pass is the
+unsharded row pass. The mesh mode draws the same noise bitwise, but its
+clip norm sums per-leaf sums where the row pass sums once, so it agrees
+with the unsharded round to float32 rounding; mesh rounds agree with
+each other bitwise across chunk budgets (tests/test_shard.py).
 """
 from __future__ import annotations
 
+import bisect
 from typing import Optional
 
 import jax
@@ -66,7 +81,7 @@ import jax.numpy as jnp
 from repro.core import protocol as protocol_lib
 from repro.core.exchange import FlatSpec
 from repro.kernels.dp_mix import ops as mix_ops
-from repro.shard.layout import ShardLayout
+from repro.shard.layout import LANES, ShardLayout
 
 
 def partition_spec(spec: FlatSpec, replicate_axis: Optional[str] = None):
@@ -114,17 +129,14 @@ def dp_mix_round_sharded(flat, g, seed, plan, layout: ShardLayout, *,
 
 
 def _padded_local_grads(cfg, proto, spec: FlatSpec, *, remat: bool = False):
-    """The flat-buffer gradient pass on a PADDED buffer: run the exact
-    unsharded subprogram on the canonical [:, :d] view, re-pad the
-    gradients with exact zeros (padding columns carry no parameters, so
-    their gradient IS zero). Row count is free — the mesh path calls this
-    on its [Wb, width] worker block, the logical path on all W rows —
-    because the base pass vmaps over whatever leading axis it gets.
-    ``remat`` turns on the model's layer-level checkpoint (``cfg.remat``):
-    each layer's activations are recomputed in the backward pass, so only
-    the layer inputs are saved — activation memory for the price of a
-    second forward, for configs whose activations dominate the row
-    block."""
+    """The logical mode's gradient pass on the PADDED buffer: run the
+    exact unsharded subprogram on the canonical [:, :d] view of all W
+    rows, re-pad the gradients with exact zeros (padding columns carry no
+    parameters, so their gradient IS zero). ``remat`` turns on the
+    model's layer-level checkpoint (``cfg.remat``): each layer's
+    activations are recomputed in the backward pass, so only the layer
+    inputs are saved — activation memory for the price of a second
+    forward."""
     base = protocol_lib._make_flat_local_pass(cfg, proto, spec.unravel_row,
                                               remat=remat)
     d, width = spec.d, spec.width
@@ -138,42 +150,169 @@ def _padded_local_grads(cfg, proto, spec: FlatSpec, *, remat: bool = False):
     return local_grads
 
 
-def _gather_block_rows(flat_p, axis: str, layout: ShardLayout, segs):
-    """Worker-split gather: trade this shard's [Wp, shard_width] column
-    slab for its worker BLOCK's full rows [Wb, padded_width], one chunk
-    segment per ``all_to_all`` (tiled: split the padded worker axis into
-    the S blocks, concatenate the S windows' spans along columns). Each
-    collective moves one segment — the transient is [Wb, S·seg] elements,
-    bounded by the chunk budget — and the segment transposes are
-    reassembled window-major into canonical column order."""
-    S, sw = layout.n_shards, layout.shard_width
-    pieces = [
-        (b - a,
-         jax.lax.all_to_all(flat_p[:, a:b], axis, split_axis=0,
-                            concat_axis=1, tiled=True))   # [Wb, S*(b-a)]
-        for a, b in segs
-    ]
-    cols = [seg[:, s * w:(s + 1) * w]
-            for s in range(S) for w, seg in pieces]
-    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+def _piece_map(spec: FlatSpec, segs):
+    """Where each ``all_to_all`` piece lives in the worker's leaves.
+
+    Piece (s, j) is window s's span of segment j — global columns
+    [s·shard_width + a_j, s·shard_width + b_j). The chunk plan cuts every
+    segment at leaf boundaries, so each piece lies inside ONE leaf or in
+    the padding past d. Returns ``(where, lane)``: ``where[s][j]`` is
+    (leaf, column offset inside the leaf), or None for padding;
+    ``lane[j]`` says segment j travels as a [.., k, LANES] view — its
+    ends are lane-aligned and so is every leaf it touches, so each piece
+    is a run of whole 128-column rows of its leaf's [k_leaf, LANES] view
+    (the chip's (8, 128) tiles stay filled even with one worker a
+    device)."""
+    S, sw, d = spec.n_shards, spec.layout.shard_width, spec.d
+    offs, sizes = spec.leaf_offsets(), spec.leaf_sizes()
+    aligned = [o % LANES == 0 and n % LANES == 0
+               for o, n in zip(offs, sizes)]
+    where = []
+    for s in range(S):
+        row = []
+        for a, b in segs:
+            g0 = s * sw + a
+            if g0 >= d:
+                row.append(None)
+                continue
+            leaf = bisect.bisect_right(offs, g0) - 1
+            row.append((leaf, g0 - offs[leaf]))
+        where.append(row)
+    lane = [a % LANES == 0 and b % LANES == 0
+            and all(where[s][j] is None or aligned[where[s][j][0]]
+                    for s in range(S))
+            for j, (a, b) in enumerate(segs)]
+    return where, lane
 
 
-def _scatter_grad_cols(g_rows, axis: str, layout: ShardLayout, segs):
-    """The reverse chunk schedule: [Wb, padded_width] row-block gradients
-    -> every worker's [Wp, shard_width] gradient columns on the OWNING
-    shard. Worker-split gradients are disjoint across devices, so the
+def _gather_block_leaves(flat_p, axis: str, spec: FlatSpec, segs, pieces):
+    """Worker-split gather, leaf-addressed: trade this shard's [Wp,
+    shard_width] column slab for its worker BLOCK's leaves, each [Wb,
+    *leaf_shape], one chunk segment per ``all_to_all``. The segment is
+    viewed as [S, Wb·k, LANES] (the S worker blocks leading, no unit
+    axis) and the collective splits and concatenates that leading axis,
+    so piece s of the result is window s's span of the block's rows and
+    the trailing [Wb·k, LANES] dims stay whole tiles. Each collective
+    moves one segment — the transient is [Wb, S·seg] elements, bounded
+    by the chunk budget — and every leaf is assembled from its own
+    pieces in global column order; padding pieces are dropped. No
+    [Wb, width] row exists."""
+    S = spec.n_shards
+    Wb = flat_p.shape[0] // S
+    where, lane = pieces
+    recv, tails = [], []
+    for j, (a, b) in enumerate(segs):
+        tail = ((b - a) // LANES, LANES) if lane[j] else (b - a,)
+        x = flat_p[:, a:b].reshape((S, Wb * tail[0]) + tail[1:])
+        recv.append(jax.lax.all_to_all(x, axis, split_axis=0,
+                                       concat_axis=0, tiled=True))
+        tails.append(tail)
+    parts = [[] for _ in spec.leaf_sizes()]
+    for s in range(S):
+        for j, at in enumerate(where[s]):
+            if at is not None:
+                parts[at[0]].append(
+                    (lane[j], recv[j][s].reshape((Wb,) + tails[j])))
+    leaves = []
+    for shape, ps in zip(spec.leaf_shapes(), parts):
+        tiled = all(t for t, _ in ps)
+        xs = [p if tiled else p.reshape(Wb, -1) for _, p in ps]
+        x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1)
+        leaves.append(x.reshape((x.shape[0],) + tuple(shape)))
+    return leaves
+
+
+def _scatter_grad_leaves(g_leaves, axis: str, spec: FlatSpec, segs, pieces):
+    """The reverse chunk schedule: the worker block's [Wb, *leaf_shape]
+    gradient leaves -> every worker's [Wp, shard_width] gradient columns
+    on the OWNING shard. Each piece is cut straight from its leaf (padding
+    pieces are zeros) and the S pieces of a segment are stacked window-
+    major. Worker-split gradients are disjoint across devices, so the
     reduce of a reduce-scatter is a no-op and the scatter is the inverse
-    ``all_to_all`` (columns split per window, worker blocks concatenated
+    ``all_to_all`` (pieces split per window, worker blocks concatenated
     back in order) — pure data movement, bitwise whatever the segment
     partition."""
-    S, sw = layout.n_shards, layout.shard_width
+    S = spec.n_shards
+    where, lane = pieces
+    Wb = g_leaves[0].shape[0]
+    dt = g_leaves[0].dtype
     outs = []
-    for a, b in segs:
-        parts = jnp.concatenate(
-            [g_rows[:, s * sw + a:s * sw + b] for s in range(S)], axis=1)
-        outs.append(jax.lax.all_to_all(parts, axis, split_axis=1,
-                                       concat_axis=0, tiled=True))
+    for j, (a, b) in enumerate(segs):
+        n = b - a
+        cut = []
+        for s in range(S):
+            at = where[s][j]
+            if at is None:
+                cut.append(jnp.zeros((Wb, n // LANES, LANES) if lane[j]
+                                     else (Wb, n), dt))
+            elif lane[j]:
+                o = at[1] // LANES
+                cut.append(g_leaves[at[0]].reshape(Wb, -1, LANES)[
+                    :, o:o + n // LANES])
+            else:
+                cut.append(g_leaves[at[0]].reshape(Wb, -1)[
+                    :, at[1]:at[1] + n])
+        x = jnp.stack(cut)
+        x = x.reshape((S, Wb * x.shape[2]) + x.shape[3:])
+        y = jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        outs.append(y.reshape(S * Wb, n))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def worker_split_grads(cfg, proto, spec: FlatSpec, axis: str, *,
+                       remat: bool = False):
+    """The gather-free worker-split grad pass of the mesh mode, for a
+    ``shard_map`` body over ``axis``:
+
+        grads(flat, batch) -> (losses [W], g_own [W, shard_width],
+                               gnorms [W])
+
+    ``flat`` is this shard's [W, shard_width] column slab and ``batch``
+    every worker's batch (replicated). The shard trades its slab for its
+    worker block's leaves (one chunk segment per collective),
+    differentiates and clips each worker on its leaves — the flat pass's
+    per-worker ``value_and_grad`` and clip, on a list of leaves instead of
+    a row — and cuts the gradient columns straight from the clipped
+    leaves back to their owning windows. Neither a [W, padded_width]
+    replica nor a worker's [Wb, d] row ever exists. ``losses`` and the
+    pre-clip ``gnorms`` are every worker's, gathered."""
+    leaf_grads = protocol_lib._make_flat_local_pass(
+        cfg, proto, spec.unravel_leaves, remat=remat)
+    segs = spec.chunk_plan.exec_segments()
+    pieces = _piece_map(spec, segs)
+    Wn = proto.n_workers
+    Wb = -(-Wn // spec.n_shards)
+    Wp = Wb * spec.n_shards
+
+    def grads(flat, batch):
+        idx = jax.lax.axis_index(axis)
+        with jax.named_scope("dwfl.shard"):
+            fl_p = flat if Wp == Wn else jnp.pad(flat,
+                                                 ((0, Wp - Wn), (0, 0)))
+            leaves = _gather_block_leaves(fl_p, axis, spec, segs, pieces)
+
+        def _block(a):
+            # zero-pad the worker axis BEFORE slicing: a clamped
+            # dynamic_slice on the last device would misalign the real
+            # rows against the padded flat blocks.
+            if Wp > Wn:
+                a = jnp.pad(a, [(0, Wp - Wn)] + [(0, 0)] * (a.ndim - 1))
+            return jax.lax.dynamic_slice_in_dim(a, idx * Wb, Wb, axis=0)
+
+        losses_b, g_leaves, gnorms_b = leaf_grads(
+            leaves, jax.tree_util.tree_map(_block, batch))
+        with jax.named_scope("dwfl.shard"):
+            g_own = _scatter_grad_leaves(g_leaves, axis, spec, segs,
+                                         pieces)[:Wn]
+        with jax.named_scope("dwfl.telemetry"):
+            losses = jax.lax.all_gather(losses_b, axis, axis=0,
+                                        tiled=True)[:Wn]
+            gnorms = jax.lax.all_gather(gnorms_b, axis, axis=0,
+                                        tiled=True)[:Wn]
+        return losses, g_own, gnorms
+
+    return grads
 
 
 def _check_mesh(spec: FlatSpec, mesh, axis: str):
@@ -202,7 +341,9 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
     layout = spec.layout
     chan = None if dynamic else proto.channel()
     xspec = protocol_lib._flat_spec(proto, dynamic=dynamic)
-    local_grads = _padded_local_grads(cfg, proto, spec, remat=remat)
+    local_grads = (_padded_local_grads(cfg, proto, spec, remat=remat)
+                   if axis is None else
+                   worker_split_grads(cfg, proto, spec, axis, remat=remat))
     gamma, eta = proto.gamma, proto.eta
 
     def run(flat, batch, key, chan_t=None, W_t=None):
@@ -212,46 +353,9 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
         else:
             k_n, k_m, k_x = jax.random.split(key, 3)
             ch = chan
-        if axis is None:
-            col0 = None
-            losses, g_own, gnorms = local_grads(flat, batch)
-        else:
-            # Gather-free worker-split grad pass: trade this shard's
-            # column slab for its worker block's full rows (one chunk
-            # segment per collective), run the exact unsharded subprogram
-            # on W/S workers, scatter the gradient columns back to their
-            # owning windows. No [W, padded_width] replica ever exists.
-            S, sw = layout.n_shards, layout.shard_width
-            Wn = proto.n_workers
-            Wb = -(-Wn // S)
-            Wp = Wb * S
-            idx = jax.lax.axis_index(axis)
-            col0 = idx.astype(jnp.int32) * sw
-            segs = spec.chunk_plan.exec_segments()
-            with jax.named_scope("dwfl.shard"):
-                fl_p = flat if Wp == Wn else jnp.pad(
-                    flat, ((0, Wp - Wn), (0, 0)))
-                rows = _gather_block_rows(fl_p, axis, layout, segs)
-
-            def _block(a):
-                # zero-pad the worker axis BEFORE slicing: a clamped
-                # dynamic_slice on the last device would misalign the
-                # real rows against the padded flat blocks.
-                if Wp > Wn:
-                    a = jnp.pad(a,
-                                [(0, Wp - Wn)] + [(0, 0)] * (a.ndim - 1))
-                return jax.lax.dynamic_slice_in_dim(a, idx * Wb, Wb,
-                                                    axis=0)
-
-            losses_b, g_rows, gnorms_b = local_grads(
-                rows, jax.tree_util.tree_map(_block, batch))
-            with jax.named_scope("dwfl.shard"):
-                g_own = _scatter_grad_cols(g_rows, axis, layout, segs)[:Wn]
-            with jax.named_scope("dwfl.telemetry"):
-                losses = jax.lax.all_gather(losses_b, axis, axis=0,
-                                            tiled=True)[:Wn]
-                gnorms = jax.lax.all_gather(gnorms_b, axis, axis=0,
-                                            tiled=True)[:Wn]
+        col0 = None if axis is None else (
+            jax.lax.axis_index(axis).astype(jnp.int32) * layout.shard_width)
+        losses, g_own, gnorms = local_grads(flat, batch)
         if proto.n_workers < 2:
             # degenerate federation: plain local SGD on the local slab
             flat = flat - gamma * g_own
@@ -273,8 +377,8 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
         # padding columns are exact zeros; in logical mode reduce over the
         # canonical [:, :d] view so param_norm matches the unsharded step
         # BITWISE (same reduction shape). Mesh-mode metrics are ULP-level
-        # only: the gathered per-row losses/gnorms are bitwise, but XLA
-        # picks the mean's reduction strategy per program, and the psum of
+        # only: the leaf pass's gnorms sum per-leaf sums, XLA picks the
+        # mean's reduction strategy per program, and the psum of
         # per-device partial sums associates differently.
         if axis is None:
             sq = jnp.sum(flat[:, :layout.d].astype(jnp.float32) ** 2)
@@ -296,8 +400,9 @@ def make_sharded_flat_train_step(cfg, proto, spec: FlatSpec, mesh=None,
     ``flat`` is the physical [W, spec.width] buffer — model-axis sharded
     over ``mesh`` when given (device_put it with
     launch.shardings.flat_buffer_sharding first), logically sharded on one
-    device otherwise. Bitwise-equal to the unsharded step on the canonical
-    [:, :d] view (CPU)."""
+    device otherwise. On the canonical [:, :d] view (CPU): bitwise-equal
+    to the unsharded step logically, equal to float32 rounding on a mesh
+    (the clip norm sums per leaf; module docstring)."""
     if mesh is None:
         run = _local_round_factory(cfg, proto, spec, dynamic=False,
                                    axis=None, impl=impl, remat=remat)

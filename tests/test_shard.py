@@ -321,8 +321,10 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.core import protocol as P
     from repro.launch import mesh as mesh_lib
     from repro.launch.shardings import flat_buffer_sharding
+    from jax.sharding import PartitionSpec as PS
     from repro.shard import (make_sharded_flat_train_step,
                              make_sharded_dynamic_flat_train_step)
+    from repro.shard.round import worker_split_grads
     from repro.configs.registry import get_arch
     import repro.models.mlp as mlp
 
@@ -344,7 +346,13 @@ _MESH_SCRIPT = textwrap.dedent("""
     base = jax.jit(P.make_flat_train_step(cfg, proto, spec0.unravel_row))
     f1, m1 = base(flat0, batch, jax.random.PRNGKey(42))
 
-    # static round on the model=2 mesh: BITWISE, noise stream included
+    # static round on the model=2 mesh, noise stream included. The mesh
+    # mode differentiates and clips each worker on its leaves
+    # (shard.round.worker_split_grads): the clip norm sums per-leaf sums
+    # of squares where the single-device round sums the row once, so the
+    # two agree to float32 rounding, not bitwise. Measured here: per-
+    # worker norms 1 ULP apart on 2 of 5 workers (9e-8 relative), the
+    # buffer equal; rtol=1e-6 is the dynamic mesh round's tolerance.
     mesh = mesh_lib.make_shard_mesh(2)
     spec = X.make_flat_spec(wp, n_shards=2)
     flat = jax.device_put(spec.flatten(wp),
@@ -352,29 +360,46 @@ _MESH_SCRIPT = textwrap.dedent("""
     step = jax.jit(make_sharded_flat_train_step(cfg, proto, spec,
                                                 mesh=mesh))
     f2, m2 = step(flat, batch, jax.random.PRNGKey(42))
-    assert np.array_equal(np.asarray(spec.unpad(f2)), np.asarray(f1)), \\
-        "static mesh round != single-device round"
-    # metric MEANS are ULP-level only: the per-row losses/gnorms the mesh
-    # step gathers are bitwise-equal to the reference vectors, but XLA
-    # picks the final mean's reduction strategy per program (param_norm
-    # additionally associates psum partials differently).
+    np.testing.assert_allclose(np.asarray(spec.unpad(f2)), np.asarray(f1),
+                               rtol=1e-6,
+                               err_msg="static mesh round != single-device "
+                                       "round")
+    # per-worker losses, gradients and pre-clip norms of the mesh pass
+    grads = jax.jit(jax.shard_map(
+        worker_split_grads(cfg, proto, spec, "model"), mesh=mesh,
+        in_specs=(PS(None, "model"), PS()),
+        out_specs=(PS(), PS(None, "model"), PS()), check_vma=False))
+    lm, gm, nm = grads(flat, batch)
+    l0, g0, n0 = jax.jit(P._make_flat_local_pass(cfg, proto,
+                                                 spec0.unravel_row))(
+        flat0, batch)
+    np.testing.assert_allclose(np.asarray(lm), np.asarray(l0), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(nm), np.asarray(n0), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(spec.unpad(gm)), np.asarray(g0),
+                               rtol=1e-6)
+    # metric MEANS are ULP-level only: XLA picks the final mean's
+    # reduction strategy per program (param_norm additionally associates
+    # psum partials differently).
     for k in ("loss", "grad_norm", "param_norm"):
         np.testing.assert_allclose(np.asarray(m1[k]), np.asarray(m2[k]),
                                    rtol=1e-6)
 
     # chunk-budget invariance: the chunk plan is pure data movement, so
-    # EVERY max_chunk_cols realizes the bitwise-identical round (and the
-    # same metrics — identical per-row values, identical reduce shapes)
+    # EVERY max_chunk_cols realizes the bitwise-identical mesh round (and
+    # the same metrics — identical per-leaf values, identical reduce
+    # shapes)
     for cap in (64, 257):
         spec_b = X.make_flat_spec(wp, n_shards=2, max_chunk_cols=cap)
         assert len(spec_b.chunk_plan.exec_segments()) > 1 or cap >= \\
             spec_b.layout.shard_width
         step_b = jax.jit(make_sharded_flat_train_step(cfg, proto, spec_b,
                                                       mesh=mesh))
-        fb, _ = step_b(flat, batch, jax.random.PRNGKey(42))
+        fb, mb = step_b(flat, batch, jax.random.PRNGKey(42))
         assert np.array_equal(np.asarray(spec_b.unpad(fb)),
-                              np.asarray(f1)), \\
+                              np.asarray(spec.unpad(f2))), \\
             f"max_chunk_cols={cap} changed the sharded round"
+        for k in m2:
+            assert np.array_equal(np.asarray(mb[k]), np.asarray(m2[k])), k
 
     # dynamic round, same criterion
     proto_d = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
@@ -443,10 +468,11 @@ _MESH_SCRIPT = textwrap.dedent("""
 def test_mesh_model2_round_parity_subprocess():
     """Acceptance criterion: on a host-device mesh with model=2
     (XLA_FLAGS=--xla_force_host_platform_device_count), the sharded
-    dp_mix round reproduces the single-device round bitwise on CPU (noise
-    stream included) for the static channel; the dynamic round draws the
-    same noise bitwise and agrees to mesh-mean ULPs, and the fleet-flat
-    2-D-mesh path within 2 ULP."""
+    round agrees with the single-device round (noise stream included) to
+    float32 rounding for the static channel, its per-worker losses,
+    gradients and pre-clip norms too, and is bitwise across chunk
+    budgets; the dynamic round draws the same noise bitwise and agrees
+    to mesh-mean ULPs, and the fleet-flat 2-D-mesh path within 2 ULP."""
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
                                       "src")
@@ -456,6 +482,174 @@ def test_mesh_model2_round_parity_subprocess():
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-4000:]
     assert "MESH_PARITY_OK" in res.stdout
+
+
+_LEAF_SCRIPT = textwrap.dedent("""
+    import os, re, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core import exchange as X
+    from repro.core import protocol as P
+    from repro.launch import mesh as mesh_lib
+    from repro.launch.shardings import flat_buffer_sharding
+    from repro.shard import make_sharded_flat_train_step
+    from repro.shard.round import _piece_map
+    from repro.configs.registry import get_arch
+    import repro.models.mlp as mlp
+
+    cap = None if sys.argv[1] == "none" else int(sys.argv[1])
+    # one worker a device, as the LM cell; lane-aligned leaves but the
+    # last bias, so the collectives move [.., k, 128] and plain columns
+    W, S, DIM, BATCH = 4, 4, 128, 4
+    cfg = get_arch("dwfl-paper").replace(d_model=128)
+    proto = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
+                             eta=0.4, clip=0.05, p_dbm=60.0, sigma=0.7,
+                             sigma_m=0.5)
+    params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=DIM)
+    wp = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (W,) + a.shape), params)
+    rng = np.random.default_rng(1)
+    batch = {"x": jnp.asarray(rng.normal(size=(W, BATCH, DIM))
+                              .astype(np.float32)),
+             "y": jnp.asarray(rng.integers(0, 10, (W, BATCH))
+                              .astype(np.int32))}
+    key = jax.random.PRNGKey(42)
+    mesh = mesh_lib.make_shard_mesh(S)
+
+    def mesh_round(c):
+        spec = X.make_flat_spec(wp, n_shards=S, max_chunk_cols=c)
+        flat = jax.device_put(spec.flatten(wp),
+                              flat_buffer_sharding(spec, mesh))
+        step = jax.jit(make_sharded_flat_train_step(cfg, proto, spec,
+                                                    mesh=mesh))
+        return spec, step, flat
+
+    spec, step, flat = mesh_round(cap)
+    segs = spec.chunk_plan.exec_segments()
+    lane = _piece_map(spec, segs)[1]
+    if cap != 64:
+        assert any(lane) and not all(lane), lane
+    # the worker row is gone: no array of the compiled step has d or the
+    # padded width on its last axis (no leaf's shape has either)
+    wide = {spec.d, spec.width}
+    assert not wide & {n for sh in spec.leaf_shapes() for n in sh}
+    text = step.lower(flat, batch, key).compile().as_text()
+    rows = [m.group(0) for m in re.finditer(r"\\w+\\[([\\d,]+)\\]", text)
+            if int(m.group(1).split(",")[-1]) in wide]
+    assert not rows, rows[:5]
+
+    f, m = step(flat, batch, key)
+    spec0 = X.make_flat_spec(wp)
+    f1, m1 = jax.jit(P.make_flat_train_step(cfg, proto, spec0.unravel_row))(
+        spec0.flatten(wp), batch, key)
+    # rounding of the clip norm, carried through the mix: entries that
+    # the mix cancels to near zero keep an absolute error of the large
+    # ones' size (measured: 3.0e-7, a third of an ULP of the largest
+    # entry), so the floor is 4 ULP of the largest entry
+    f1 = np.asarray(f1)
+    np.testing.assert_allclose(
+        np.asarray(spec.unpad(f)), f1, rtol=1e-6,
+        atol=4 * float(np.spacing(np.abs(f1).max())))
+    for k in m1:
+        np.testing.assert_allclose(np.asarray(m[k]), np.asarray(m1[k]),
+                                   rtol=1e-6)
+    if cap is not None:
+        # every budget realizes the unbounded mesh round, bitwise
+        spec_u, step_u, flat_u = mesh_round(None)
+        fu, mu = step_u(flat_u, batch, key)
+        assert np.array_equal(np.asarray(f), np.asarray(fu))
+        for k in mu:
+            assert np.array_equal(np.asarray(m[k]), np.asarray(mu[k])), k
+    print("LEAF_PASS_OK")
+""")
+
+
+@pytest.mark.parametrize("cap", [None, 64, 257])
+def test_mesh_round_holds_no_worker_row_subprocess(cap):
+    """The static mesh round on a model=4 host-device mesh, one worker a
+    device: its compiled program holds no array with the buffer's width
+    (d or padded) on its last axis — the worker's [Wb, d] row is never
+    built — it agrees with the single-device round to float32 rounding,
+    and every chunk budget gives the unbounded mesh round bitwise."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
+                                      "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", _LEAF_SCRIPT,
+                          "none" if cap is None else str(cap)], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-4000:]
+    assert "LEAF_PASS_OK" in res.stdout
+
+
+def _leaf_case(clip, poison=False):
+    """(leaf pass, flat pass) results of three MLP workers: the flat
+    pass's per-worker value_and_grad and clip on a list of leaves, and on
+    the raveled row."""
+    from repro.configs.registry import get_arch
+    import repro.models.mlp as mlp
+    W = 3
+    cfg = get_arch("dwfl-paper").replace(d_model=8)
+    proto = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
+                             eta=0.4, clip=clip, p_dbm=60.0, sigma=0.7)
+    wp = jax.tree_util.tree_map(
+        lambda a: jnp.stack([a * (1.0 + w) for w in range(W)]),
+        mlp.init(jax.random.PRNGKey(0), cfg, input_dim=12))
+    spec = X.make_flat_spec(wp)
+    flat = spec.flatten(wp)
+    if poison:
+        flat = flat.at[1, 3].set(jnp.nan)
+    rng = np.random.default_rng(0)
+    batch = {"x": jnp.asarray(rng.normal(size=(W, 4, 12))
+                              .astype(np.float32)),
+             "y": jnp.asarray(rng.integers(0, 10, (W, 4))
+                              .astype(np.int32))}
+    offs = spec.leaf_offsets()
+    leaves = [flat[:, o:o + n].reshape((W,) + tuple(sh))
+              for o, n, sh in zip(offs, spec.leaf_sizes(),
+                                  spec.leaf_shapes())]
+    by_leaf = jax.jit(P._make_flat_local_pass(cfg, proto,
+                                              spec.unravel_leaves))
+    by_row = jax.jit(P._make_flat_local_pass(cfg, proto, spec.unravel_row))
+    l_a, g_a, n_a = by_leaf(leaves, batch)
+    g_a = jnp.concatenate([g.reshape(W, -1) for g in g_a], axis=1)
+    return (l_a, g_a, n_a), by_row(flat, batch)
+
+
+@pytest.mark.parametrize("clip", [1e6, 0.5])
+def test_leaf_pass_clips_as_the_row_pass(clip):
+    """The mesh round's leaf pass clips as privacy.clip_gradient_tree does
+    on the row: the same losses; the gradient equal where every norm is
+    under g_max (scale 1), within rounding where the clip scales it; the
+    pre-clip norms within rounding (one sum per leaf, then their sum)."""
+    (l_a, g_a, n_a), (l_b, g_b, n_b) = _leaf_case(clip)
+    np.testing.assert_array_equal(np.asarray(l_a), np.asarray(l_b))
+    np.testing.assert_allclose(np.asarray(n_a), np.asarray(n_b), rtol=1e-6)
+    if clip > float(np.max(n_b)):
+        np.testing.assert_array_equal(np.asarray(g_a), np.asarray(g_b))
+    else:
+        assert float(np.min(n_b)) > clip    # every worker scaled
+        np.testing.assert_allclose(np.asarray(g_a), np.asarray(g_b),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(
+            np.linalg.norm(np.asarray(g_a), axis=1), clip, rtol=1e-6)
+
+
+def test_leaf_pass_zeroes_a_non_finite_worker():
+    """A non-finite gradient zeroes every leaf of that worker's gradient
+    and reports its norm as 0, as the row pass does; the other workers
+    are untouched."""
+    (_, g_a, n_a), (_, g_b, n_b) = _leaf_case(0.5, poison=True)
+    g_a, n_a = np.asarray(g_a), np.asarray(n_a)
+    assert not np.isfinite(
+        np.asarray(_leaf_case(1e30, poison=True)[1][0])[1])
+    assert n_a[1] == 0.0 and not np.any(g_a[1])
+    np.testing.assert_array_equal(g_a[1], np.asarray(g_b)[1])
+    assert np.all(n_a[[0, 2]] > 0)
+    np.testing.assert_allclose(g_a[[0, 2]], np.asarray(g_b)[[0, 2]],
+                               rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

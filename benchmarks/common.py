@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.registry import get_arch
 from repro.core import protocol as P
+from repro.core.exchange import make_flat_spec
 from repro.data import classification_dataset, dirichlet_partition, FederatedBatcher
 import repro.models.mlp as mlp
 
@@ -45,9 +46,10 @@ def provenance(smoke: bool = False) -> Dict:
 
 
 def _setup_task(proto: P.ProtocolConfig, seed: int):
-    """Shared harness: the reduced benchmark task (config, batcher,
-    replicated init params, eval fn) — identical between the static and
-    dynamic runners so their rows stay comparable."""
+    """Shared harness: the reduced benchmark task (config, batcher, the
+    replicated init params as a flat [W, d] buffer, its row unraveler, an
+    eval fn over the buffer) — identical between the static and dynamic
+    runners so their rows stay comparable."""
     cfg = get_arch("dwfl-paper").replace(d_model=HIDDEN)
     x, y = classification_dataset(DATA_N, input_dim=INPUT_DIM, seed=seed)
     parts = dirichlet_partition(y, proto.n_workers, alpha=0.5, seed=seed)
@@ -57,7 +59,10 @@ def _setup_task(proto: P.ProtocolConfig, seed: int):
     wp = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (proto.n_workers,) + a.shape),
         params)
-    return cfg, bat, wp, jax.jit(P.make_eval_fn(cfg)), key
+    spec = make_flat_spec(wp)
+    ev = jax.jit(P.make_eval_fn(cfg))
+    evaluate = lambda flat, batch: ev(spec.unravel(flat), batch)
+    return cfg, bat, spec.flatten(wp), spec.unravel_row, evaluate, key
 
 
 def _finish(wp, bat, evaluate, us_per_step: float, eps_fields: Dict,
@@ -82,8 +87,8 @@ def run_protocol(scheme: str, *, n_workers: int, epsilon: float,
                              participation=participation)
     chan = proto.channel()
     rep = P.epsilon_report(proto, chan)
-    cfg, bat, wp, evaluate, key = _setup_task(proto, seed)
-    step = jax.jit(P.make_train_step(cfg, proto))
+    cfg, bat, wp, unravel_row, evaluate, key = _setup_task(proto, seed)
+    step = jax.jit(P.make_flat_train_step(cfg, proto, unravel_row))
 
     curve: List = []
     # warmup/compile
@@ -96,7 +101,7 @@ def run_protocol(scheme: str, *, n_workers: int, epsilon: float,
         if eval_every and t % eval_every == 0:
             el, ea = evaluate(wp, bat.full(128))
             curve.append((t, float(el), float(ea)))
-    jax.tree_util.tree_leaves(wp)[0].block_until_ready()
+    wp.block_until_ready()
     us_per_step = (time.perf_counter() - t0) / steps * 1e6
 
     return _finish(wp, bat, evaluate, us_per_step, {
@@ -123,8 +128,8 @@ def run_dynamic_protocol(scenario: str, *, n_workers: int, epsilon: float,
                              channel_model="dynamic", scenario=scenario,
                              coherence_rounds=coherence_rounds)
     sim = proto.simulator()
-    cfg, bat, wp, evaluate, key = _setup_task(proto, seed)
-    step = jax.jit(P.make_dynamic_train_step(cfg, proto))
+    cfg, bat, wp, unravel_row, evaluate, key = _setup_task(proto, seed)
+    step = jax.jit(P.make_dynamic_flat_train_step(cfg, proto, unravel_row))
     net_round = jax.jit(sim.round)
 
     key, nk = jax.random.split(key)
@@ -141,7 +146,7 @@ def run_dynamic_protocol(scenario: str, *, n_workers: int, epsilon: float,
         chan_log.append(chan)
         w_log.append(W)
         wp, metrics = step(wp, bat.next(), sk, chan, W)
-    jax.tree_util.tree_leaves(wp)[0].block_until_ready()
+    wp.block_until_ready()
     us_per_step = (time.perf_counter() - t0) / steps * 1e6
 
     rep = P.epsilon_report(proto, stack_states(chan_log),

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import protocol as P
+from repro.core.exchange import make_flat_spec
 from repro.fleet import FleetEngine
 from repro.fleet.sweep import ScenarioGrid, run_grid
 
@@ -56,6 +57,12 @@ def _tiny_setup():
     return proto, cfg, wp1, batch1
 
 
+def _flat(tree, lead_axes=1):
+    """(flat buffer, FlatSpec) of a worker-stacked tree."""
+    spec = make_flat_spec(tree, lead_axes=lead_axes)
+    return spec.flatten(tree), spec
+
+
 def bench_batch_vs_loop(steps: int = STEPS):
     proto, cfg, wp1, batch1 = _tiny_setup()
     key = jax.random.PRNGKey(1)
@@ -63,9 +70,11 @@ def bench_batch_vs_loop(steps: int = STEPS):
     # -- loop path: R independent single-replicate pipelines ---------------
     sim = proto.simulator()
     net_round = jax.jit(sim.round)
-    step = jax.jit(P.make_dynamic_train_step(cfg, proto))
+    flat1, spec1 = _flat(wp1)
+    step = jax.jit(P.make_dynamic_flat_train_step(cfg, proto,
+                                                  spec1.unravel_row))
     loop_states = [sim.init(jax.random.fold_in(key, r)) for r in range(R)]
-    loop_wp = [wp1 for _ in range(R)]
+    loop_wp = [flat1 for _ in range(R)]
 
     def loop_round(t):
         for r in range(R):
@@ -78,15 +87,15 @@ def bench_batch_vs_loop(steps: int = STEPS):
     t0 = time.perf_counter()
     for t in range(steps):
         loop_round(t + 1)
-    jax.tree_util.tree_leaves(loop_wp[-1])[0].block_until_ready()
+    loop_wp[-1].block_until_ready()
     loop_us = (time.perf_counter() - t0) / steps * 1e6
 
     # -- fleet path: same R networks through one compiled round ------------
     fleet = FleetEngine(proto)
-    fleet_round = jax.jit(fleet.make_fleet_round(cfg))
+    wp, specR = _flat(jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), wp1), 2)
+    fleet_round = jax.jit(fleet.make_fleet_round(cfg, spec=specR))
     states = fleet.init(key)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), wp1)
     batch = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), batch1)
 
@@ -95,7 +104,7 @@ def bench_batch_vs_loop(steps: int = STEPS):
     for t in range(steps):
         states, wp, metrics, _c, _w = fleet_round(
             jax.random.fold_in(key, t), states, wp, batch)
-    jax.tree_util.tree_leaves(wp)[0].block_until_ready()
+    wp.block_until_ready()
     fleet_us = (time.perf_counter() - t0) / steps * 1e6
 
     speedup = loop_us / fleet_us

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dp_perturb import ops as dp_ops, ref as dp_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.models.ssm import ssd_chunked
@@ -31,12 +30,6 @@ def _time(fn, *a, n=3):
 def main():
     rows = []
     key = jax.random.PRNGKey(0)
-
-    p = jax.random.normal(key, (512, 512))
-    g = jax.random.normal(jax.random.fold_in(key, 1), (512, 512))
-    us, got = _time(lambda a, b: dp_ops.sgd_update(a, b, 0.05), p, g)
-    err = float(jnp.max(jnp.abs(got - dp_ref.sgd_update_ref(p, g, 0.05))))
-    rows.append(f"kernel/dp_perturb_512x512,{us:.1f},{err:.2e}")
 
     q = jax.random.normal(key, (1, 256, 4, 64))
     k = jax.random.normal(jax.random.fold_in(key, 2), (1, 256, 2, 64))
@@ -72,7 +65,6 @@ def _dp_mix_pair(N=8, sizes=((256, 512), (512,), (512, 512), (512,),
     multi-leaf worker tree — the fusion acceptance comparison."""
     from repro.core import dwfl, exchange as X
     from repro.core.channel import ChannelConfig
-    from repro.core.protocol import _bucket
     from repro.kernels.dp_mix import ops as mix_ops
 
     chan = ChannelConfig(n_workers=N, p_dbm=60.0, sigma=0.7, sigma_m=0.5,
@@ -83,20 +75,21 @@ def _dp_mix_pair(N=8, sizes=((256, 512), (512,), (512, 512), (512,),
     gtree = {k: 0.01 * v for k, v in tree.items()}
     gamma, eta = 0.05, 0.4
     plan = X.plan_complete(None, chan)
+    fspec = X.make_flat_spec(tree)
 
     def unfused(tree, gtree, k):
+        # the tree bucketed into one [N, d] leaf each round, then unravelled
         Xs = jax.tree_util.tree_map(lambda p, g: p - gamma * g, tree, gtree)
-        Xb, unravel = _bucket(Xs)
+        Xb = {"flat": fspec.flatten(Xs)}
         k1, k2 = jax.random.split(k)
         n = dwfl.dp_noise(k1, Xb, chan)
         m = dwfl.channel_noise(k2, Xb, chan.awgn_sigma)
-        return unravel(dwfl.exchange_dwfl(Xb, n, m, chan, eta)["flat"])
+        return fspec.unravel(dwfl.exchange_dwfl(Xb, n, m, chan, eta)["flat"])
 
     def fused(flat, gflat, seed):
         return mix_ops.dp_mix_round_plan(flat, gflat, seed, plan,
                                          gamma=gamma, eta=eta)
 
-    fspec = X.make_flat_spec(tree)
     flat = fspec.flatten(tree)
     gflat = fspec.flatten(gtree)
     return (jax.jit(unfused), (tree, gtree, key),

@@ -140,18 +140,18 @@ def _case(path: str, *, k: int, target_s: float, input_dim: int,
                                           hidden)
     key = jax.random.PRNGKey(1)
     tele = obs.TelemetrySpec()
-    kw = dict(flat=True, unravel_row=unravel_row)
+    kw = dict(unravel_row=unravel_row)
 
     if path == "static":
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
-                                 p_dbm=60.0, sigma=0.7, flat_buffer=True)
+                                 p_dbm=60.0, sigma=0.7)
         mk = lambda t: TJ.make_round_body(cfg, proto, store, telemetry=t,
                                           **kw)
         carry = lambda eps: TJ.TrajCarry(key, flat, eps=eps)
     elif path == "dynamic":
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
                                  p_dbm=60.0, channel_model="dynamic",
-                                 scenario="iot_dense", flat_buffer=True)
+                                 scenario="iot_dense")
         sim = proto.simulator()
         net0 = sim.init(jax.random.PRNGKey(2))
         mk = lambda t: TJ.make_round_body(cfg, proto, store, sim=sim,
@@ -162,7 +162,7 @@ def _case(path: str, *, k: int, target_s: float, input_dim: int,
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
                                  p_dbm=60.0, channel_model="dynamic",
                                  scenario="iot_dense",
-                                 replicates=replicates, flat_buffer=True)
+                                 replicates=replicates)
         fleet = FleetEngine(proto)
         net0 = fleet.init(jax.random.PRNGKey(2))
         flatR = jnp.broadcast_to(flat[None],

@@ -21,7 +21,7 @@ def main() -> None:
                             fig5_orthogonal, fig6_centralized,
                             privacy_table, kernel_bench, sampling_ablation,
                             accounting_bench, coherence_sweep,
-                            exchange_bench, fleet_sweep, trajectory_bench,
+                            fleet_sweep, trajectory_bench,
                             workers_bench)
 
     suites = [
@@ -32,9 +32,6 @@ def main() -> None:
         ("fig6_centralized", lambda: fig6_centralized.main(args.steps)),
         ("privacy_table", privacy_table.main),
         ("kernel_bench", kernel_bench.main),
-        # emits BENCH_exchange.json at the repo root (fused-vs-unfused
-        # exchange latency, R=1 and R=8 — the perf trajectory artifact)
-        ("exchange_bench", lambda: exchange_bench.main(args.steps)),
         # emits BENCH_trajectory.json at the repo root (K-chunked scan vs
         # per-round dispatch rounds/sec; asserts the >= 2x acceptance)
         ("trajectory_bench", lambda: trajectory_bench.main(args.steps)),

@@ -7,14 +7,14 @@ perf trajectory is versioned alongside the code.
 Three cases, one per driver path — static channel, dynamic (repro.net),
 fleet (R=8 replicates) — each timing rounds/sec of
 
-  * per-round: the legacy driver loop exactly as ``train.py --no-scan``
-    runs it (host ``jax.random.split`` + NumPy batch assembly + one jitted
-    dispatch per round + per-round chan/W list appends), vs
+  * per-round: a one-dispatch-per-round driver loop (host
+    ``jax.random.split`` + NumPy batch assembly + one jitted dispatch per
+    round + per-round chan/W list appends), vs
   * scan: ``ChunkRunner.run`` — one dispatch per K-round ``lax.scan``
     chunk with on-device batch sampling (repro.data.device).
 
-All cases run the FLAT-BUFFER round (the fused dp_mix path, PR 3) — the
-repo's hot path, and the regime the scan engine exists for: once the O(d)
+All cases run the flat-buffer round (the fused dp_mix path) — the
+repo's only layout, and the regime the scan engine exists for: once the O(d)
 round body is one fused kernel, per-round dispatch + host work dominate
 wall-clock (ISSUE 4 / the edge-mesh bottleneck of PAPERS.md). Task scale
 follows the benchmarks.common convention (the paper MLP config at smoke
@@ -127,7 +127,7 @@ def _case(path: str, k: int, chunks: int, n_workers: int, batch: int,
 
     if path == "static":
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
-                                 p_dbm=60.0, sigma=0.7, flat_buffer=True)
+                                 p_dbm=60.0, sigma=0.7)
         step = jax.jit(P.make_flat_train_step(cfg, proto, unravel_row))
 
         def per_round():
@@ -137,13 +137,13 @@ def _case(path: str, k: int, chunks: int, n_workers: int, batch: int,
                 f, _m = step(f, bat.next(), sk)
             return f
 
-        body = TJ.make_round_body(cfg, proto, store, flat=True,
+        body = TJ.make_round_body(cfg, proto, store,
                                   unravel_row=unravel_row)
         carry0 = TJ.TrajCarry(key, flat)
     elif path == "dynamic":
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
                                  p_dbm=60.0, channel_model="dynamic",
-                                 scenario="iot_dense", flat_buffer=True)
+                                 scenario="iot_dense")
         sim = proto.simulator()
         net0 = sim.init(jax.random.PRNGKey(2))
         step = jax.jit(P.make_dynamic_flat_train_step(cfg, proto,
@@ -162,23 +162,22 @@ def _case(path: str, k: int, chunks: int, n_workers: int, batch: int,
                 f, _m = step(f, bat.next(), sk, chan, Wt)
             return f
 
-        body = TJ.make_round_body(cfg, proto, store, sim=sim, flat=True,
+        body = TJ.make_round_body(cfg, proto, store, sim=sim,
                                   unravel_row=unravel_row)
         carry0 = TJ.TrajCarry(key, flat, net0)
     elif path == "fleet":
         from repro.fleet import FleetEngine
         proto = P.ProtocolConfig(scheme="dwfl", n_workers=n_workers,
                                  p_dbm=60.0, channel_model="dynamic",
-                                 scenario="iot_dense", replicates=replicates,
-                                 flat_buffer=True)
+                                 scenario="iot_dense", replicates=replicates)
         fleet = FleetEngine(proto)
         net0 = fleet.init(jax.random.PRNGKey(2))
         flatR = jnp.broadcast_to(flat[None], (replicates,) + flat.shape) + 0.0
         fleet_round = jax.jit(fleet.make_fleet_round(
-            cfg, flat=True, unravel_row=unravel_row))
+            cfg, unravel_row=unravel_row))
 
         def next_batch():
-            # the legacy R-fold host stacking the device store replaces
+            # the R-fold host stacking the device store replaces
             return jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs),
                 *[bat.next() for _ in range(replicates)])
@@ -193,7 +192,7 @@ def _case(path: str, k: int, chunks: int, n_workers: int, batch: int,
                 w_log.append(Wt)
             return f
 
-        body = TJ.make_round_body(cfg, proto, store, fleet=fleet, flat=True,
+        body = TJ.make_round_body(cfg, proto, store, fleet=fleet,
                                   unravel_row=unravel_row)
         carry0 = TJ.TrajCarry(key, flatR, net0)
     else:

@@ -71,7 +71,7 @@ def log(msg):
 
 
 def common_argv(steps, eval_every, chunk, seed=0):
-    return ["--arch", "dwfl-paper", "--flat-buffer", "--telemetry", "on",
+    return ["--arch", "dwfl-paper", "--telemetry", "on",
             "--accountant", "rdp", "--seed", str(seed), "--steps", str(steps),
             "--eval-every", str(eval_every), "--chunk-rounds", str(chunk)]
 

@@ -40,15 +40,18 @@ import repro.models.mlp as mlp
 params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=256)
 worker_params = jax.tree_util.tree_map(
     lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
+from repro.core import exchange as E
+spec = E.make_flat_spec(worker_params)                 # the buffer contract
+flat = spec.flatten(worker_params)                     # [N, d] — once
 
 # 3. The dynamic round: channel + mixing matrix are ARGUMENTS of the jitted
 #    step, not constants — count the traces to see it compile exactly once.
 traces = {"n": 0}
-_step = P.make_dynamic_train_step(cfg, proto)
+_step = P.make_dynamic_flat_train_step(cfg, proto, spec.unravel_row)
 
-def _counted(wp, batch, key, chan, W):
+def _counted(flat, batch, key, chan, W):
     traces["n"] += 1           # python side effect: runs once per (re)trace
-    return _step(wp, batch, key, chan, W)
+    return _step(flat, batch, key, chan, W)
 
 step = jax.jit(_counted)
 net_round = jax.jit(sim.round)
@@ -63,9 +66,9 @@ for t in range(151):
     net_state, chan, mask, W = net_round(ck, net_state)   # the radio round
     chan_log.append(chan)
     w_log.append(W)
-    worker_params, metrics = step(worker_params, batcher.next(), sk, chan, W)
+    flat, metrics = step(flat, batcher.next(), sk, chan, W)
     if t % 50 == 0:
-        ev_loss, ev_acc = evaluate(worker_params, batcher.full(128))
+        ev_loss, ev_acc = evaluate(spec.unravel(flat), batcher.full(128))
         print(f"round {t:4d}  c={float(chan.c):6.2f}  "
               f"active={int(jnp.sum(mask))}/{N}  "
               f"train_loss={float(metrics['loss']):.3f}  "

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get_arch
 from repro.core import protocol as P
+from repro.core.exchange import make_flat_spec
 from repro.data import classification_dataset, dirichlet_partition, FederatedBatcher
 from repro.fleet import FleetEngine, fleet_epsilon_report, mean_ci, stack_rounds
 
@@ -48,11 +49,13 @@ key = jax.random.PRNGKey(0)
 wp = jax.vmap(lambda k: jax.tree_util.tree_map(
     lambda a: jnp.broadcast_to(a[None], (N,) + a.shape),
     mlp.init(k, cfg, input_dim=64)))(jax.random.split(key, R))
+spec = make_flat_spec(wp, lead_axes=2)       # the [R, N, d] buffer contract
+wp = spec.flatten(wp)                        # raveled once
 
 # 3. ONE jitted call per round for the whole fleet: network evolution
 #    (fading/geometry/churn for all R) + the R-way vmapped DWFL step.
 traces = {"n": 0}
-_round = fleet.make_fleet_round(cfg)
+_round = fleet.make_fleet_round(cfg, spec=spec)
 
 def _counted(k, states, wp, batch):
     traces["n"] += 1             # python side effect: runs once per (re)trace
@@ -77,7 +80,7 @@ for t in range(ROUNDS):
 # 4. Across-replicate read-out: eval mean ± CI and the batched ε report.
 full = jax.tree_util.tree_map(
     lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), batchers[0].full(256))
-losses, accs = evaluate(wp, full)
+losses, accs = evaluate(spec.unravel(wp), full)
 lm, lc = mean_ci(losses)
 am, ac = mean_ci(accs)
 rep = fleet_epsilon_report(proto, stack_rounds(chan_log), stack_rounds(w_log))

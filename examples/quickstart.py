@@ -37,12 +37,11 @@ params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=256)
 worker_params = jax.tree_util.tree_map(
     lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
 
-# 4. Train on the persistent FLAT buffer (the fast path): params are
-#    raveled ONCE into a [N, d] f32 buffer, every round is one fused
-#    dp_mix kernel call (local SGD + on-chip DP noise + mixing matmul +
-#    self-correction + AWGN — a single pass over the buffer), and the
-#    pytree is recovered only at eval time. Swap make_flat_train_step for
-#    make_train_step (and drop the ravel) to get the classic pytree path.
+# 4. Train on the persistent FLAT buffer (the only parameter layout):
+#    params are raveled ONCE into a [N, d] f32 buffer, every round is one
+#    fused dp_mix kernel call (local SGD + on-chip DP noise + mixing
+#    matmul + self-correction + AWGN — a single pass over the buffer), and
+#    the pytree is recovered only at eval time.
 from repro.core import exchange as E
 spec = E.make_flat_spec(worker_params)                 # the buffer contract
 flat = spec.flatten(worker_params)                     # [N, d] — once

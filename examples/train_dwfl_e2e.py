@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import protocol as P
-from repro.checkpoint import save as ckpt_save
+from repro.core.exchange import make_flat_spec
+from repro.checkpoint import save_flat
 from repro.data import lm_dataset, LMBatcher
 from repro.models import model as M
 
@@ -63,18 +64,21 @@ def main():
     key = jax.random.PRNGKey(0)
     wp = P.init_worker_params(key, cfg, W)
     n = M.count_params(wp) // W
+    spec = make_flat_spec(wp)               # the [W, d] buffer contract
+    flat = spec.flatten(wp)
     print(f"[e2e] {cfg.name}: {n/1e6:.1f}M params x {W} workers, "
           f"eps/round={rep['epsilon_worst']:.3g} sigma={rep['sigma']:.3g}")
 
     toks = lm_dataset(W * 120_000, cfg.vocab_size, seed=0)
     bat = LMBatcher(toks, W, args.batch, args.seq_len, seed=0)
-    step = jax.jit(P.make_train_step(cfg, proto), donate_argnums=0)
+    step = jax.jit(P.make_flat_train_step(cfg, proto, spec.unravel_row),
+                   donate_argnums=0)
 
     t0 = time.time()
     losses = []
     for t in range(args.steps + 1):
         key, sk = jax.random.split(key)
-        wp, metrics = step(wp, bat.next(), sk)
+        flat, metrics = step(flat, bat.next(), sk)
         losses.append(float(metrics["loss"]))
         if t % max(1, args.steps // 10) == 0:
             tok_s = (t + 1) * W * args.batch * args.seq_len / (time.time() - t0)
@@ -95,7 +99,7 @@ def main():
         print("[e2e] WARNING: loss diverged — check channel power "
               "(--p-dbm) vs the worst-channel alignment (DESIGN.md §6b).")
     if args.checkpoint:
-        ckpt_save(args.checkpoint, wp, step=args.steps,
+        save_flat(args.checkpoint, flat, spec, step=args.steps,
                   metadata={"size": args.size})
         print(f"[e2e] checkpoint -> {args.checkpoint}")
 

@@ -104,33 +104,19 @@ print(row)
 assert float(row.split(",")[2]) == 1.0, f"dp_mix retraced: {row}"
 EOF
 
-echo "== ISSUE 3 smoke: exchange perf artifact (smoke shapes) =="
-python -m benchmarks.exchange_bench --smoke
-python - <<'EOF'
-import json
-# smoke writes into gitignored bench_out/ so it never clobbers (or gets
-# committed next to) the versioned full-run BENCH_exchange.json artifact
-rep = json.load(open("bench_out/BENCH_exchange_smoke.json"))
-assert {c["replicates"] for c in rep["cases"]} == {1, 8}, rep
-for c in rep["cases"]:
-    assert c["speedup"] > 1.0, c   # fused must not regress below unfused
-print("bench_out/BENCH_exchange_smoke.json:",
-      ", ".join(f"R={c['replicates']}: {c['speedup']}x" for c in rep["cases"]))
-EOF
-
-echo "== ISSUE 3 smoke: flat-buffer training path =="
+echo "== ISSUE 3 smoke: flat-buffer training path (static, fleet) =="
 python -m repro.launch.train \
-    --arch dwfl-paper --steps 10 --workers 6 --flat-buffer --eval-every 5
+    --arch dwfl-paper --steps 10 --workers 6 --eval-every 5
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 6 \
     --channel-model dynamic --scenario iot_dense --replicates 2 \
-    --flat-buffer --eval-every 5
+    --eval-every 5
 
 if [[ "$RUN_REGRESSION" == 1 ]]; then
 echo "== ISSUE 3 regression tests: unified exchange engine =="
 python -m pytest -q tests/test_exchange.py \
     tests/test_dwfl.py::test_eval_fn_lm_next_token_accuracy
-python -m pytest -q tests/test_kernels.py -k "dp_mix or dp_perturb"
+python -m pytest -q tests/test_kernels.py -k dp_mix
 fi
 
 echo "== ISSUE 4 smoke: scan-fused trajectory engine (>=2x vs per-round) =="
@@ -162,7 +148,7 @@ python -m repro.launch.train \
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 6 --batch-size 8 \
     --channel-model dynamic --scenario iot_dense --replicates 2 \
-    --flat-buffer --chunk-rounds 4 --eval-every 5
+    --chunk-rounds 4 --eval-every 5
 
 if [[ "$RUN_REGRESSION" == 1 ]]; then
 echo "== ISSUE 4 regression tests: scan-vs-loop equivalence =="
@@ -173,15 +159,15 @@ echo "== ISSUE 5 smoke: model-sharded flat buffer (repro.shard) =="
 # logical sharding on one device, then a REAL model=2 host-device mesh
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 6 --batch-size 8 \
-    --flat-buffer --model-shards 2 --chunk-rounds 4 --eval-every 5
+    --model-shards 2 --chunk-rounds 4 --eval-every 5
 XLA_FLAGS=--xla_force_host_platform_device_count=2 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 6 --batch-size 8 \
-    --flat-buffer --model-shards 2 --chunk-rounds 4 --eval-every 5
+    --model-shards 2 --chunk-rounds 4 --eval-every 5
 
 echo "== ISSUE 8 smoke: gather-free grad pass (chunk plan + remat) =="
 XLA_FLAGS=--xla_force_host_platform_device_count=2 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 5 --batch-size 8 \
-    --flat-buffer --model-shards 2 --max-chunk-cols 131072 --remat \
+    --model-shards 2 --max-chunk-cols 131072 --remat \
     --chunk-rounds 4 --eval-every 5
 
 echo "== ISSUE 8 smoke: shard perf artifact (4 forced devices, S in 1/2/4) =="
@@ -226,7 +212,7 @@ OBS_RUNDIR="bench_out/runlogs"
 rm -rf "$OBS_RUNDIR" && mkdir -p "$OBS_RUNDIR"
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 6 --batch-size 8 \
-    --channel-model dynamic --scenario iot_dense --flat-buffer \
+    --channel-model dynamic --scenario iot_dense \
     --chunk-rounds 4 --eval-every 5 --runlog-dir "$OBS_RUNDIR"
 python -m repro.obs.report "$OBS_RUNDIR"/*
 python - "$OBS_RUNDIR" <<'EOF'
@@ -267,15 +253,15 @@ echo "== ISSUE 9 smoke: sparse neighbor-list training path =="
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 8 --batch-size 8 \
     --channel-model dynamic --scenario iot_dense --sparse-neighbors 3 \
-    --flat-buffer --chunk-rounds 4 --eval-every 5
+    --chunk-rounds 4 --eval-every 5
 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 16 --batch-size 8 \
     --channel-model dynamic --scenario mesh_sparse --sparse-neighbors 4 \
-    --graph-fallback --flat-buffer --chunk-rounds 4 --eval-every 5
+    --graph-fallback --chunk-rounds 4 --eval-every 5
 XLA_FLAGS=--xla_force_host_platform_device_count=2 python -m repro.launch.train \
     --arch dwfl-paper --steps 10 --workers 8 --batch-size 8 \
     --channel-model dynamic --scenario iot_dense --sparse-neighbors 3 \
-    --flat-buffer --worker-shards 2 --chunk-rounds 4 --eval-every 0
+    --worker-shards 2 --chunk-rounds 4 --eval-every 0
 
 echo "== ISSUE 9 smoke: worker-scale perf artifact (N in 128/256/512) =="
 # cross-checks sparse vs the dense reference round before timing anything
@@ -314,7 +300,7 @@ ACCT_RUNDIR="bench_out/runlogs_accounting"
 rm -rf "$ACCT_RUNDIR" && mkdir -p "$ACCT_RUNDIR"
 python -m repro.launch.train \
     --arch dwfl-paper --steps 3 --workers 6 --batch-size 8 \
-    --channel-model dynamic --scenario iot_dense --flat-buffer \
+    --channel-model dynamic --scenario iot_dense \
     --chunk-rounds 2 --eval-every 0 --accountant rdp \
     --runlog-dir "$ACCT_RUNDIR"
 python - "$ACCT_RUNDIR" <<'EOF'
@@ -337,7 +323,7 @@ EOF
 echo "== ISSUE 10 smoke: rdp total-budget sigma calibration =="
 python -m repro.launch.train \
     --arch dwfl-paper --steps 3 --workers 6 --batch-size 8 \
-    --channel-model dynamic --scenario iot_dense --flat-buffer \
+    --channel-model dynamic --scenario iot_dense \
     --chunk-rounds 2 --eval-every 0 --accountant rdp --total-epsilon 4.0
 
 echo "== ISSUE 10 smoke: accountant gap artifact (T in 32/128/512) =="

@@ -1,7 +1,7 @@
 """CLI: lint the shipped compiled programs + library source.
 
     python -m repro.analysis                      # everything
-    python -m repro.analysis --programs static-tree,fleet-flat
+    python -m repro.analysis --programs static-flat,fleet-flat
     python -m repro.analysis --source-only        # AST lint only
     python -m repro.analysis --json report.json   # CI artifact
 

@@ -11,15 +11,16 @@ produces BOTH static views the checkers need:
 * ``hlo_text`` — the optimized HLO of the donated compile with RAW
   uint32 keys, exactly as the driver runs it (donation.py).
 
-The catalogue covers every shipped path: static/dynamic/fleet ×
-tree/flat, telemetry+ε in-carry, the sparse neighbor-list round
-(dense-mixing contract), and the model-sharded flat round twice
+The catalogue covers every shipped path: static/dynamic/fleet on the
+flat buffer, the orthogonal baseline's planless flat round, telemetry+ε
+in-carry, the sparse neighbor-list round (dense-mixing contract), and
+the model-sharded flat round twice
 — S=2 LOGICAL sharding (device-count independent) and the S=2 MESH
 program (shard_map + the gather-free collectives; needs >= 2 devices,
 so it drops out of ``available_programs()`` on a bare 1-device runtime
 and CI's lint job forces a 4-device host platform).
 
-Programs build lazily and independently: ``build_programs(["static-tree"])``
+Programs build lazily and independently: ``build_programs(["static-flat"])``
 traces/compiles one program, the CLI default builds all of them (<60 s
 CPU total — acceptance bound, pinned by tests/test_analysis.py).
 """
@@ -106,19 +107,17 @@ def _finish(name: str, body: Callable, wp, net=None, eps=None,
         sparse=sparse)
 
 
-def _static(name: str, flat: bool, n_shards: int = 1,
-            mesh: bool = False) -> BuiltProgram:
+def _static(name: str, n_shards: int = 1, mesh: bool = False,
+            scheme: str = "dwfl") -> BuiltProgram:
     from repro.core import exchange as X
     from repro.core import protocol as P
     from repro.core import trajectory as TJ
     cfg, store = _base()
-    proto = _proto(flat_buffer=flat)
+    proto = _proto(scheme=scheme)
     wp = P.init_worker_params(jax.random.PRNGKey(_SEED), cfg, N_WORKERS)
-    spec = None
+    spec = X.make_flat_spec(wp, n_shards=n_shards)
+    wp = spec.flatten(wp)
     shard_mesh = None
-    if flat:
-        spec = X.make_flat_spec(wp, n_shards=n_shards)
-        wp = spec.flatten(wp)
     if mesh:
         from repro.launch import mesh as mesh_lib
         from repro.launch import shardings as shardings_lib
@@ -132,22 +131,19 @@ def _static(name: str, flat: bool, n_shards: int = 1,
     return _finish(name, body, wp, spec=spec if mesh else None)
 
 
-def _dynamic(name: str, flat: bool, telemetry: bool = False,
+def _dynamic(name: str, telemetry: bool = False,
              sparse_k: int = 0) -> BuiltProgram:
     from repro.core import exchange as X
     from repro.core import protocol as P
     from repro.core import trajectory as TJ
     cfg, store = _base()
     proto = _proto(channel_model="dynamic", scenario="iot_dense",
-                   coherence_rounds=4, flat_buffer=flat,
-                   sparse_neighbors=sparse_k)
+                   coherence_rounds=4, sparse_neighbors=sparse_k)
     sim = proto.simulator()
     net = sim.init(jax.random.PRNGKey(1))
     wp = P.init_worker_params(jax.random.PRNGKey(_SEED), cfg, N_WORKERS)
-    spec = None
-    if flat:
-        spec = X.make_flat_spec(wp)
-        wp = spec.flatten(wp)
+    spec = X.make_flat_spec(wp)
+    wp = spec.flatten(wp)
     tele = eps0 = None
     if telemetry:
         from repro import obs
@@ -163,20 +159,14 @@ def _dynamic(name: str, flat: bool, telemetry: bool = False,
                    sparse=sparse_k > 0)
 
 
-def _fleet(name: str, flat: bool) -> BuiltProgram:
+def _fleet(name: str) -> BuiltProgram:
     from repro.core import trajectory as TJ
     from repro.fleet import FleetEngine
     cfg, store = _base()
     proto = _proto(channel_model="dynamic", scenario="iot_dense",
-                   coherence_rounds=4, replicates=REPLICATES,
-                   flat_buffer=flat)
+                   coherence_rounds=4, replicates=REPLICATES)
     fleet = FleetEngine(proto)
-    key = jax.random.PRNGKey(_SEED)
-    spec = None
-    if flat:
-        wp, spec = fleet.init_flat_spec(key, cfg)
-    else:
-        wp = fleet.init_worker_params(key, cfg)
+    wp, spec = fleet.init_flat_spec(jax.random.PRNGKey(_SEED), cfg)
     net = fleet.init(jax.random.PRNGKey(1))
     body = TJ.make_round_body(cfg, proto, store, fleet=fleet, spec=spec)
     return _finish(name, body, wp, net=net, dynamic=True)
@@ -184,27 +174,27 @@ def _fleet(name: str, flat: bool) -> BuiltProgram:
 
 # name -> zero-arg builder; ORDER is the CLI report order
 PROGRAMS: Dict[str, Callable[[], BuiltProgram]] = {
-    "static-tree": lambda: _static("static-tree", flat=False),
-    "static-flat": lambda: _static("static-flat", flat=True),
-    "dynamic-tree": lambda: _dynamic("dynamic-tree", flat=False),
-    "dynamic-flat-tele": lambda: _dynamic("dynamic-flat-tele", flat=True,
+    "static-flat": lambda: _static("static-flat"),
+    # the baselines' planless round: the local step and the orthogonal
+    # exchange on the [W, d] buffer as one leaf (no dp_mix kernel)
+    "static-flat-orthogonal": lambda: _static("static-flat-orthogonal",
+                                              scheme="orthogonal"),
+    "dynamic-flat-tele": lambda: _dynamic("dynamic-flat-tele",
                                           telemetry=True),
     # the sparse neighbor-list round (padded [N, k] W, O(N·k·d) mixing):
     # the program the dense-mixing checker enforces the no-[N,N]-
     # contraction contract on — telemetry+ε in-carry so the graph-aware
     # accountant's sparse branch is inside the checked jaxpr too.
-    "dynamic-sparse-flat": lambda: _dynamic("dynamic-sparse-flat", flat=True,
+    "dynamic-sparse-flat": lambda: _dynamic("dynamic-sparse-flat",
                                             telemetry=True, sparse_k=3),
-    "fleet-tree": lambda: _fleet("fleet-tree", flat=False),
-    "fleet-flat": lambda: _fleet("fleet-flat", flat=True),
-    "shard-flat-s2": lambda: _static("shard-flat-s2", flat=True,
-                                     n_shards=2),
+    "fleet-flat": lambda: _fleet("fleet-flat"),
+    "shard-flat-s2": lambda: _static("shard-flat-s2", n_shards=2),
     # the REAL mesh program (shard_map + collectives): the one the
     # gather-free checker enforces the memory contract on. Needs >= 2
     # devices (CI lint exports XLA_FLAGS=--xla_force_host_platform_
     # device_count=4; see available_programs).
-    "shard-flat-s2-mesh": lambda: _static("shard-flat-s2-mesh", flat=True,
-                                          n_shards=2, mesh=True),
+    "shard-flat-s2-mesh": lambda: _static("shard-flat-s2-mesh", n_shards=2,
+                                          mesh=True),
 }
 
 # programs with an environment precondition: name -> () -> bool

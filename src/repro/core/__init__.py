@@ -6,8 +6,8 @@ from repro.core.exchange import (  # noqa: F401
     mix_exchange, resolve_spec, worker_unravelers,
 )
 from repro.core.protocol import (  # noqa: F401
-    ProtocolConfig, make_train_step, make_dynamic_train_step,
-    make_flat_train_step, make_dynamic_flat_train_step, make_eval_fn,
+    ProtocolConfig, make_flat_train_step, make_dynamic_flat_train_step,
+    make_eval_fn,
     init_worker_params, epsilon_report,
 )
 from repro.core.trajectory import (  # noqa: F401

@@ -34,9 +34,10 @@ ladder. Every spec is verified against ``dwfl.matrix_form_reference``
 
 The same plans feed the fused Pallas kernel family
 ``repro.kernels.dp_mix`` (local SGD step + on-chip DP noise + the
-[N,N]×[N,d] mixing matmul + self-correction + AWGN in one HBM pass over a
-persistent flat [N, d] parameter buffer — see ``flatten_worker_tree`` /
-``MixPlan`` and protocol.make_flat_train_step).
+[N,N]×[N,d] mixing matmul + self-correction + AWGN in one HBM pass over
+the persistent flat [N, d] parameter buffer — see ``FlatSpec`` /
+``MixPlan`` and protocol.make_flat_train_step). The baselines have no
+plan: the flat round runs their ``run`` on the [N, d] buffer as one leaf.
 """
 from __future__ import annotations
 
@@ -61,6 +62,16 @@ def _leaf_keys(key, tree):
     return jax.tree_util.tree_unflatten(treedef, list(keys))
 
 
+def _normal(key, shape) -> jnp.ndarray:
+    """Standard normals [shape] f32, held apart from the amplitude that
+    scales them: XLA would otherwise fold a static amplitude into the
+    sampler's constants in some programs and not in others (a scan body
+    against a plain jit), and the same draw would round differently by
+    an ULP or two, breaking chunk invariance."""
+    return jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, jnp.float32))
+
+
 def dp_noise(key, X: Tree, chan) -> Tree:
     """n_k = |h_k| sqrt(β_k P_k) * 𝒢_k,  𝒢_k ~ N(0, σ²) i.i.d per entry.
 
@@ -73,7 +84,7 @@ def dp_noise(key, X: Tree, chan) -> Tree:
 
     def one(k, x):
         amp = scale.reshape((x.shape[0],) + (1,) * (x.ndim - 1))
-        return (amp * jax.random.normal(k, x.shape, jnp.float32)).astype(x.dtype)
+        return (amp * _normal(k, x.shape)).astype(x.dtype)
 
     return jax.tree_util.tree_map(one, _leaf_keys(key, X), X)
 
@@ -81,7 +92,7 @@ def dp_noise(key, X: Tree, chan) -> Tree:
 def channel_noise(key, X: Tree, sigma_m) -> Tree:
     """m_i ~ N(0, σ_m²) per receiver (leading axis) per entry."""
     def one(k, x):
-        return (sigma_m * jax.random.normal(k, x.shape, jnp.float32)).astype(x.dtype)
+        return (sigma_m * _normal(k, x.shape)).astype(x.dtype)
     return jax.tree_util.tree_map(one, _leaf_keys(key, X), X)
 
 
@@ -346,33 +357,33 @@ def run_mix(X, noise_n, noise_m, eta, plan: MixPlan) -> Tree:
                         listen=plan.listen)
 
 
-def _run_complete(X, keys, chan, proto, *, axis=None, W=None):
+def _run_complete(X, keys, chan, proto, *, W=None):
     k_n, k_m, k_x = keys
     n = dp_noise(k_n, X, chan)
     m = channel_noise(k_m, X, chan.awgn_sigma)
     return run_mix(X, n, m, proto.eta, plan_complete(proto, chan))
 
 
-def _run_gossip(X, keys, chan, proto, *, axis=None, W=None):
+def _run_gossip(X, keys, chan, proto, *, W=None):
     zero = jax.tree_util.tree_map(jnp.zeros_like, X)
     return run_mix(X, zero, zero, proto.eta, plan_gossip(proto, chan))
 
 
-def _run_topology(X, keys, chan, proto, *, axis=None, W=None):
+def _run_topology(X, keys, chan, proto, *, W=None):
     k_n, k_m, k_x = keys
     n = dp_noise(k_n, X, chan)
     m = channel_noise(k_m, X, chan.awgn_sigma)
     return run_mix(X, n, m, proto.eta, plan_topology(proto, chan, W_arg=W))
 
 
-def _run_dynamic(X, keys, chan, proto, *, axis=None, W=None):
+def _run_dynamic(X, keys, chan, proto, *, W=None):
     k_n, k_m = keys[0], keys[1]
     n = dp_noise(k_n, X, chan)
     m = channel_noise(k_m, X, chan.awgn_sigma)
     return run_mix(X, n, m, proto.eta, plan_dynamic(proto, chan, W_arg=W))
 
 
-def _run_dynamic_sparse(X, keys, chan, proto, *, axis=None, W=None):
+def _run_dynamic_sparse(X, keys, chan, proto, *, W=None):
     k_n, k_m = keys[0], keys[1]
     n = dp_noise(k_n, X, chan)
     m = channel_noise(k_m, X, chan.awgn_sigma)
@@ -380,23 +391,11 @@ def _run_dynamic_sparse(X, keys, chan, proto, *, axis=None, W=None):
                    plan_dynamic_sparse(proto, chan, W_arg=W))
 
 
-def _run_sampled(X, keys, chan, proto, *, axis=None, W=None):
+def _run_sampled(X, keys, chan, proto, *, W=None):
     k_n, k_m, k_x = keys
     n = dp_noise(k_n, X, chan)
     m = channel_noise(k_m, X, chan.awgn_sigma)
     return run_mix(X, n, m, proto.eta, plan_sampled(proto, chan, k_x))
-
-
-def _run_collective(X, keys, chan, proto, *, axis=None, W=None):
-    """shard_map realization of the complete-graph spec: the superposition
-    is a literal lax.psum over the worker mesh axis (core.dwfl keeps the
-    per-worker implementation — it is the same update, computed with a
-    collective instead of the [N,N] matmul)."""
-    from repro.core import dwfl
-    k_n, k_m, k_x = keys
-    n = dp_noise(k_n, X, chan)
-    m = channel_noise(k_m, X, chan.awgn_sigma)
-    return dwfl.exchange_dwfl_collective(X, n, m, chan, proto.eta, axis)
 
 
 # Floor for the inverted per-link gain |h_j|√(α_j P_j) in the orthogonal
@@ -444,10 +443,9 @@ def run_orthogonal(X: Tree, key, chan, eta) -> Tree:
     k2 = jax.tree_util.tree_map(lambda p: p[1], pairs)
     n = jax.tree_util.tree_map(
         lambda k, x: inv_gain.reshape((x.shape[0],) + (1,) * (x.ndim - 1))
-        * jax.random.normal(k, x.shape, jnp.float32), k1, X)
+        * _normal(k, x.shape), k1, X)
     m = jax.tree_util.tree_map(
-        lambda k, x: mean_m_std * jax.random.normal(k, x.shape, jnp.float32),
-        k2, X)
+        lambda k, x: mean_m_std * _normal(k, x.shape), k2, X)
     return mix_exchange(X, n, m, 1.0, eta, complete_W(N), self_scale=0.0)
 
 
@@ -460,18 +458,18 @@ def run_centralized(X: Tree, noise_n: Tree, key, chan) -> Tree:
     c = chan.c
     m = jax.tree_util.tree_map(
         lambda k, x: jnp.asarray(chan.awgn_sigma, jnp.float32)
-        * jax.random.normal(k, (1,) + x.shape[1:], jnp.float32),
+        * _normal(k, (1,) + x.shape[1:]),
         _leaf_keys(key, X), X)
     W = jnp.ones((N, N), jnp.float32) / N
     return mix_exchange(X, noise_n, m, c, 1.0, W,
                         self_scale=0.0, m_scale=1.0 / (c * N))
 
 
-def _run_orthogonal_spec(X, keys, chan, proto, *, axis=None, W=None):
+def _run_orthogonal_spec(X, keys, chan, proto, *, W=None):
     return run_orthogonal(X, keys[2], chan, proto.eta)
 
 
-def _run_centralized_spec(X, keys, chan, proto, *, axis=None, W=None):
+def _run_centralized_spec(X, keys, chan, proto, *, W=None):
     k_n, k_m, k_x = keys
     n = dp_noise(k_n, X, chan)
     return run_centralized(X, n, k_m, chan)
@@ -484,16 +482,13 @@ def _run_centralized_spec(X, keys, chan, proto, *, axis=None, W=None):
 
 @dataclass(frozen=True)
 class ExchangeSpec:
-    """One exchange variant: how to run a round (``run``), whether the
-    worker tree may be bucketed into one flat leaf first (``fuse_ok`` —
-    True exactly for the pure mixing family, where the update treats every
-    parameter entry identically; the orthogonal/centralized baselines keep
-    their historical per-leaf PRNG layout), and how to build the fused-
-    kernel plan (``plan`` — None for baselines outside the mixing family).
+    """One exchange variant: how to run a round on worker-stacked leaves
+    (``run``) and how to build the fused-kernel plan (``plan`` — None for
+    the orthogonal/centralized baselines outside the mixing family, whose
+    ``run`` the flat round calls on the [W, d] buffer as one leaf).
     """
     name: str
     run: Callable
-    fuse_ok: bool = True
     plan: Optional[Callable] = None
 
 
@@ -505,19 +500,14 @@ SPECS = {
     "dynamic_sparse": ExchangeSpec("dynamic_sparse", _run_dynamic_sparse,
                                    plan=plan_dynamic_sparse),
     "sampled": ExchangeSpec("sampled", _run_sampled, plan=plan_sampled),
-    "collective": ExchangeSpec("collective", _run_collective),
-    "orthogonal": ExchangeSpec("orthogonal", _run_orthogonal_spec,
-                               fuse_ok=False),
-    "centralized": ExchangeSpec("centralized", _run_centralized_spec,
-                                fuse_ok=False),
+    "orthogonal": ExchangeSpec("orthogonal", _run_orthogonal_spec),
+    "centralized": ExchangeSpec("centralized", _run_centralized_spec),
 }
 
 
-def resolve_spec(proto, axis: Optional[str] = None,
-                 dynamic: bool = False) -> ExchangeSpec:
+def resolve_spec(proto, dynamic: bool = False) -> ExchangeSpec:
     """Scheme/scenario → ExchangeSpec (the ONE routing policy; both the
-    static and the dynamic train-step factories consult it, so e.g. the
-    fuse_exchange guard cannot drift between them again). ``dynamic``:
+    static and the dynamic train-step factories consult it). ``dynamic``:
     the per-round traced-W step (repro.net) — only scheme="dwfl" has
     dynamic semantics (the baselines are static-channel comparisons)."""
     if dynamic:
@@ -540,8 +530,6 @@ def resolve_spec(proto, axis: Optional[str] = None,
             return SPECS["topology"]
         if proto.participation < 1.0:
             return SPECS["sampled"]
-        if axis is not None:
-            return SPECS["collective"]
         return SPECS["complete"]
     raise ValueError(proto.scheme)
 

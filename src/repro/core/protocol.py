@@ -1,25 +1,23 @@
-"""Protocol configuration and the DWFL training-step factory.
+"""Protocol configuration and the DWFL training-step factories.
 
-``make_train_step`` composes: per-worker stochastic gradients (vmap over the
-worker axis) → gradient clipping to g_max → local SGD step (Alg. 1 line 5;
-optionally the fused Pallas dp_perturb kernel) → DP noise generation →
-parameter exchange → metrics. The exchange is dispatched through the
-unified mixing-matrix engine (repro.core.exchange.resolve_spec — ONE
-routing table for the static and dynamic steps; the scheme if/elif ladder
-is gone).
+Parameters live in ONE persistent [W, d] f32 buffer (exchange.FlatSpec —
+ravel once at init, train flat, unravel only at eval/checkpoint). A round
+is: per-worker clipped gradients (vmap over the worker rows; each
+worker's loss is a function of its flat row) → local SGD step (Alg. 1
+line 5) → DP noise → parameter exchange → metrics. The exchange is
+dispatched through the unified mixing-matrix engine
+(repro.core.exchange.resolve_spec — ONE routing table for the static and
+dynamic steps). For the mixing family the whole O(d) post-gradient
+pipeline is the fused dp_mix kernel (local step + on-chip noise + mixing
+matmul + self-correction + AWGN in one HBM pass); the baselines, whose
+specs have no fused plan, take the local step and run their element-wise
+exchange on the [W, d] leaf.
 
 Schemes:
     dwfl         — the paper's algorithm (over-the-air superposition)
     orthogonal   — pairwise transmission baseline (Remark 4.1 / Fig. 5)
     centralized  — PS over MAC baseline ([11] / Fig. 6)
     gossip       — noiseless decentralized averaging (σ = σ_m = 0 ablation)
-
-``make_flat_train_step`` / ``make_dynamic_flat_train_step`` are the
-flat-buffer twins: parameters live in ONE persistent [W, d] f32 buffer
-(exchange.flatten_worker_tree — ravel once at init, train flat, unravel
-only at eval/checkpoint) and the whole O(d) post-gradient pipeline is the
-fused Pallas dp_mix kernel (local step + on-chip noise + mixing matmul +
-self-correction + AWGN in one HBM pass).
 """
 from __future__ import annotations
 
@@ -31,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core import dwfl, privacy
+from repro.core import privacy
 from repro.core import exchange as exchange_lib
 from repro.core.channel import ChannelConfig, ChannelState
 from repro.models import model as M
@@ -51,12 +49,6 @@ class ProtocolConfig:
     fading: str = "rayleigh"
     seed: int = 0
     target_epsilon: float = 0.0  # >0: calibrate σ to hit this per-round ε
-    use_collective: bool = False # shard_map/psum exchange (vs vectorized pjit)
-    use_pallas: bool = False     # fused dp_perturb kernel for the local step
-    fuse_exchange: bool = False  # bucket all leaves into ONE flat vector for
-                                 # the over-the-air exchange (1 all-reduce +
-                                 # 1 PRNG pass instead of per-leaf; beyond-
-                                 # paper systems optimization, §Perf olmo)
     participation: float = 1.0   # beyond-paper: per-round worker sampling
                                  # rate q (<1 enables privacy amplification
                                  # by subsampling; see privacy.epsilon_sampled)
@@ -76,13 +68,6 @@ class ProtocolConfig:
                                  # realizations through ONE compiled step
                                  # (repro.fleet.FleetEngine; launch/train.py
                                  # --replicates)
-    flat_buffer: bool = False    # train on the persistent flat [W, d]
-                                 # buffer with the fused dp_mix kernel
-                                 # (make_flat_train_step /
-                                 # make_dynamic_flat_train_step;
-                                 # launch/train.py --flat-buffer). Mixing-
-                                 # family schemes only (dwfl/gossip incl.
-                                 # topology/sampled/dynamic).
     sparse_neighbors: int = 0    # >0: degree cap k — the dynamic per-round
                                  # W becomes a padded neighbor list
                                  # (repro.net.sparse.SparseW) and mixing,
@@ -281,7 +266,7 @@ def epsilon_report(proto: ProtocolConfig, chan,
     # strictly larger at equal σ.
     e_round, d_round = float(eps_scheme.max()), proto.delta
     # amplification applies ONLY when the round actually samples: the
-    # make_train_step dispatch takes the sampled exchange just for the
+    # resolve_spec dispatch takes the sampled exchange just for the
     # complete-graph dwfl scheme (topology/orthogonal/centralized branches
     # transmit every round — quoting an amplified budget there would
     # UNDER-state the real privacy loss).
@@ -331,132 +316,6 @@ def epsilon_report(proto: ProtocolConfig, chan,
     return rep
 
 
-def _make_local_pass(cfg: ModelConfig, proto: ProtocolConfig):
-    """Shared per-worker pass: vmapped clipped gradients + local SGD step
-    (Alg. 1 lines 4-5) — identical between the static and dynamic rounds."""
-    gamma = proto.gamma
-
-    def local_grads(worker_params, batch):
-        def one(p, b):
-            loss, g = jax.value_and_grad(M.loss_fn)(p, b, cfg)
-            g, gnorm = privacy.clip_gradient_tree(g, proto.clip)
-            return loss, g, gnorm
-        with jax.named_scope("dwfl.grad"):
-            return jax.vmap(one)(worker_params, batch)
-
-    def local_step(worker_params, grads):
-        if proto.use_pallas:
-            from repro.kernels.dp_perturb import ops as dp_ops
-            return jax.tree_util.tree_map(
-                lambda p, g: dp_ops.sgd_update(p, g, gamma), worker_params, grads)
-        return jax.tree_util.tree_map(
-            lambda p, g: (p.astype(jnp.float32) - gamma * g.astype(jnp.float32)
-                          ).astype(p.dtype), worker_params, grads)
-
-    return local_grads, local_step
-
-
-def _bucket(X):
-    """Worker-stacked pytree -> single [W, total] f32 leaf + unravel
-    (the per-round fuse_exchange path; the flat-buffer path flattens ONCE
-    at init instead — exchange.flatten_worker_tree)."""
-    flat = exchange_lib.flatten_worker_tree(X)
-    unravel_full, _ = exchange_lib.worker_unravelers(X)
-    return {"flat": flat}, unravel_full
-
-
-@jax.named_scope("dwfl.telemetry")
-def _metrics(losses, gnorms, X):
-    return {
-        "loss": jnp.mean(losses),
-        "grad_norm": jnp.mean(gnorms),
-        "param_norm": jnp.sqrt(sum(
-            jnp.sum(x.astype(jnp.float32) ** 2)
-            for x in jax.tree_util.tree_leaves(X))),
-    }
-
-
-def make_train_step(cfg: ModelConfig, proto: ProtocolConfig,
-                    axis: Optional[str] = None) -> Callable:
-    """Build the jittable DWFL round (STATIC channel: the one-shot
-    realization is closed over as compile-time constants — the paper's
-    setup; for the per-round traced channel see make_dynamic_train_step).
-
-    Vectorized path (axis=None): worker_params leaves are [W, ...] and the
-    exchange sums over axis 0 (XLA → all-reduce when sharded over ``data``).
-    Collective path (axis="data"): call under shard_map; leaves are local.
-    """
-    chan = proto.channel()
-    spec = exchange_lib.resolve_spec(proto, axis)
-    local_grads, local_step = _make_local_pass(cfg, proto)
-
-    def step(worker_params, batch, key):
-        """batch leaves: [W, per_worker_batch, ...]."""
-        keys = jax.random.split(key, 3)
-        losses, grads, gnorms = local_grads(worker_params, batch)
-        X = local_step(worker_params, grads)
-
-        if proto.n_workers < 2:
-            # degenerate federation (single worker / single-device test
-            # mesh): no peers to exchange with — plain local SGD round.
-            return X, _metrics(losses, gnorms, X)
-
-        unravel = None
-        if proto.fuse_exchange and spec.fuse_ok:
-            X, unravel = _bucket(X)
-
-        X = spec.run(X, keys, chan, proto, axis=axis)
-
-        if unravel is not None:
-            X = unravel(X["flat"])
-
-        return X, _metrics(losses, gnorms, X)
-
-    return step
-
-
-def make_dynamic_train_step(cfg: ModelConfig, proto: ProtocolConfig) -> Callable:
-    """Build the DWFL round for channel_model="dynamic" (repro.net).
-
-    Unlike make_train_step, the channel and mixing matrix are traced
-    ARGUMENTS, not closed-over constants::
-
-        step(worker_params, batch, key, chan, W) -> (worker_params', metrics)
-
-    ``chan`` is a net.TracedChannelState and ``W`` the round's [N, N]
-    doubly-stochastic mixing matrix (both from NetworkSimulator.round), so
-    ONE compiled step serves every fading block, geometry, and churn
-    realization — zero retraces across draws (asserted by
-    tests/test_net.py and benchmarks/kernel_bench.py ``net/retrace``).
-    Only scheme="dwfl" has dynamic semantics (the baselines are static-
-    channel comparisons).
-    """
-    spec = exchange_lib.resolve_spec(proto, dynamic=True)
-    local_grads, local_step = _make_local_pass(cfg, proto)
-
-    def step(worker_params, batch, key, chan, W):
-        keys = jax.random.split(key)
-        losses, grads, gnorms = local_grads(worker_params, batch)
-        X = local_step(worker_params, grads)
-        if proto.n_workers < 2:
-            return X, _metrics(losses, gnorms, X)
-
-        unravel = None
-        if proto.fuse_exchange and spec.fuse_ok:
-            X, unravel = _bucket(X)
-        X = spec.run(X, keys, chan, proto, W=W)
-        if unravel is not None:
-            X = unravel(X["flat"])
-        return X, _metrics(losses, gnorms, X)
-
-    return step
-
-
-# ---------------------------------------------------------------------------
-# flat-buffer path: persistent [W, d] params + the fused dp_mix round
-# ---------------------------------------------------------------------------
-
-
 def _make_flat_local_pass(cfg: ModelConfig, proto: ProtocolConfig,
                           unravel_row, remat: bool = False):
     """Per-worker clipped gradients ON THE FLAT BUFFER: each worker's loss
@@ -493,30 +352,36 @@ def _flat_metrics(losses, gnorms, flat):
     }
 
 
-def _flat_spec(proto: ProtocolConfig, dynamic: bool,
-               axis=None) -> "exchange_lib.ExchangeSpec":
-    spec = exchange_lib.resolve_spec(proto, axis, dynamic=dynamic)
+def _mixing_spec(proto: ProtocolConfig,
+                 dynamic: bool) -> "exchange_lib.ExchangeSpec":
+    """The spec of a round that needs the fused plan (the model- and
+    worker-sharded rounds): the mixing family only."""
+    spec = exchange_lib.resolve_spec(proto, dynamic=dynamic)
     if spec.plan is None:
         raise ValueError(
-            f"flat-buffer training supports the mixing-family exchanges "
-            f"only (dwfl/gossip incl. topology/sampled/dynamic); "
-            f"spec {spec.name!r} has no fused plan")
+            f"the sharded rounds support the mixing-family exchanges only "
+            f"(dwfl/gossip incl. topology/sampled/dynamic); spec "
+            f"{spec.name!r} has no fused plan")
     return spec
 
 
 def make_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
                          unravel_row) -> Callable:
-    """Flat-buffer twin of make_train_step (STATIC channel):
+    """The DWFL round (STATIC channel: the one-shot realization is closed
+    over as compile-time constants — the paper's setup):
 
         step(flat, batch, key) -> (flat', metrics)      # flat: [W, d] f32
 
     ``unravel_row`` maps one flat row to one worker's pytree
-    (exchange.worker_unravelers) — used only inside the grad vmap; the
-    O(d) post-gradient pipeline is ONE fused dp_mix kernel call.
+    (exchange.FlatSpec.unravel_row) — used only inside the grad vmap. For
+    the mixing family the O(d) post-gradient pipeline is ONE fused dp_mix
+    kernel call; the orthogonal and centralized baselines (no fused plan)
+    take the local step and run their element-wise exchange on the
+    [W, d] buffer as one leaf.
     """
     from repro.kernels.dp_mix import ops as mix_ops
     chan = proto.channel()
-    spec = _flat_spec(proto, dynamic=False)
+    spec = exchange_lib.resolve_spec(proto)
     local_grads = _make_flat_local_pass(cfg, proto, unravel_row)
     gamma, eta = proto.gamma, proto.eta
 
@@ -524,12 +389,18 @@ def make_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
         k_n, k_m, k_x = jax.random.split(key, 3)
         losses, g, gnorms = local_grads(flat, batch)
         if proto.n_workers < 2:
+            # degenerate federation: no peers — plain local SGD
             flat = flat - gamma * g
-            return flat, _flat_metrics(losses, gnorms, flat)
-        with jax.named_scope("dwfl.mix"):
-            plan = spec.plan(proto, chan, k_x)
-        flat = mix_ops.dp_mix_round_plan(
-            flat, g, mix_ops.seed_from_key(k_n), plan, gamma=gamma, eta=eta)
+        elif spec.plan is None:
+            with jax.named_scope("dwfl.mix"):
+                flat = spec.run(flat - gamma * g, (k_n, k_m, k_x), chan,
+                                proto)
+        else:
+            with jax.named_scope("dwfl.mix"):
+                plan = spec.plan(proto, chan, k_x)
+            flat = mix_ops.dp_mix_round_plan(
+                flat, g, mix_ops.seed_from_key(k_n), plan, gamma=gamma,
+                eta=eta)
         return flat, _flat_metrics(losses, gnorms, flat)
 
     return step
@@ -537,15 +408,21 @@ def make_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
 
 def make_dynamic_flat_train_step(cfg: ModelConfig, proto: ProtocolConfig,
                                  unravel_row) -> Callable:
-    """Flat-buffer twin of make_dynamic_train_step (repro.net):
+    """The DWFL round for channel_model="dynamic" (repro.net):
 
         step(flat, batch, key, chan, W) -> (flat', metrics)
 
-    ``chan``/``W`` are traced per-round arguments (NetworkSimulator.round);
-    the fused kernel takes every channel quantity as an operand, so one
-    compiled step serves every realization with zero retraces."""
+    Unlike make_flat_train_step, the channel and mixing matrix are traced
+    ARGUMENTS, not closed-over constants: ``chan`` is a
+    net.TracedChannelState and ``W`` the round's mixing matrix (both from
+    NetworkSimulator.round). The fused kernel takes every channel quantity
+    as an operand, so ONE compiled step serves every fading block,
+    geometry and churn realization — zero retraces across draws (asserted
+    by tests/test_net.py and benchmarks/kernel_bench.py ``net/retrace``).
+    Only scheme="dwfl" has dynamic semantics (the baselines are static-
+    channel comparisons)."""
     from repro.kernels.dp_mix import ops as mix_ops
-    spec = _flat_spec(proto, dynamic=True)
+    spec = exchange_lib.resolve_spec(proto, dynamic=True)
     local_grads = _make_flat_local_pass(cfg, proto, unravel_row)
     gamma, eta = proto.gamma, proto.eta
 

@@ -14,22 +14,24 @@ This module rolls K consecutive rounds into a single ``lax.scan``:
     body(carry) -> (carry', out)           one full DWFL round, on device
     ChunkRunner.run(carry, K)              ONE dispatch = K rounds
 
-with a donated carry (PRNG key, params — worker tree or flat [W, d] /
-[R, W, d] buffer — and the repro.net ``NetState`` when dynamic) and
+with a donated carry (PRNG key, the flat [W, d] / [R, W, d] parameter
+buffer, and the repro.net ``NetState`` when dynamic) and
 stacked ``[K, ...]`` outputs (metrics, per-round TracedChannelState and
 mixing matrices) that feed ``epsilon_report`` / ``fleet_epsilon_report``
 directly. Inside the scan: on-device key folding (the SAME split
 discipline whether the trajectory is chunked K-at-a-time or stepped one
 round per dispatch — chunk boundaries cannot change the realized PRNG
-stream), net evolution via ``NetworkSimulator.round``, the unified-engine
-round (fused dp_mix in flat mode), and on-device batch sampling from a
-device-resident store (repro.data.device) instead of per-round host NumPy.
+stream), net evolution via ``NetworkSimulator.round``, the flat-buffer
+round (fused dp_mix for the mixing family), and on-device batch sampling
+from a
+device-resident store (repro.data.device) instead of per-round host
+NumPy.
 
-All three driver paths share the one body factory:
+All three paths share the one body factory:
 
-    make_round_body(cfg, proto, store)                      static channel
-    make_round_body(cfg, proto, store, sim=sim)             dynamic (repro.net)
-    make_round_body(cfg, proto, store, fleet=fleet)         fleet ([R, ...])
+    make_round_body(cfg, proto, store, spec=spec)              static channel
+    make_round_body(cfg, proto, store, spec=spec, sim=sim)     dynamic (repro.net)
+    make_round_body(cfg, proto, store, spec=spec, fleet=fleet) fleet ([R, ...])
 
 ``run_per_round`` executes the same body one jitted dispatch per round —
 the equivalence/benchmark baseline (tests/test_trajectory.py asserts the
@@ -50,9 +52,8 @@ from repro.obs.spans import span
 class TrajCarry(NamedTuple):
     """The donated scan carry: everything a round consumes and rewrites.
 
-    ``params`` is the worker-stacked pytree ([W, ...] leaves; [R, W, ...]
-    for the fleet) or the persistent flat buffer ([W, d] / [R, W, d]) in
-    flat mode. ``net`` is the repro.net NetState (stacked for the fleet),
+    ``params`` is the persistent flat buffer ([W, d]; [R, W, d] for the
+    fleet; the physical padded width under a model-sharded layout). ``net`` is the repro.net NetState (stacked for the fleet),
     or None on the static-channel path. ``eps`` is the running accountant
     accumulator ([Σε, Σε², Σε(e^ε−1), T | Σε(α₁..α_A)] — [4+A] f32 with
     the per-order RDP ledger appended (A = accounting.N_ORDERS; the
@@ -68,19 +69,19 @@ class TrajCarry(NamedTuple):
 
 
 def make_round_body(cfg, proto, store, *, sim=None, fleet=None,
-                    flat: bool = False, unravel_row=None, spec=None,
-                    shard_mesh=None, worker_mesh=None, telemetry=None,
+                    unravel_row=None, spec=None, shard_mesh=None,
+                    worker_mesh=None, telemetry=None,
                     remat: bool = False) -> Callable:
     """Build ``body(carry) -> (carry', out)`` — one full DWFL round.
 
     ``store`` is a repro.data.device store (sample/sample_fleet). Exactly
     one of the three paths is taken: ``fleet`` (FleetEngine — vmapped
     [R, ...] round), ``sim`` (NetworkSimulator — single dynamic network),
-    neither (static channel). ``flat``/``unravel_row`` select the fused
-    flat-buffer round (protocol.make_*_flat_train_step).
+    neither (static channel). The round runs on the flat buffer
+    (protocol.make_*_flat_train_step); ``unravel_row`` maps one buffer row
+    to one worker's tree, and defaults to ``spec.unravel_row``.
 
-    ``spec`` (exchange.FlatSpec, implies ``flat``): the layout-aware
-    buffer contract. With a model-sharded spec (repro.shard) the carry's
+    ``spec`` (exchange.FlatSpec): the layout-aware buffer contract. With a model-sharded spec (repro.shard) the carry's
     flat buffer is the physical [.., width] padded buffer — sharded over
     ``shard_mesh``'s "model" axis when given (the scan then runs
     shard_map bodies with the carry donated in place on every device), or
@@ -118,16 +119,17 @@ def make_round_body(cfg, proto, store, *, sim=None, fleet=None,
     touches params, so chunked-vs-per-round trajectories stay BITWISE
     identical with telemetry on (tests/test_trajectory.py).
     """
-    if spec is not None:
-        flat = True
-        if unravel_row is None:
-            unravel_row = spec.unravel_row
+    if unravel_row is None:
+        if spec is None:
+            raise ValueError("make_round_body needs the buffer's layout: "
+                             "pass spec= (or unravel_row=)")
+        unravel_row = spec.unravel_row
     sharded = spec is not None and spec.layout is not None
 
     if fleet is not None:
         step = fleet.make_fleet_step(cfg, mesh=shard_mesh if sharded else None,
-                                     flat=flat, unravel_row=unravel_row,
-                                     spec=spec, remat=remat)
+                                     unravel_row=unravel_row, spec=spec,
+                                     remat=remat)
         R = fleet.replicates
 
         def body(carry: TrajCarry):
@@ -158,9 +160,8 @@ def make_round_body(cfg, proto, store, *, sim=None, fleet=None,
             step = make_sharded_dynamic_flat_train_step(
                 cfg, proto, spec, mesh=shard_mesh, remat=remat)
         else:
-            step = (protocol_lib.make_dynamic_flat_train_step(
-                        cfg, proto, unravel_row) if flat
-                    else protocol_lib.make_dynamic_train_step(cfg, proto))
+            step = protocol_lib.make_dynamic_flat_train_step(
+                cfg, proto, unravel_row)
 
         def body(carry: TrajCarry):
             key, sk = jax.random.split(carry.key)
@@ -178,8 +179,7 @@ def make_round_body(cfg, proto, store, *, sim=None, fleet=None,
         step = make_sharded_flat_train_step(cfg, proto, spec,
                                             mesh=shard_mesh, remat=remat)
     else:
-        step = (protocol_lib.make_flat_train_step(cfg, proto, unravel_row)
-                if flat else protocol_lib.make_train_step(cfg, proto))
+        step = protocol_lib.make_flat_train_step(cfg, proto, unravel_row)
 
     def body(carry: TrajCarry):
         key, sk = jax.random.split(carry.key)

@@ -162,23 +162,21 @@ class FleetEngine:
         return flat, spec.unravel, spec.unravel_row
 
     def make_fleet_step(self, cfg, mesh=None, axis: str = "replicas",
-                        flat: bool = False, unravel_row=None, spec=None,
-                        remat: bool = False):
+                        unravel_row=None, spec=None, remat: bool = False):
         """The batched round:
 
-            step(worker_params, batch, keys, chans, Ws)
-                -> (worker_params', metrics)     # every leaf [R, ...]
+            step(flat, batch, keys, chans, Ws)
+                -> (flat', metrics)              # every leaf [R, ...]
 
-        vmap of protocol.make_dynamic_train_step over the replicate axis —
-        or, with ``flat=True`` (pass the ``unravel_row`` from
-        init_flat_params), of the fused flat-buffer step
-        protocol.make_dynamic_flat_train_step: worker_params is then the
-        [R, W, d] buffer and the whole per-replicate O(d) pipeline is one
-        vmapped dp_mix kernel call. With ``mesh`` (optional, 1-axis jax
-        mesh), the same program is wrapped in shard_map instead, splitting
-        R over the mesh devices (R % |mesh| must be 0); replicates never
-        communicate, so in/out specs are plain leading-axis shards and the
-        body stays the vmapped step on the local R/|mesh| slab.
+        vmap of protocol.make_dynamic_flat_train_step over the replicate
+        axis: ``flat`` is the [R, W, d] buffer (init_flat_spec — pass its
+        ``spec``, or the ``unravel_row`` from init_flat_params) and the
+        whole per-replicate O(d) pipeline is one vmapped dp_mix kernel
+        call. With ``mesh`` (optional, 1-axis jax mesh), the same program
+        is wrapped in shard_map instead, splitting R over the mesh devices
+        (R % |mesh| must be 0); replicates never communicate, so in/out
+        specs are plain leading-axis shards and the body stays the vmapped
+        step on the local R/|mesh| slab.
 
         Pass a model-sharded ``spec`` (FleetEngine.init_flat_spec with
         n_shards > 1) to shard each replicate's buffer columns as well
@@ -190,29 +188,24 @@ class FleetEngine:
         in different XLA fusion clusters (same caveat as the scan engine,
         DESIGN.md §10).
         """
-        if flat:
-            if spec is not None and spec.layout is not None:
-                from repro.shard.round import (
-                    make_fleet_sharded_step,
-                    make_sharded_dynamic_flat_train_step)
-                if mesh is not None and "model" in mesh.axis_names:
-                    return make_fleet_sharded_step(cfg, self.proto, spec,
-                                                   mesh,
-                                                   replicate_axis=axis,
-                                                   remat=remat)
-                base = make_sharded_dynamic_flat_train_step(
-                    cfg, self.proto, spec, mesh=None, remat=remat)
-            else:
-                if unravel_row is None and spec is not None:
-                    unravel_row = spec.unravel_row
-                if unravel_row is None:
-                    raise ValueError("flat=True requires the unravel_row "
-                                     "from init_flat_params (or a spec "
-                                     "from init_flat_spec)")
-                base = protocol_lib.make_dynamic_flat_train_step(
-                    cfg, self.proto, unravel_row)
+        if spec is not None and spec.layout is not None:
+            from repro.shard.round import (
+                make_fleet_sharded_step, make_sharded_dynamic_flat_train_step)
+            if mesh is not None and "model" in mesh.axis_names:
+                return make_fleet_sharded_step(cfg, self.proto, spec, mesh,
+                                               replicate_axis=axis,
+                                               remat=remat)
+            base = make_sharded_dynamic_flat_train_step(
+                cfg, self.proto, spec, mesh=None, remat=remat)
         else:
-            base = protocol_lib.make_dynamic_train_step(cfg, self.proto)
+            if unravel_row is None and spec is not None:
+                unravel_row = spec.unravel_row
+            if unravel_row is None:
+                raise ValueError("the fleet step needs the unravel_row from "
+                                 "init_flat_params (or a spec from "
+                                 "init_flat_spec)")
+            base = protocol_lib.make_dynamic_flat_train_step(
+                cfg, self.proto, unravel_row)
         batched = jax.vmap(base)
         if mesh is None:
             return batched
@@ -226,30 +219,28 @@ class FleetEngine:
                              in_specs=(spec, spec, spec, spec, spec),
                              out_specs=(spec, spec), check_vma=False)
 
-    def make_fleet_round(self, cfg, mesh=None, flat: bool = False,
-                         unravel_row=None, spec=None):
+    def make_fleet_round(self, cfg, mesh=None, unravel_row=None, spec=None):
         """Network advance + train step as ONE jittable call (what the
-        sweep driver and launch/train.py --replicates actually run):
+        sweep driver runs):
 
-            fleet_round(key, states, worker_params, batch)
-                -> (states', worker_params', metrics, chans, Ws)
+            fleet_round(key, states, flat, batch)
+                -> (states', flat', metrics, chans, Ws)
 
         A single dispatch per round for the whole fleet — the unit the
-        ≥3×-vs-Python-loop acceptance benchmark times. ``flat=True``:
-        worker_params is the persistent [R, W, d] buffer
-        (init_flat_params) and the round runs the fused dp_mix kernel;
-        with a model-sharded ``spec`` (init_flat_spec) the buffer columns
-        shard too (see make_fleet_step).
+        ≥3×-vs-Python-loop acceptance benchmark times. ``flat`` is the
+        persistent [R, W, d] buffer (init_flat_params / init_flat_spec);
+        with a model-sharded ``spec`` the buffer columns shard too (see
+        make_fleet_step).
         """
-        step = self.make_fleet_step(cfg, mesh=mesh, flat=flat,
-                                    unravel_row=unravel_row, spec=spec)
+        step = self.make_fleet_step(cfg, mesh=mesh, unravel_row=unravel_row,
+                                    spec=spec)
 
-        def fleet_round(key, states, worker_params, batch):
+        def fleet_round(key, states, flat, batch):
             k_net, k_step = jax.random.split(key)
             states, chans, _masks, Ws = self.round(k_net, states)
-            worker_params, metrics = step(
-                worker_params, batch, self.split_keys(k_step), chans, Ws)
-            return states, worker_params, metrics, chans, Ws
+            flat, metrics = step(flat, batch, self.split_keys(k_step),
+                                 chans, Ws)
+            return states, flat, metrics, chans, Ws
 
         return fleet_round
 

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import protocol as P
+from repro.core.exchange import make_flat_spec
 from repro.fleet.engine import FleetEngine, fleet_epsilon_report, mean_ci, stack_rounds
 
 # reduced benchmark task (mirrors benchmarks/common.py at smaller scale so a
@@ -146,14 +147,16 @@ def run_point(grid: ScenarioGrid, point: Dict, seed: int = 0,
     fleet = FleetEngine(proto)
     cfg, next_batch, full_batch, init_params = _setup_fleet_task(fleet, seed)
 
-    fleet_round = jax.jit(fleet.make_fleet_round(cfg),
-                          donate_argnums=(1, 2))
     evaluate = jax.vmap(P.make_eval_fn(cfg))
 
     key = jax.random.PRNGKey(seed)
     key, k_net, k_wp = jax.random.split(key, 3)
     states = fleet.init(k_net)
-    wp = init_params(k_wp)
+    tree = init_params(k_wp)
+    spec = make_flat_spec(tree, lead_axes=2)
+    wp = spec.flatten(tree)
+    fleet_round = jax.jit(fleet.make_fleet_round(cfg, spec=spec),
+                          donate_argnums=(1, 2))
 
     chan_log, w_log = [], []
     # warmup/compile outside the timed region
@@ -175,7 +178,7 @@ def run_point(grid: ScenarioGrid, point: Dict, seed: int = 0,
     jax.tree_util.tree_leaves(wp)[0].block_until_ready()
     us_per_round = (time.perf_counter() - t0) / grid.steps * 1e6
 
-    ev_loss, ev_acc = evaluate(wp, full_batch(128))        # [R], [R]
+    ev_loss, ev_acc = evaluate(spec.unravel(wp), full_batch(128))  # [R]
     eps_rep = fleet_epsilon_report(
         proto, stack_rounds(chan_log), stack_rounds(w_log))
 

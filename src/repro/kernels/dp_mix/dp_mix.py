@@ -43,14 +43,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.dp_perturb.dp_perturb import _hash_bits
-
 LANES = 128     # last-dim tile multiple (f32)
 SUBLANES = 8    # worker-axis pad multiple (f32 sublane)
 # The mix must run in full f32 on the MXU: the noise terms reach |n/c| ~ 70
 # and cancel against the self-correction column, so one bf16 pass would
 # leave ~0.3 of noise in every parameter. (XLA CPU's dot is f32 anyway.)
 _F32 = jax.lax.Precision.HIGHEST
+
+
+def _hash_bits(idx, seed):
+    """Counter-based hash (interpret-mode PRNG): xorshift-mul mix."""
+    x = (idx.astype(jnp.uint32) * jnp.uint32(2654435761)) ^ seed.astype(jnp.uint32)
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(2246822519)
+    x = x ^ (x >> 13)
+    x = x * jnp.uint32(3266489917)
+    x = x ^ (x >> 16)
+    return x
 
 
 def _normal_from_bits(bits):

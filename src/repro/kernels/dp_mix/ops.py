@@ -8,7 +8,7 @@ compiled call serves every realization (zero retraces — asserted by the
 the Pallas kernel on TPU, its bitwise-equivalent fused-jnp lowering on
 CPU, and the Pallas interpreter on demand for kernel validation.
 
-Dtype contract (shared with dp_perturb): the output buffer has the INPUT
+Dtype contract: the output buffer has the INPUT
 buffer's dtype — internal arithmetic is f32, results cast back once.
 """
 from __future__ import annotations

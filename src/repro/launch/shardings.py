@@ -1,10 +1,11 @@
 """Sharding rules: map parameter/batch/cache pytrees to PartitionSpecs.
 
 Heuristic Megatron-style placement: for each parameter leaf, shard the
-largest eligible (divisible, >= axis size) non-leading dimension over
-``model``; leading worker/layer-stack dims are handled explicitly. DWFL
-worker-stacked leaves put the worker axis over ``data`` (and ``pod``).
-Small leaves (norm scales, biases, gate vectors) replicate.
+largest eligible (divisible, >= axis size) dimension over ``model``;
+layer-stack dims are left unsharded. DWFL's flat [W, d] training buffer
+puts its worker rows over ``data`` (and ``pod``) and, model-sharded, its
+columns over ``model`` (``flat_buffer_sharding``). Small leaves (norm
+scales, biases, gate vectors) replicate.
 
 This is deliberately rule-based rather than per-tensor hand-annotation:
 with 10 architecture families the rule set IS the config surface, and XLA's
@@ -58,56 +59,23 @@ def _path_str(path) -> str:
         str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
 
-def param_specs(params_shape, *, mesh, worker_axes: Tuple[str, ...] = (),
-                stack_dims: int = 0):
-    """PartitionSpec pytree for a (possibly worker-stacked) param tree.
-
-    worker_axes: mesh axes for the leading worker dim (() for serving).
-    stack_dims counts additional leading layer-stack dims to leave
-    unsharded — they are detected per-leaf instead via path heuristics, so
-    this is the default for scalars.
-    """
+def param_specs(params_shape, *, mesh):
+    """PartitionSpec pytree for one model's param tree (serving): each
+    leaf's model dim over ``model``, layer-stack dims unsharded."""
     msize = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
 
     def spec_for(path, leaf):
-        shape = leaf.shape
-        p = _path_str(path)
-        n_lead = len(worker_axes)
-        # layer-stack dims: blocks/moe_blocks/mamba/mlstm etc. carry 1-2
-        # stacked leading dims after the worker axis; treat dims that are
-        # "small and leading" as stack dims by skipping until we see a
-        # tensor-ish dim. Simpler: never shard the first `n_lead` dims and
-        # choose the model dim among the trailing ndim-n_lead dims,
-        # skipping any dim before the last two for matrices.
-        skip = n_lead
-        d = _model_dim(shape, skip, msize, p) if leaf.ndim > n_lead else None
+        d = _model_dim(leaf.shape, 0, msize, _path_str(path))
         # guard: never place 'model' on what is actually a layer-stack dim —
         # only shard among the last 3 dims of the leaf.
         if d is not None and d < leaf.ndim - 3:
             d = None
         spec = [None] * leaf.ndim
-        if worker_axes:
-            spec[0] = worker_axes if len(worker_axes) > 1 else worker_axes[0]
         if d is not None:
             spec[d] = "model"
         return P(*spec)
 
     return jax.tree_util.tree_map_with_path(spec_for, params_shape)
-
-
-def batch_specs(batch_shape, *, mesh, worker_axes: Tuple[str, ...] = (),
-                data_axes: Tuple[str, ...] = ()):
-    """Batch leaves: worker-stacked [W, b, ...] -> P(worker_axes, ...);
-    serving [B, ...] -> P(data_axes, ...)."""
-    lead = worker_axes or data_axes
-
-    def spec_for(path, leaf):
-        spec = [None] * leaf.ndim
-        if lead and leaf.shape[0] >= np.prod([_axis_size(mesh, a) for a in lead]):
-            spec[0] = lead if len(lead) > 1 else lead[0]
-        return P(*spec)
-
-    return jax.tree_util.tree_map_with_path(spec_for, batch_shape)
 
 
 def cache_specs(cache_shape, *, mesh, data_axes: Tuple[str, ...] = ("data",),

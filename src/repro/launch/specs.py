@@ -4,7 +4,8 @@
 ShapeDtypeStruct arguments (weak-type-correct, shardable, zero allocation)
 for one of the three step kinds:
 
-    train    — the full DWFL round (per-worker grads + local step + exchange)
+    train    — the full DWFL round on the flat [W, d] buffer (per-worker
+               grads + local step + exchange)
     prefill  — forward building the KV/state cache
     decode   — ONE new token against a seq_len cache
 
@@ -24,7 +25,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.configs.registry import get_arch, get_shape
-from repro.core.protocol import ProtocolConfig, init_worker_params, make_train_step
+from repro.core import exchange as X
+from repro.core.protocol import (ProtocolConfig, init_worker_params,
+                                 make_flat_train_step)
 from repro.launch import mesh as mesh_lib
 from repro.launch import shardings as sh
 from repro.models import model as M
@@ -122,11 +125,12 @@ def build_case(arch: str, shape: str, mesh, *, multi_pod: bool = False,
         proto = proto or ProtocolConfig(scheme="dwfl", n_workers=W,
                                         gamma=0.01, eta=0.5, clip=1.0)
         proto = dataclasses.replace(proto, n_workers=W)
-        step = make_train_step(cfg, proto)
-        wp_shape = jax.eval_shape(
-            lambda k: init_worker_params(k, cfg, W), key0)
-        wp = _attach(wp_shape, sh.param_specs(wp_shape, mesh=mesh,
-                                              worker_axes=waxes), mesh)
+        spec = X.make_flat_spec(jax.eval_shape(
+            lambda k: init_worker_params(k, cfg, W), key0))
+        step = make_flat_train_step(cfg, proto, spec.unravel_row)
+        # the [W, d] f32 buffer, its rows sharded over the worker axes
+        wp = _sds((W, spec.d), jnp.float32, mesh,
+                  P(waxes if len(waxes) > 1 else waxes[0], None))
         bshapes = _train_batch_shapes(cfg, shp, W)
         batch = {k: _sds(s, dt, mesh,
                          P(waxes if len(waxes) > 1 else waxes[0],
@@ -134,14 +138,13 @@ def build_case(arch: str, shape: str, mesh, *, multi_pod: bool = False,
                  for k, (s, dt) in bshapes.items()}
         keyspec = _sds(key0.shape, key0.dtype, mesh, P())
         tokens = float(shp.global_batch * shp.seq_len)
-        out_sh = (jax.tree_util.tree_map(lambda s: s.sharding, wp),
-                  NamedSharding(mesh, P()))  # (params', metrics)
+        out_sh = (wp.sharding, NamedSharding(mesh, P()))  # (params', metrics)
         return Case(f"{arch}|{shape}", step, (wp, batch, keyspec),
                     tokens, 6.0 * n_params * act_frac * tokens, n_params,
                     "train", out_shardings=out_sh, donate_argnums=(0,))
 
     params = _attach(params_shape,
-                     sh.param_specs(params_shape, mesh=mesh, worker_axes=()),
+                     sh.param_specs(params_shape, mesh=mesh),
                      mesh)
 
     msize = mesh_lib.model_size(mesh)

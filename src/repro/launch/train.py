@@ -5,13 +5,13 @@ devices exist. On this CPU rig it drives the reduced configs / the
 paper-scale MLP; on a TPU pod the same driver drives the full configs (the
 mesh and shardings come from repro.launch.mesh / shardings).
 
-By default the trajectory is executed by the scan-fused engine
-(repro.core.trajectory): whole chunks of ``--chunk-rounds`` consecutive
-rounds — one coherence block or one eval interval unless overridden —
-compile into a single ``lax.scan`` program with on-device batch sampling
-(repro.data.device), so the driver dispatches once per CHUNK instead of
-once per round. Eval/log happen at chunk boundaries. ``--no-scan`` falls
-back to the legacy one-dispatch-per-round loop with host NumPy batching.
+Parameters live in one flat [W, d] buffer (exchange.FlatSpec: ravel once
+at init, train flat, unravel only at eval/checkpoint), and the trajectory
+is executed by the scan-fused engine (repro.core.trajectory): whole chunks
+of ``--chunk-rounds`` consecutive rounds — one coherence block or one eval
+interval unless overridden — compile into a single ``lax.scan`` program
+with on-device batch sampling (repro.data.device), so the driver
+dispatches once per CHUNK. Eval/log happen at chunk boundaries.
 
 Examples:
     PYTHONPATH=src python -m repro.launch.train --arch dwfl-paper --steps 300
@@ -29,14 +29,13 @@ import argparse
 import dataclasses
 import json
 import time
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.checkpoint import save as ckpt_save
 from repro.configs.registry import ARCHS, get_arch
 from repro.configs import dwfl_paper
 from repro.core import privacy
@@ -51,17 +50,17 @@ from repro.launch.compile_cache import setup_compile_cache
 class TrainResult(NamedTuple):
     """What a finished run hands back to an in-process caller.
 
-    ``params``: the final worker tree or flat buffer. ``epsilon``: the
-    run's ε quote — composed from the scan carry's ledger under
-    ``--accountant`` when telemetry keeps one, else the end-of-run
-    report's (per round on the static channel). ``runner``: the scan engine's
-    ChunkRunner (None under --no-scan), whose trace_counts(),
-    compile_seconds and lowered_text() expose each compiled chunk length;
-    ``carry``: its final TrajCarry, from which ``runner.run`` continues."""
+    ``params``: the final flat buffer. ``epsilon``: the run's ε quote —
+    composed from the scan carry's ledger under ``--accountant`` when
+    telemetry keeps one, else the end-of-run report's (per round on the
+    static channel). ``runner``: the scan engine's ChunkRunner, whose
+    trace_counts(), compile_seconds and lowered_text() expose each
+    compiled chunk length; ``carry``: its final TrajCarry, from which
+    ``runner.run`` continues."""
     params: Any
     epsilon: float
-    runner: Optional[TJ.ChunkRunner]
-    carry: Optional[TJ.TrajCarry]
+    runner: TJ.ChunkRunner
+    carry: TJ.TrajCarry
 
 
 def _sharded_initial_buffer(key, cfg, n_workers, spec, mesh):
@@ -157,9 +156,8 @@ def _main(argv) -> TrainResult:
                     help="shard the WORKER axis of the flat buffer over a "
                          "'workers' mesh axis (repro.shard.worker): each "
                          "device runs the grad pass + sparse mix for its "
-                         "own N/S rows. Requires --flat-buffer, "
-                         "--sparse-neighbors > 0, the scan engine, and S "
-                         "devices (CPU: XLA_FLAGS=--xla_force_host_"
+                         "own N/S rows. Requires --sparse-neighbors > 0 "
+                         "and S devices (CPU: XLA_FLAGS=--xla_force_host_"
                          "platform_device_count=S).")
     ap.add_argument("--replicates", type=int, default=1,
                     help="dynamic only: batch R independent network "
@@ -167,10 +165,8 @@ def _main(argv) -> TrainResult:
                          "(repro.fleet); metrics/privacy report mean±CI "
                          "across replicates")
     ap.add_argument("--flat-buffer", action="store_true",
-                    help="train on the persistent flat [W, d] parameter "
-                         "buffer with the fused Pallas dp_mix round "
-                         "(ravel once at init, train flat, unravel only "
-                         "at eval/checkpoint); dwfl/gossip schemes only")
+                    help="the flat buffer is the only layout; accepted for "
+                         "existing command lines")
     ap.add_argument("--model-shards", type=int, default=1,
                     help="shard the flat buffer's columns over a 'model' "
                          "mesh axis (repro.shard): each shard runs the "
@@ -179,7 +175,7 @@ def _main(argv) -> TrainResult:
                          "(CPU: XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=S); with fewer, shards logically on "
                          "one device on CPU and refuses on an accelerator. "
-                         "Requires --flat-buffer.")
+                         "Mixing-family schemes (dwfl/gossip) only.")
     ap.add_argument("--max-chunk-cols", type=int, default=0,
                     help="cap (in columns) on each collective of the "
                          "sharded round's gather-free grad pass "
@@ -198,9 +194,6 @@ def _main(argv) -> TrainResult:
                     help="scan-fused trajectory engine: rounds compiled "
                          "into one lax.scan dispatch (0 = auto: one "
                          "coherence block or one eval interval)")
-    ap.add_argument("--no-scan", action="store_true",
-                    help="legacy driver: one jitted dispatch per round, "
-                         "host NumPy batch assembly")
     ap.add_argument("--no-transfer-guard", action="store_true",
                     help="disable jax.transfer_guard('disallow') around "
                          "the hot loop (the guard rejects IMPLICIT host<->"
@@ -219,7 +212,7 @@ def _main(argv) -> TrainResult:
                     help="in-scan per-round telemetry (loss/grad-norm/"
                          "consensus/SNR/deep-fade/participation/eps), "
                          "emitted as one stacked array per chunk. auto: "
-                         "on when --runlog-dir is set (scan path only)")
+                         "on when --runlog-dir is set")
     ap.add_argument("--eps-budget", type=float, default=0.0,
                     help="warn when the composed trajectory epsilon "
                          "approaches (80%%) / exceeds this budget "
@@ -254,7 +247,6 @@ def _main(argv) -> TrainResult:
         target_epsilon=0.0 if args.total_epsilon > 0 else args.epsilon,
         channel_model=args.channel_model, scenario=args.scenario,
         coherence_rounds=args.coherence_rounds, replicates=args.replicates,
-        flat_buffer=args.flat_buffer,
         sparse_neighbors=args.sparse_neighbors,
         graph_fallback=args.graph_fallback,
         accountant=args.accountant,
@@ -264,20 +256,13 @@ def _main(argv) -> TrainResult:
         print(f"[train] total budget: eps={args.total_epsilon} "
               f"delta={proto.delta} over {args.steps + 1} rounds "
               f"(accountant={args.accountant})")
-    if proto.flat_buffer and args.scheme not in ("dwfl", "gossip"):
-        raise SystemExit("--flat-buffer supports the mixing-family schemes "
-                         "only (dwfl/gossip)")
 
-    # observability: in-scan telemetry spec + structured run log. Telemetry
-    # rides the scan path (the spec is compiled into the chunk program);
-    # "auto" switches it on exactly when a run log wants the rows.
-    if args.telemetry == "on" and args.no_scan:
-        raise SystemExit("--telemetry on requires the scan engine "
-                         "(telemetry is computed inside the compiled "
-                         "chunks; drop --no-scan)")
+    # observability: in-scan telemetry spec + structured run log. The spec
+    # is compiled into the chunk program; "auto" switches it on exactly
+    # when a run log wants the rows.
     tele = None
-    if not args.no_scan and (args.telemetry == "on" or (
-            args.telemetry == "auto" and args.runlog_dir is not None)):
+    if args.telemetry == "on" or (args.telemetry == "auto"
+                                  and args.runlog_dir is not None):
         tele = obs.TelemetrySpec()
     if args.eps_budget > 0 and tele is None:
         raise SystemExit("--eps-budget needs telemetry (the composed eps "
@@ -293,9 +278,9 @@ def _main(argv) -> TrainResult:
             extra={"telemetry": list(tele.fields) if tele else None})
         print(f"[train] run log -> {runlog.dir}")
     n_shards = max(1, args.model_shards)
-    if n_shards > 1 and not proto.flat_buffer:
-        raise SystemExit("--model-shards requires --flat-buffer (only the "
-                         "persistent flat buffer has a model axis to shard)")
+    if n_shards > 1 and args.scheme not in ("dwfl", "gossip"):
+        raise SystemExit("--model-shards runs the fused mixing round; the "
+                         "orthogonal/centralized baselines have none")
     max_chunk_cols = args.max_chunk_cols if args.max_chunk_cols > 0 else None
     if max_chunk_cols is not None and n_shards <= 1:
         raise SystemExit("--max-chunk-cols caps the sharded round's "
@@ -306,16 +291,13 @@ def _main(argv) -> TrainResult:
                          "--worker-shards > 1")
     worker_mesh = None
     if args.worker_shards > 1:
-        if not (proto.flat_buffer and proto.sparse_neighbors > 0
-                and args.channel_model == "dynamic"):
-            raise SystemExit("--worker-shards requires --flat-buffer and "
-                             "--sparse-neighbors > 0 (only the sparse "
-                             "neighbor-list round has a worker-sharded "
-                             "lowering)")
-        if n_shards > 1 or args.replicates > 1 or args.no_scan:
+        if proto.sparse_neighbors <= 0:
+            raise SystemExit("--worker-shards requires --sparse-neighbors "
+                             "> 0 (only the sparse neighbor-list round has "
+                             "a worker-sharded lowering)")
+        if n_shards > 1 or args.replicates > 1:
             raise SystemExit("--worker-shards composes with neither "
-                             "--model-shards, --replicates nor --no-scan "
-                             "yet")
+                             "--model-shards nor --replicates yet")
         if W % args.worker_shards != 0:
             raise SystemExit(f"--workers {W} must divide evenly over "
                              f"--worker-shards {args.worker_shards}")
@@ -362,32 +344,21 @@ def _main(argv) -> TrainResult:
             toks = lm_dataset(W * 200_000, cfg.vocab_size, seed=args.seed)
             batcher = LMBatcher(toks, W, args.batch_size, args.seq_len,
                                 seed=args.seed)
-        # the scan path samples on the device from this store
-        store = None if args.no_scan else store_from_batcher(batcher)
+        # the chunks sample on the device from this store
+        store = store_from_batcher(batcher)
 
-    # spec: flat-buffer mode only — the layout-aware buffer contract
-    # (exchange.FlatSpec); unravel maps the persistent [.., W, width]
-    # buffer back to the worker-stacked pytree at eval/checkpoint time
-    spec = shard_mesh = None
-    unravel = unravel_row = None
+    # spec: the layout-aware buffer contract (exchange.FlatSpec); its
+    # unravel maps the persistent [.., W, width] buffer back to the
+    # worker-stacked pytree at eval/checkpoint time
+    shard_mesh = None
     if fleet is not None:
-        if proto.flat_buffer:
-            wp, spec = fleet.init_flat_spec(key, cfg, n_shards=n_shards,
-                                            max_chunk_cols=max_chunk_cols)
-            unravel, unravel_row = spec.unravel, spec.unravel_row
-            n_params = spec.d      # lead_axes=2: d is PER-WORKER already
-        else:
-            wp = fleet.init_worker_params(key, cfg)
-            n_params = (sum(int(x.size)
-                            for x in jax.tree_util.tree_leaves(wp))
-                        // (W * fleet.replicates))
-    elif proto.flat_buffer:
+        wp, spec = fleet.init_flat_spec(key, cfg, n_shards=n_shards,
+                                        max_chunk_cols=max_chunk_cols)
+    else:
         from repro.core import exchange as X
         spec = X.make_flat_spec(
             jax.eval_shape(lambda: P.init_worker_params(key, cfg, W)),
             n_shards=n_shards, max_chunk_cols=max_chunk_cols)
-        unravel, unravel_row = spec.unravel, spec.unravel_row
-        n_params = spec.d
         with obs.span("dwfl.main.params"):
             if n_shards > 1 and jax.device_count() >= n_shards:
                 from repro.launch import mesh as mesh_lib
@@ -395,16 +366,12 @@ def _main(argv) -> TrainResult:
                 wp = _sharded_initial_buffer(key, cfg, W, spec, shard_mesh)
             else:
                 wp = spec.flatten(P.init_worker_params(key, cfg, W))
-    else:
-        with obs.span("dwfl.main.params"):
-            wp = P.init_worker_params(key, cfg, W)
-        n_params = sum(int(x.size) for x in jax.tree_util.tree_leaves(wp)) // W
     if worker_mesh is not None:
         from jax.sharding import NamedSharding
         from repro.shard.worker import worker_partition_spec
         wp = jax.device_put(
             wp, NamedSharding(worker_mesh, worker_partition_spec()))
-    if spec is not None and spec.n_shards > 1:
+    if spec.n_shards > 1:
         # place the padded buffer on a real model mesh when the devices
         # exist; otherwise shard logically inside one device's program
         from repro.launch import mesh as mesh_lib
@@ -436,9 +403,9 @@ def _main(argv) -> TrainResult:
         print(f"[train] grad-pass chunk plan: {len(plan.chunks)} chunks, "
               f"{len(plan.exec_segments())} collective segments"
               + (f", cap {cap} cols" if cap else " (unbounded)"))
-    print(f"[train] params/worker: {n_params/1e6:.2f}M"
-          + (" (flat dp_mix buffer)" if proto.flat_buffer else ""))
-    if proto.flat_buffer:
+    # d is per worker (the fleet's lead axes are [R, W])
+    print(f"[train] params/worker: {spec.d/1e6:.2f}M (flat buffer)")
+    if args.scheme in ("dwfl", "gossip"):
         print(f"[train] mix lowering: "
               f"{mix_ops.lowering(sparse=proto.sparse_neighbors > 0)}")
 
@@ -447,13 +414,6 @@ def _main(argv) -> TrainResult:
         key, nk = jax.random.split(key)
         net_state = fleet.init(nk)
         evaluate = jax.jit(jax.vmap(P.make_eval_fn(cfg)))
-
-        def next_batch():
-            # R independent per-replicate draws from the worker-batch
-            # stream, stacked to [R, W, B, ...] (legacy / LM-eval only)
-            return jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[batcher.next() for _ in range(fleet.replicates)])
     elif sim is not None:
         key, nk = jax.random.split(key)
         net_state = sim.init(nk)
@@ -502,16 +462,21 @@ def _main(argv) -> TrainResult:
             eval_batch = jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(
                     a[None], (fleet.replicates,) + a.shape), eval_batch)
+    elif fleet is not None:
+        # R independent draws from the worker-batch stream, stacked to
+        # [R, W, B, ...]
+        eval_batch = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[batcher.next() for _ in range(fleet.replicates)])
     else:
-        eval_batch = next_batch() if fleet is not None else jax.tree_util.\
-            tree_map(jnp.asarray, batcher.next())
+        eval_batch = jax.tree_util.tree_map(jnp.asarray, batcher.next())
 
     logf = open(args.log, "w") if args.log else None
     t0 = time.time()
 
     def log_eval(t, metrics, params):
-        # flat-buffer mode: unravel the persistent buffer ONLY here
-        wp_eval = unravel(params) if unravel is not None else params
+        # the persistent buffer is unravelled ONLY here
+        wp_eval = spec.unravel(params)
         if fleet is not None:
             # across-replicate reduction happens ONLY at eval/log
             # boundaries — never once per round
@@ -533,177 +498,106 @@ def _main(argv) -> TrainResult:
         if runlog is not None:
             runlog.eval_metrics(**rec)
 
-    chan_chunks, w_chunks = [], []    # scan path: ONE [K, ...] array/chunk
-    chan_log, w_log = [], []          # legacy path: one array per round
-
-    if not args.no_scan:
-        # scan-fused trajectory: one dispatch per chunk, on-device batch
-        # sampling, eval/log at chunk boundaries only
-        body = TJ.make_round_body(
-            cfg, proto, store, sim=None if fleet is not None else sim,
-            fleet=fleet, flat=proto.flat_buffer, unravel_row=unravel_row,
-            spec=spec, shard_mesh=shard_mesh, worker_mesh=worker_mesh,
-            telemetry=tele, remat=args.remat)
-        coher = (sim.scenario.fading.coherence_rounds
-                 if sim is not None else None)
-        chunk = (args.chunk_rounds if args.chunk_rounds > 0
-                 else TJ.auto_chunk(args.eval_every, coher))
-        print(f"[train] scan-fused trajectory: chunk={chunk} rounds/dispatch"
-              + (f", telemetry: {','.join(tele.fields)}" if tele else ""))
-        runner = TJ.ChunkRunner(body)
-        eps0 = (obs.init_eps_moments(
-                    fleet.replicates if fleet is not None else None)
-                if tele is not None and tele.epsilon else None)
-        if shard_mesh is not None and fleet is None:
-            # the chunk program hands the key and the ledger back
-            # replicated over the mesh: hand them in so, or the second
-            # chunk compiles again for the new placement of its inputs
-            from jax.sharding import NamedSharding, PartitionSpec
-            key, eps0 = jax.device_put(
-                (key, eps0), NamedSharding(shard_mesh, PartitionSpec()))
-        carry = TJ.TrajCarry(key, wp, net_state, eps0)
-        eps_dog = (obs.EpsilonBudgetWatchdog(
-                       args.eps_budget,
-                       on_warn=runlog.warn if runlog is not None else
-                       (lambda msg, **kw: print(f"[train] WARNING: {msg}")))
-                   if args.eps_budget > 0 else None)
-        retrace_dog = obs.RetraceWatchdog(runner, runlog=runlog,
-                                          label="chunk")
-        t = 0
-        for n, do_eval in TJ.plan_chunks(args.steps + 1, chunk,
-                                         args.eval_every):
-            # the chunk dispatch is the hot path: everything it touches is
-            # device-resident by construction, and the transfer guard
-            # (repro.obs) makes any regression — a host batch smuggled in,
-            # an implicit readback — fail loudly at the call site
-            with obs.no_implicit_transfers(not args.no_transfer_guard):
-                carry, out = runner.run(carry, n)
-            t += n
-            if "chan" in out:
-                chan_chunks.append(out["chan"])
-                w_chunks.append(out["W"])
-            if tele is not None and runlog is not None:
-                # ONE device->host transfer per chunk: the stacked
-                # [K, M] ([K, R, M] fleet: across-replicate mean) rows
-                rows = np.asarray(out["telemetry"])
-                if rows.ndim == 3:
-                    rows = rows.mean(axis=1)
-                for i, row in enumerate(rows):
-                    runlog.round_metrics(
-                        t - n + i, **{f: float(v)
-                                      for f, v in zip(tele.fields, row)})
-            retrace_dog.check(step=t - 1)
-            if carry.eps is not None and (do_eval or eps_dog is not None):
-                m = np.asarray(carry.eps)
-                e_c, d_c = privacy.compose_from_moments(m, proto.delta)
-                # fleet: worst replicate is the binding budget
-                e_worst = float(np.max(e_c))
-                # the widened carry also holds the per-order RDP ledger —
-                # quote the tighter budget and let the watchdog track
-                # whichever accountant the run selected
-                e_rdp = None
-                if m.shape[-1] > 4:
-                    e_r, _ = privacy.compose_from_moments(
-                        m, proto.delta, accountant="rdp")
-                    e_rdp = float(np.max(e_r))
-                e_track = (e_rdp if args.accountant == "rdp"
-                           and e_rdp is not None else e_worst)
-                if eps_dog is not None:
-                    eps_dog.check(e_track, step=t - 1)
-                if do_eval and runlog is not None:
-                    extra = ({"eps_rdp": e_rdp,
-                              "accountant": args.accountant}
-                             if e_rdp is not None else {})
-                    runlog.epsilon(
-                        step=t - 1, eps_composed=e_worst,
-                        delta_composed=float(np.max(d_c)),
-                        rounds=int(np.max(m[..., 3])),
-                        eps_round=float(np.asarray(
-                            out["telemetry"])[-1, ...,
-                                              tele.fields.index("epsilon")]
-                            .max()),
-                        **extra)
-            if do_eval:
-                metrics = jax.tree_util.tree_map(lambda a: a[-1],
-                                                 out["metrics"])
-                log_eval(t - 1, metrics, carry.params)
-        key, wp, net_state = carry.key, carry.params, carry.net
-    else:
-        if fleet is not None:
-            # ONE jitted call advances all R networks: net evolution +
-            # train step fused (repro.fleet.FleetEngine.make_fleet_round);
-            # donate the threaded state/params like the single-network
-            # paths do
-            fleet_round = jax.jit(
-                fleet.make_fleet_round(cfg, mesh=shard_mesh,
-                                       flat=proto.flat_buffer,
-                                       unravel_row=unravel_row, spec=spec),
-                donate_argnums=(1, 2))
-        elif sim is not None:
-            sharded = spec is not None and spec.n_shards > 1
-            if sharded:
-                from repro.shard import make_sharded_dynamic_flat_train_step
-                mk = lambda: make_sharded_dynamic_flat_train_step(
-                    cfg, proto, spec, mesh=shard_mesh, remat=args.remat)
-            else:
-                mk = (lambda: P.make_dynamic_flat_train_step(cfg, proto,
-                                                             unravel_row)
-                      ) if proto.flat_buffer else (
-                      lambda: P.make_dynamic_train_step(cfg, proto))
-            step = jax.jit(mk(), donate_argnums=0)
-            net_round = jax.jit(sim.round)
-        else:
-            sharded = spec is not None and spec.n_shards > 1
-            if sharded:
-                from repro.shard import make_sharded_flat_train_step
-                mk = lambda: make_sharded_flat_train_step(
-                    cfg, proto, spec, mesh=shard_mesh, remat=args.remat)
-            else:
-                mk = (lambda: P.make_flat_train_step(cfg, proto, unravel_row)
-                      ) if proto.flat_buffer else (
-                      lambda: P.make_train_step(cfg, proto))
-            step = jax.jit(mk(), donate_argnums=0)
-
-        # legacy loop: host NumPy batches are uploaded EXPLICITLY
-        # (jax.device_put) so the guarded dispatches stay free of implicit
-        # transfers — the guard then catches any new host round-trip
-        guard_on = not args.no_transfer_guard
-        for t in range(args.steps + 1):
-            key, sk = jax.random.split(key)
-            if fleet is not None:
-                batch = next_batch()
-                with obs.no_implicit_transfers(guard_on):
-                    net_state, wp, metrics, chan_t, W_t = fleet_round(
-                        sk, net_state, wp, batch)
-                chan_log.append(chan_t)
-                w_log.append(W_t)
-            elif sim is not None:
-                sk, ck = jax.random.split(sk)
-                batch = jax.device_put(batcher.next())
-                with obs.no_implicit_transfers(guard_on):
-                    net_state, chan_t, mask_t, W_t = net_round(ck, net_state)
-                    wp, metrics = step(wp, batch, sk, chan_t, W_t)
-                chan_log.append(chan_t)
-                w_log.append(W_t)
-            else:
-                batch = jax.device_put(batcher.next())
-                with obs.no_implicit_transfers(guard_on):
-                    wp, metrics = step(wp, batch, sk)
-            if args.eval_every > 0 and t % args.eval_every == 0:
-                log_eval(t, metrics, wp)
-
+    # scan-fused trajectory: one dispatch per chunk, on-device batch
+    # sampling, eval/log at chunk boundaries only
+    chan_chunks, w_chunks = [], []    # ONE [K, ...] array per chunk
+    body = TJ.make_round_body(
+        cfg, proto, store, sim=None if fleet is not None else sim,
+        fleet=fleet, spec=spec, shard_mesh=shard_mesh,
+        worker_mesh=worker_mesh, telemetry=tele, remat=args.remat)
+    coher = (sim.scenario.fading.coherence_rounds
+             if sim is not None else None)
+    chunk = (args.chunk_rounds if args.chunk_rounds > 0
+             else TJ.auto_chunk(args.eval_every, coher))
+    print(f"[train] scan-fused trajectory: chunk={chunk} rounds/dispatch"
+          + (f", telemetry: {','.join(tele.fields)}" if tele else ""))
+    runner = TJ.ChunkRunner(body)
+    eps0 = (obs.init_eps_moments(
+                fleet.replicates if fleet is not None else None)
+            if tele is not None and tele.epsilon else None)
+    if shard_mesh is not None and fleet is None:
+        # the chunk program hands the key and the ledger back
+        # replicated over the mesh: hand them in so, or the second
+        # chunk compiles again for the new placement of its inputs
+        from jax.sharding import NamedSharding, PartitionSpec
+        key, eps0 = jax.device_put(
+            (key, eps0), NamedSharding(shard_mesh, PartitionSpec()))
+    carry = TJ.TrajCarry(key, wp, net_state, eps0)
+    eps_dog = (obs.EpsilonBudgetWatchdog(
+                   args.eps_budget,
+                   on_warn=runlog.warn if runlog is not None else
+                   (lambda msg, **kw: print(f"[train] WARNING: {msg}")))
+               if args.eps_budget > 0 else None)
+    retrace_dog = obs.RetraceWatchdog(runner, runlog=runlog,
+                                      label="chunk")
+    t = 0
+    for n, do_eval in TJ.plan_chunks(args.steps + 1, chunk,
+                                     args.eval_every):
+        # the chunk dispatch is the hot path: everything it touches is
+        # device-resident by construction, and the transfer guard
+        # (repro.obs) makes any regression — a host batch smuggled in,
+        # an implicit readback — fail loudly at the call site
+        with obs.no_implicit_transfers(not args.no_transfer_guard):
+            carry, out = runner.run(carry, n)
+        t += n
+        if "chan" in out:
+            chan_chunks.append(out["chan"])
+            w_chunks.append(out["W"])
+        if tele is not None and runlog is not None:
+            # ONE device->host transfer per chunk: the stacked
+            # [K, M] ([K, R, M] fleet: across-replicate mean) rows
+            rows = np.asarray(out["telemetry"])
+            if rows.ndim == 3:
+                rows = rows.mean(axis=1)
+            for i, row in enumerate(rows):
+                runlog.round_metrics(
+                    t - n + i, **{f: float(v)
+                                  for f, v in zip(tele.fields, row)})
+        retrace_dog.check(step=t - 1)
+        if carry.eps is not None and (do_eval or eps_dog is not None):
+            m = np.asarray(carry.eps)
+            e_c, d_c = privacy.compose_from_moments(m, proto.delta)
+            # fleet: worst replicate is the binding budget
+            e_worst = float(np.max(e_c))
+            # the widened carry also holds the per-order RDP ledger —
+            # quote the tighter budget and let the watchdog track
+            # whichever accountant the run selected
+            e_rdp = None
+            if m.shape[-1] > 4:
+                e_r, _ = privacy.compose_from_moments(
+                    m, proto.delta, accountant="rdp")
+                e_rdp = float(np.max(e_r))
+            e_track = (e_rdp if args.accountant == "rdp"
+                       and e_rdp is not None else e_worst)
+            if eps_dog is not None:
+                eps_dog.check(e_track, step=t - 1)
+            if do_eval and runlog is not None:
+                extra = ({"eps_rdp": e_rdp,
+                          "accountant": args.accountant}
+                         if e_rdp is not None else {})
+                runlog.epsilon(
+                    step=t - 1, eps_composed=e_worst,
+                    delta_composed=float(np.max(d_c)),
+                    rounds=int(np.max(m[..., 3])),
+                    eps_round=float(np.asarray(
+                        out["telemetry"])[-1, ...,
+                                          tele.fields.index("epsilon")]
+                        .max()),
+                    **extra)
+        if do_eval:
+            metrics = jax.tree_util.tree_map(lambda a: a[-1],
+                                             out["metrics"])
+            log_eval(t - 1, metrics, carry.params)
+    key, wp, net_state = carry.key, carry.params, carry.net
     if fleet is not None:
         # batched accounting over ALL replicates' realized trajectories:
         # [R, T, N] budgets in one vmapped program, composed per replicate,
         # reported as across-replicate mean ± CI (DESIGN.md §repro.fleet).
-        from repro.fleet import fleet_epsilon_report, stack_rounds
-        if chan_chunks:
-            # scan path logged one stacked [K, R, ...] array per chunk —
-            # concatenate ONCE and flip to the replicate-major [R, T, ...]
-            chans = TJ.replicate_major(TJ.concat_chunks(chan_chunks))
-            Ws = TJ.replicate_major(TJ.concat_chunks(w_chunks))
-        else:
-            chans, Ws = stack_rounds(chan_log), stack_rounds(w_log)
+        from repro.fleet import fleet_epsilon_report
+        # one stacked [K, R, ...] array per chunk — concatenate ONCE and
+        # flip to the replicate-major [R, T, ...]
+        chans = TJ.replicate_major(TJ.concat_chunks(chan_chunks))
+        Ws = TJ.replicate_major(TJ.concat_chunks(w_chunks))
         rep = fleet_epsilon_report(proto, chans, Ws)
         print(f"[train] eps over {rep['rounds']} rounds x "
               f"{rep['replicates']} replicates: worst/round="
@@ -738,12 +632,8 @@ def _main(argv) -> TrainResult:
         # per-round privacy over the REALIZED fading trajectory (not a
         # scalar): Thm 4.1 on each round's channel + worst-case
         # heterogeneous composition (DESIGN.md §repro.net).
-        from repro.net.state import stack_states
-        if chan_chunks:
-            chans = TJ.concat_chunks(chan_chunks)
-            Ws = TJ.concat_chunks(w_chunks)
-        else:
-            chans, Ws = stack_states(chan_log), jnp.stack(w_log)
+        chans = TJ.concat_chunks(chan_chunks)
+        Ws = TJ.concat_chunks(w_chunks)
         rep = P.epsilon_report(proto, chans, Ws=Ws)
         traj = rep["epsilon_per_round"]
         print(f"[train] per-round eps over {rep['rounds']} rounds: "
@@ -783,20 +673,16 @@ def _main(argv) -> TrainResult:
             meta["sparse_neighbors"] = proto.sparse_neighbors
             if isinstance(Ws, SparseW):
                 meta["sparse_w"] = Ws.layout_meta()
-        if spec is not None:
-            # flat-buffer runs checkpoint the buffer itself, with the
-            # shard-layout metadata — restorable under ANY shard count
-            # (checkpoint.restore_flat). The state pytree carries the PRNG
-            # carry key AND the net/fleet NetState (dynamic runs): exactly
-            # the TrajCarry a bitwise resume needs.
-            from repro.checkpoint import save_flat
-            state = {"key": key}
-            if net_state is not None:
-                state["net"] = net_state
-            save_flat(args.checkpoint, wp, spec, step=args.steps,
-                      state=state, metadata=meta)
-        else:
-            ckpt_save(args.checkpoint, wp, step=args.steps, metadata=meta)
+        # the buffer itself, with the shard-layout metadata — restorable
+        # under ANY shard count (checkpoint.restore_flat). The state
+        # pytree carries the PRNG carry key AND the net/fleet NetState
+        # (dynamic runs): exactly the TrajCarry a bitwise resume needs.
+        from repro.checkpoint import save_flat
+        state = {"key": key}
+        if net_state is not None:
+            state["net"] = net_state
+        save_flat(args.checkpoint, wp, spec, step=args.steps,
+                  state=state, metadata=meta)
         print(f"[train] checkpoint -> {args.checkpoint}")
         if runlog is not None:
             runlog.checkpoint(args.checkpoint, step=args.steps)
@@ -808,7 +694,7 @@ def _main(argv) -> TrainResult:
         print(f"[train] run log closed: {runlog.dir} "
               f"({runlog.n_events} events, {runlog.n_warnings} warnings) — "
               f"summarize with `python -m repro.obs.report {runlog.dir}`")
-    if not args.no_scan and carry.eps is not None:
+    if carry.eps is not None:
         m = np.asarray(carry.eps)
         e_c, _ = privacy.compose_from_moments(
             m, proto.delta,
@@ -820,8 +706,6 @@ def _main(argv) -> TrainResult:
         epsilon = float(rep["epsilon_total"])
     else:
         epsilon = float(rep["epsilon_worst"])
-    if args.no_scan:
-        return TrainResult(wp, epsilon, None, None)
     return TrainResult(wp, epsilon, runner, carry)
 
 

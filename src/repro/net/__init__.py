@@ -9,7 +9,7 @@ channel is a TracedChannelState pytree (net.state) consumed by the train
 step as an argument — one compiled step, any realization, zero retraces.
 
 Entry points: ``ProtocolConfig(channel_model="dynamic", scenario=...)`` +
-``protocol.make_dynamic_train_step``; see examples/dynamic_quickstart.py.
+``protocol.make_dynamic_flat_train_step``; see examples/dynamic_quickstart.py.
 """
 from repro.net.churn import ChurnConfig, ChurnState
 from repro.net.fading import FadingConfig, FadingState, rho_from_doppler

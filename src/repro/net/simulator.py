@@ -4,7 +4,7 @@ traced channel state, participation mask and mixing matrix.
 The whole per-round evolution is a pure function of (key, NetState) built
 from jnp ops over [N]-shaped arrays — it jits once and serves every round
 (and every realization) with zero retraces; the heavy train step consumes
-its outputs as ARGUMENTS (protocol.make_dynamic_train_step), so neither
+its outputs as ARGUMENTS (protocol.make_dynamic_flat_train_step), so neither
 side ever recompiles when the channel changes.
 
 Round pipeline (one call to ``round``):

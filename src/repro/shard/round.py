@@ -340,7 +340,7 @@ def _local_round_factory(cfg, proto, spec: FlatSpec, *, dynamic: bool,
                          "n_shards=S))")
     layout = spec.layout
     chan = None if dynamic else proto.channel()
-    xspec = protocol_lib._flat_spec(proto, dynamic=dynamic)
+    xspec = protocol_lib._mixing_spec(proto, dynamic=dynamic)
     local_grads = (_padded_local_grads(cfg, proto, spec, remat=remat)
                    if axis is None else
                    worker_split_grads(cfg, proto, spec, axis, remat=remat))

@@ -164,7 +164,7 @@ def make_worker_sharded_dynamic_flat_train_step(cfg, proto, spec: FlatSpec,
     local_grads = protocol_lib._make_flat_local_pass(cfg, proto,
                                                      spec.unravel_row,
                                                      remat=remat)
-    xspec = protocol_lib._flat_spec(proto, dynamic=True)
+    xspec = protocol_lib._mixing_spec(proto, dynamic=True)
     gamma, eta = proto.gamma, proto.eta
     n_workers = proto.n_workers
 

@@ -317,9 +317,9 @@ def shipped():
 
 
 def test_registry_covers_all_driver_paths():
-    assert {"static-tree", "static-flat", "dynamic-tree",
-            "dynamic-flat-tele", "fleet-tree", "fleet-flat",
-            "shard-flat-s2", "shard-flat-s2-mesh"} <= set(PROGRAMS)
+    assert {"static-flat", "static-flat-orthogonal", "dynamic-flat-tele",
+            "dynamic-sparse-flat", "fleet-flat", "shard-flat-s2",
+            "shard-flat-s2-mesh"} <= set(PROGRAMS)
 
 
 def test_shipped_programs_have_no_findings(shipped):

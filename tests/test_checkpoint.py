@@ -29,7 +29,7 @@ def _cfg():
 
 def _proto(**kw):
     base = dict(scheme="dwfl", n_workers=W, gamma=0.05, eta=0.4, clip=1.0,
-                p_dbm=60.0, sigma=0.7, sigma_m=0.5, flat_buffer=True)
+                p_dbm=60.0, sigma=0.7, sigma_m=0.5)
     base.update(kw)
     return P.ProtocolConfig(**base)
 
@@ -63,34 +63,52 @@ def _dynamic_setup(n_shards, max_chunk_cols=None):
     return spec, body, carry0
 
 
+def _orthogonal_setup():
+    """The static orthogonal baseline: its exchange runs on the buffer as
+    one leaf, and the carry holds no net state."""
+    cfg = _cfg()
+    wp = _wp(cfg)
+    spec = X.make_flat_spec(wp)
+    body = TJ.make_round_body(cfg, _proto(scheme="orthogonal"), _store(),
+                              spec=spec)
+    return spec, body, TJ.TrajCarry(jax.random.PRNGKey(5), spec.flatten(wp))
+
+
 def _run(body, carry, k):
     runner = TJ.ChunkRunner(body, donate=False)
     carry, _ = runner.run(carry, k)
     return carry
 
 
-@pytest.mark.parametrize("n_shards", [1, 2], ids=["unsharded", "sharded"])
-def test_mid_trajectory_checkpoint_resumes_bitwise(n_shards, tmp_path):
-    """Run 6 dynamic rounds straight; run 3, checkpoint (buffer + net
-    state + PRNG key), restore into a FRESH spec, run 3 more: final
-    buffer, net state and carry key are bitwise-identical."""
-    spec, body, carry0 = _dynamic_setup(n_shards)
+@pytest.mark.parametrize("case", ["unsharded", "sharded", "orthogonal"])
+def test_mid_trajectory_checkpoint_resumes_bitwise(case, tmp_path):
+    """Run 6 rounds straight; run 3, checkpoint (buffer + net state, where
+    the round has one, + PRNG key), restore into a FRESH spec, run 3
+    more: final buffer, net state and carry key are bitwise-identical.
+    Dynamic DWFL unsharded and model-sharded, and the static orthogonal
+    baseline."""
+    setup = (_orthogonal_setup if case == "orthogonal" else
+             lambda: _dynamic_setup(2 if case == "sharded" else 1))
+    spec, body, carry0 = setup()
     ref = _run(body, carry0, 6)
 
     mid = _run(body, carry0, 3)
+    kept = {"key": mid.key}
+    if mid.net is not None:
+        kept["net"] = mid.net
     path = os.path.join(tmp_path, "ckpt")
-    save_flat(path, mid.params, spec,
-              step=3, state={"key": mid.key, "net": mid.net},
+    save_flat(path, mid.params, spec, step=3, state=kept,
               metadata={"test": "mid-trajectory"})
 
-    spec2, body2, carry_fresh = _dynamic_setup(n_shards)
-    flat, state, manifest = restore_flat(
-        path, spec2, state_like={"key": mid.key, "net": mid.net})
+    spec2, body2, carry_fresh = setup()
+    flat, state, manifest = restore_flat(path, spec2, state_like=kept)
     assert manifest["step"] == 3
     assert manifest["metadata"]["flat_layout"]["d"] == spec2.d
-    got = _run(body2, TJ.TrajCarry(jnp.asarray(state["key"]), flat,
-                                   jax.tree_util.tree_map(
-                                       jnp.asarray, state["net"])), 3)
+    net = state.get("net")
+    got = _run(body2, TJ.TrajCarry(
+        jnp.asarray(state["key"]), flat,
+        None if net is None else jax.tree_util.tree_map(jnp.asarray, net)),
+        3)
     np.testing.assert_array_equal(np.asarray(spec2.unpad(got.params)),
                                   np.asarray(spec.unpad(ref.params)))
     np.testing.assert_array_equal(np.asarray(got.key), np.asarray(ref.key))

@@ -21,6 +21,7 @@ import pytest
 
 from repro.core import privacy
 from repro.core import protocol as P
+from repro.core.exchange import make_flat_spec
 
 pytestmark = pytest.mark.claims
 
@@ -212,13 +213,16 @@ def _train_accuracy(scheme, *, steps, N=8, epsilon=1.0, seed=0):
                              seed=seed, p_dbm=70.0)
     key = jax.random.PRNGKey(seed)
     params = mlp.init(key, cfg, input_dim=input_dim)
-    wp = jax.tree_util.tree_map(
+    tree = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
-    step = jax.jit(P.make_train_step(cfg, proto))
+    spec = make_flat_spec(tree)
+    flat = spec.flatten(tree)
+    step = jax.jit(P.make_flat_train_step(cfg, proto, spec.unravel_row))
     for _ in range(steps):
         key, sk = jax.random.split(key)
-        wp, _ = step(wp, bat.next(), sk)
-    ev_loss, ev_acc = jax.jit(P.make_eval_fn(cfg))(wp, bat.full(128))
+        flat, _ = step(flat, bat.next(), sk)
+    ev_loss, ev_acc = jax.jit(P.make_eval_fn(cfg))(spec.unravel(flat),
+                                                   bat.full(128))
     return float(ev_loss), float(ev_acc)
 
 

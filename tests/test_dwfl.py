@@ -7,8 +7,9 @@ import numpy as np
 import pytest
 
 from repro.core import dwfl
+from repro.core import exchange as X_lib
 from repro.core.channel import ChannelConfig
-from repro.core.protocol import ProtocolConfig, init_worker_params, make_train_step
+from repro.core.protocol import ProtocolConfig, make_flat_train_step
 from repro.configs.registry import get_arch
 
 
@@ -135,18 +136,12 @@ def test_protocol_schemes_run(scheme):
     cfg = get_arch("dwfl-paper").replace(d_model=32)
     proto = ProtocolConfig(scheme=scheme, n_workers=4, gamma=0.05, eta=0.5,
                            clip=1.0, target_epsilon=1.0)
-    import repro.models.mlp as mlp
-    key = jax.random.PRNGKey(0)
-    params = mlp.init(key, cfg, input_dim=24)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (4,) + a.shape), params)
-    step = jax.jit(make_train_step(cfg, proto))
-    batch = {"x": jax.random.normal(key, (4, 8, 24)),
-             "y": jnp.zeros((4, 8), jnp.int32)}
-    wp2, metrics = step(wp, batch, key)
+    flat, unravel_row, batch = _flat_mlp(4)
+    step = jax.jit(make_flat_train_step(cfg, proto, unravel_row))
+    flat2, metrics = step(flat, batch, jax.random.PRNGKey(0))
     assert np.isfinite(float(metrics["loss"]))
-    leaves = jax.tree_util.tree_leaves(wp2)
-    assert all(bool(jnp.all(jnp.isfinite(l))) for l in leaves)
+    assert flat2.shape == flat.shape
+    assert bool(jnp.all(jnp.isfinite(flat2)))
 
 
 def test_dwfl_convergence_quadratic():
@@ -266,76 +261,70 @@ def test_sampled_denominator_clamping_degenerate():
         assert float(jnp.max(jnp.abs(out))) < 1e3
 
 
-def _fused_pair(scheme, sigma_m=1.0, participation=1.0):
-    """Run one identical protocol round with fuse_exchange off/on."""
-    from repro.configs.registry import get_arch
+def _flat_mlp(N, seed=0):
+    """[N, d] buffer of a tiny MLP (a shared init, as the trainer starts),
+    its row unraveler and one batch a worker."""
     import repro.models.mlp as mlp
     cfg = get_arch("dwfl-paper").replace(d_model=32)
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(seed)
     params = mlp.init(key, cfg, input_dim=24)
     wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (6,) + a.shape), params)
-    batch = {"x": jax.random.normal(key, (6, 8, 24)),
-             "y": jnp.zeros((6, 8), jnp.int32)}
-    outs = []
-    for fuse in (False, True):
-        proto = ProtocolConfig(scheme=scheme, n_workers=6, gamma=0.05,
-                               eta=0.5, clip=1.0, target_epsilon=1.0,
-                               sigma_m=sigma_m, participation=participation,
-                               fuse_exchange=fuse)
-        step = jax.jit(make_train_step(cfg, proto))
-        wp2, _ = step(wp, batch, key)
-        outs.append(wp2)
-    return outs
+        lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
+    spec = X_lib.make_flat_spec(wp)
+    batch = {"x": jax.random.normal(key, (N, 8, 24)),
+             "y": jax.random.randint(key, (N, 8), 0, 10)}
+    return spec.flatten(wp), spec.unravel_row, batch
 
 
-def test_fuse_exchange_gossip_exact_equivalence():
-    """Noiseless gossip: the bucketed (single flat all-reduce) path must
-    reproduce the per-leaf path EXACTLY — same tree, same values."""
-    plain, fused = _fused_pair("gossip")
-    assert (jax.tree_util.tree_structure(plain)
-            == jax.tree_util.tree_structure(fused))
-    for a, b in zip(jax.tree_util.tree_leaves(plain),
-                    jax.tree_util.tree_leaves(fused)):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
+@pytest.mark.parametrize("kw", [
+    dict(scheme="dwfl"), dict(scheme="gossip"),
+    dict(scheme="dwfl", topology="ring"),
+    dict(scheme="dwfl", participation=0.5)],
+    ids=["complete", "gossip", "topology", "sampled"])
+def test_flat_step_mean_invariant_every_mixing_spec(kw):
+    """Eqt. 9 with σ_m = 0 on each plan of the fused flat round: the DP
+    noise cancels in the worker mean, which moves by −γ·mean(clip(g))
+    alone. The mean is the one the round's W keeps: uniform for the
+    doubly-stochastic plans, uniform over the transmitters for the
+    sampled one (whose listeners' columns are zero)."""
+    from repro.core import privacy
+    from repro.models import model as M
+    cfg = get_arch("dwfl-paper").replace(d_model=32)
+    N, gamma = 6, 0.05
+    proto = ProtocolConfig(n_workers=N, gamma=gamma, eta=0.5, clip=1.0,
+                           target_epsilon=1.0, sigma_m=0.0, **kw)
+    flat, unravel_row, batch = _flat_mlp(N)
+    flat = flat + 0.1 * jax.random.normal(jax.random.PRNGKey(5), flat.shape)
+    key = jax.random.PRNGKey(7)
+    out = jax.jit(make_flat_train_step(cfg, proto, unravel_row))(
+        flat, batch, key)[0]
 
-
-def test_fuse_exchange_dwfl_mean_invariant():
-    """DWFL with noise: fused and per-leaf paths consume PRNG differently
-    (one key for the flat leaf vs one per leaf) so values differ — but with
-    sigma_m=0 BOTH must preserve the worker mean exactly (Eqt. 9), which
-    pins the bucket/unravel layout without fixing the noise draw."""
-    plain, fused = _fused_pair("dwfl", sigma_m=0.0)
-    for a, b in zip(jax.tree_util.tree_leaves(plain),
-                    jax.tree_util.tree_leaves(fused)):
-        np.testing.assert_allclose(np.asarray(a.mean(0)),
-                                   np.asarray(b.mean(0)),
-                                   rtol=2e-4, atol=2e-5)
-
-
-def test_fuse_exchange_sampled_runs():
-    """fuse_exchange composes with per-round worker sampling."""
-    plain, fused = _fused_pair("dwfl", participation=0.5)
-    for l in jax.tree_util.tree_leaves(fused):
-        assert bool(jnp.all(jnp.isfinite(l)))
+    spec = X_lib.resolve_spec(proto)
+    assert spec.plan is not None
+    W = np.asarray(spec.plan(proto, proto.channel(),
+                             jax.random.split(key, 3)[2]).W, np.float64)
+    vals, vecs = np.linalg.eig(W.T)
+    pi = np.real(vecs[:, np.argmin(np.abs(vals - 1.0))])
+    pi = pi / pi.sum()
+    g = jax.vmap(lambda v, b: privacy.clip_gradient_tree(
+        jax.grad(lambda u: M.loss_fn(unravel_row(u), b, cfg))(v),
+        proto.clip)[0])(flat, batch)
+    want = pi @ np.asarray(flat - gamma * g, np.float64)
+    np.testing.assert_allclose(pi @ np.asarray(out, np.float64), want,
+                               rtol=1e-4, atol=2e-5)
+    # the round did mix: rows moved by more than the local step
+    assert float(jnp.max(jnp.abs(out - (flat - gamma * g)))) > 1e-3
 
 
 def test_sampled_protocol_runs():
     cfg = get_arch("dwfl-paper").replace(d_model=32)
     proto = ProtocolConfig(scheme="dwfl", n_workers=6, gamma=0.05, eta=0.5,
                            clip=1.0, target_epsilon=1.0, participation=0.5)
-    import repro.models.mlp as mlp
-    key = jax.random.PRNGKey(0)
-    params = mlp.init(key, cfg, input_dim=24)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (6,) + a.shape), params)
-    step = jax.jit(make_train_step(cfg, proto))
-    batch = {"x": jax.random.normal(key, (6, 8, 24)),
-             "y": jnp.zeros((6, 8), jnp.int32)}
-    wp2, metrics = step(wp, batch, key)
+    flat, unravel_row, batch = _flat_mlp(6)
+    step = jax.jit(make_flat_train_step(cfg, proto, unravel_row))
+    flat2, metrics = step(flat, batch, jax.random.PRNGKey(0))
     assert np.isfinite(float(metrics["loss"]))
+    assert bool(jnp.all(jnp.isfinite(flat2)))
 
 
 # ---------------------------------------------------------------------------

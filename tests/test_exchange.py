@@ -1,7 +1,8 @@
 """Unified mixing-matrix exchange engine (repro.core.exchange): every
 ExchangeSpec against the Eqt. (8) oracle, property tests over arbitrary
-doubly-stochastic W, flat-buffer mean-descent invariance, and the unified
-fuse_exchange guard."""
+doubly-stochastic W, flat-buffer mean-descent invariance, and the flat
+round of every scheme (the baselines run their exchange on the [W, d]
+buffer as one leaf)."""
 import dataclasses
 
 import jax
@@ -17,8 +18,9 @@ except ImportError:
 from repro.configs.registry import get_arch
 from repro.core import dwfl, exchange as X
 from repro.core.channel import ChannelConfig
-from repro.core.protocol import (ProtocolConfig, make_flat_train_step,
-                                 make_train_step)
+from repro.core.protocol import (ProtocolConfig,
+                                 make_dynamic_flat_train_step,
+                                 make_flat_train_step)
 
 
 def _chan(N=6, sigma=0.7, sigma_m=0.3, seed=3):
@@ -165,9 +167,12 @@ def test_resolve_spec_routing(kw, want):
 
 
 def test_resolve_spec_collective_and_dynamic():
+    """The dynamic routes: the dense traced W, its neighbor-list twin, and
+    the refusal of the baselines (static-channel comparisons)."""
     proto = ProtocolConfig(scheme="dwfl", n_workers=8)
-    assert X.resolve_spec(proto, axis="data").name == "collective"
     assert X.resolve_spec(proto, dynamic=True).name == "dynamic"
+    assert X.resolve_spec(dataclasses.replace(proto, sparse_neighbors=3),
+                          dynamic=True).name == "dynamic_sparse"
     with pytest.raises(ValueError):
         X.resolve_spec(ProtocolConfig(scheme="orthogonal", n_workers=8),
                        dynamic=True)
@@ -177,55 +182,6 @@ def test_resolve_spec_unknown_scheme():
     proto = dataclasses.replace(ProtocolConfig(n_workers=4), scheme="nope")
     with pytest.raises(ValueError):
         X.resolve_spec(proto)
-
-
-# ---------------------------------------------------------------------------
-# unified fuse_exchange guard (regression: the static step fused only
-# ("dwfl", "gossip") while the dynamic step fused unconditionally)
-# ---------------------------------------------------------------------------
-
-
-def _round_pair(scheme, fuse_vals=(False, True)):
-    import repro.models.mlp as mlp
-    cfg = get_arch("dwfl-paper").replace(d_model=32)
-    key = jax.random.PRNGKey(0)
-    params = mlp.init(key, cfg, input_dim=24)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (6,) + a.shape), params)
-    batch = {"x": jax.random.normal(key, (6, 8, 24)),
-             "y": jnp.zeros((6, 8), jnp.int32)}
-    outs = []
-    for fuse in fuse_vals:
-        proto = ProtocolConfig(scheme=scheme, n_workers=6, gamma=0.05,
-                               eta=0.5, clip=1.0, target_epsilon=1.0,
-                               fuse_exchange=fuse)
-        step = jax.jit(make_train_step(cfg, proto))
-        outs.append(step(wp, batch, key)[0])
-    return outs
-
-
-@pytest.mark.parametrize("scheme", ["orthogonal", "centralized"])
-def test_fuse_guard_baselines_never_bucketed(scheme):
-    """orthogonal/centralized must NEVER see a bucketed tree: with the
-    guard active their fused and unfused rounds consume PRNG identically,
-    so the results are BIT-IDENTICAL (a bucketed run would re-key the
-    single flat leaf and diverge)."""
-    assert not X.resolve_spec(
-        ProtocolConfig(scheme=scheme, n_workers=6)).fuse_ok
-    plain, fused = _round_pair(scheme)
-    for a, b in zip(jax.tree_util.tree_leaves(plain),
-                    jax.tree_util.tree_leaves(fused)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_fuse_guard_uniform_across_steps():
-    """The SAME spec table drives both step factories: the mixing family
-    buckets, the baselines never do."""
-    for scheme, ok in [("dwfl", True), ("gossip", True),
-                       ("orthogonal", False), ("centralized", False)]:
-        assert X.resolve_spec(
-            ProtocolConfig(scheme=scheme, n_workers=6)).fuse_ok == ok
-    assert X.resolve_spec(ProtocolConfig(n_workers=6), dynamic=True).fuse_ok
 
 
 # ---------------------------------------------------------------------------
@@ -263,40 +219,101 @@ def test_flatten_fleet_axes():
     assert unravel_row(flat[1, 2]).get("w").shape == (6,)
 
 
-def test_flat_train_step_matches_tree_step_stats():
-    """The flat-buffer static step trains the same problem the tree step
-    does: gossip (noiseless) rounds must agree on the parameter MEAN
-    (exact mixing invariant) though PRNG-free here entirely."""
+def _mlp_problem(W=6, seed=0):
+    """A tiny MLP with DIFFERENT weights on each of ``W`` workers, its
+    [W, d] buffer and one batch a worker."""
     import repro.models.mlp as mlp
     cfg = get_arch("dwfl-paper").replace(d_model=32)
-    key = jax.random.PRNGKey(0)
-    params = mlp.init(key, cfg, input_dim=24)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (6,) + a.shape), params)
-    batch = {"x": jax.random.normal(key, (6, 8, 24)),
-             "y": jnp.zeros((6, 8), jnp.int32)}
-    proto = ProtocolConfig(scheme="gossip", n_workers=6, gamma=0.05, eta=0.5,
-                           clip=1.0)
-    tree_step = jax.jit(make_train_step(cfg, proto))
-    flat = X.flatten_worker_tree(wp)
-    unravel, unravel_row = X.worker_unravelers(wp)
-    flat_step = jax.jit(make_flat_train_step(cfg, proto, unravel_row))
-    wp2, m_tree = tree_step(wp, batch, key)
-    flat2, m_flat = flat_step(flat, batch, key)
-    assert m_flat["loss"] == pytest.approx(float(m_tree["loss"]), rel=1e-5)
-    back = unravel(flat2)
-    for a, b in zip(jax.tree_util.tree_leaves(wp2),
-                    jax.tree_util.tree_leaves(back)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
+    key = jax.random.PRNGKey(seed)
+    wp = jax.vmap(lambda k: mlp.init(k, cfg, input_dim=24))(
+        jax.random.split(key, W))
+    batch = {"x": jax.random.normal(key, (W, 8, 24)),
+             "y": jax.random.randint(key, (W, 8), 0, 10)}
+    return cfg, wp, batch
+
+
+def _clipped_grads(cfg, wp, batch, clip):
+    """Each worker's gradient of its own loss, L2-clipped, as a tree."""
+    from repro.core import privacy
+    from repro.models import model as M
+    g = jax.vmap(jax.grad(lambda p, b: M.loss_fn(p, b, cfg)))(wp, batch)
+    return jax.vmap(lambda t: privacy.clip_gradient_tree(t, clip)[0])(g)
 
 
 def test_flat_step_rejects_baseline_schemes():
-    cfg = get_arch("dwfl-paper").replace(d_model=32)
+    """The flat step takes every scheme: orthogonal and centralized
+    rounds run on the [W, d] buffer, finite and moving every row."""
+    cfg, wp, batch = _mlp_problem()
+    flat = X.flatten_worker_tree(wp)
+    _, unravel_row = X.worker_unravelers(wp)
     for scheme in ("orthogonal", "centralized"):
-        proto = ProtocolConfig(scheme=scheme, n_workers=6)
-        with pytest.raises(ValueError):
-            make_flat_train_step(cfg, proto, lambda v: v)
+        proto = ProtocolConfig(scheme=scheme, n_workers=6, gamma=0.05,
+                               eta=0.5, target_epsilon=1.0)
+        step = jax.jit(make_flat_train_step(cfg, proto, unravel_row))
+        out, metrics = step(flat, batch, jax.random.PRNGKey(1))
+        assert out.shape == flat.shape and out.dtype == jnp.float32
+        assert np.all(np.isfinite(np.asarray(out)))
+        assert np.all(np.abs(np.asarray(out - flat)).max(axis=1) > 0)
+        assert np.isfinite(float(metrics["loss"]))
+
+
+@pytest.mark.parametrize("scheme", ["orthogonal", "centralized"])
+def test_flat_baseline_round_matches_leafwise_exchange(scheme):
+    """σ = σ_m = 0: the flat round of a baseline equals its exchange run
+    leaf by leaf on the unravelled tree after the local step (the law is
+    element-wise, so the buffer's one leaf changes nothing)."""
+    cfg, wp, batch = _mlp_problem()
+    gamma, eta = 0.05, 0.5
+    proto = ProtocolConfig(scheme=scheme, n_workers=6, gamma=gamma, eta=eta,
+                           clip=1.0, sigma=0.0, sigma_m=0.0,
+                           target_epsilon=0.0)
+    chan = proto.channel()
+    flat = X.flatten_worker_tree(wp)
+    unravel, unravel_row = X.worker_unravelers(wp)
+    key = jax.random.PRNGKey(2)
+    got = jax.jit(make_flat_train_step(cfg, proto, unravel_row))(
+        flat, batch, key)[0]
+
+    g = _clipped_grads(cfg, wp, batch, proto.clip)
+    x = jax.tree_util.tree_map(lambda p, gg: p - gamma * gg, wp, g)
+    if scheme == "orthogonal":
+        want = dwfl.exchange_orthogonal(x, key, chan, eta)
+    else:
+        zero = jax.tree_util.tree_map(jnp.zeros_like, x)
+        want = dwfl.exchange_centralized(x, zero, key, chan)
+    for a, b in zip(jax.tree_util.tree_leaves(unravel(got)),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("channel_model", ["static", "dynamic"])
+def test_single_worker_flat_step_is_plain_sgd(channel_model):
+    """One worker has no peers: the round is flat − γ·clip(g), with the
+    gradient and the clip computed here by hand."""
+    cfg, wp, batch = _mlp_problem(W=1)
+    gamma, clip = 0.05, 0.1
+    proto = ProtocolConfig(n_workers=1, gamma=gamma, clip=clip,
+                           channel_model=channel_model)
+    flat = X.flatten_worker_tree(wp)
+    _, unravel_row = X.worker_unravelers(wp)
+    key = jax.random.PRNGKey(3)
+    if channel_model == "static":
+        step = jax.jit(make_flat_train_step(cfg, proto, unravel_row))
+        got = step(flat, batch, key)[0]
+    else:
+        step = jax.jit(make_dynamic_flat_train_step(cfg, proto, unravel_row))
+        got = step(flat, batch, key, None, None)[0]
+
+    from repro.models import model as M
+    row = jax.grad(lambda v: M.loss_fn(unravel_row(v),
+                                       {k: b[0] for k, b in batch.items()},
+                                       cfg))(flat[0])
+    norm = float(jnp.sqrt(jnp.sum(row ** 2)))
+    assert norm > clip                       # the clip is active
+    want = flat[0] - gamma * row * (clip / norm)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

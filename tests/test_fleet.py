@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import exchange as X
 from repro.core import privacy
 from repro.core import protocol as P
 from repro.fleet import (FleetEngine, ScenarioGrid, fleet_epsilon_report,
@@ -107,18 +108,20 @@ def test_fleet_step_equals_python_loop():
     states = fleet.init(jax.random.PRNGKey(4))
     _, chans, _, Ws = fleet.round(jax.random.PRNGKey(5), states)
     keys = fleet.split_keys(jax.random.PRNGKey(6))
+    spec1, specR = X.make_flat_spec(wp1), X.make_flat_spec(wpR, lead_axes=2)
 
-    fleet_step = jax.jit(fleet.make_fleet_step(cfg))
-    wp_f, metrics_f = fleet_step(wpR, batchR, keys, chans, Ws)
+    fleet_step = jax.jit(fleet.make_fleet_step(cfg, spec=specR))
+    flat_f, metrics_f = fleet_step(specR.flatten(wpR), batchR, keys, chans,
+                                   Ws)
 
-    base_step = jax.jit(P.make_dynamic_train_step(cfg, proto))
+    base_step = jax.jit(P.make_dynamic_flat_train_step(cfg, proto,
+                                                       spec1.unravel_row))
     for r in range(R):
         chan_r = jax.tree_util.tree_map(lambda a: a[r], chans)
-        wp_r, metrics_r = base_step(wp1, batch1, keys[r], chan_r, Ws[r])
-        for a, b in zip(jax.tree_util.tree_leaves(wp_f),
-                        jax.tree_util.tree_leaves(wp_r)):
-            np.testing.assert_allclose(np.asarray(a[r]), np.asarray(b),
-                                       rtol=2e-5, atol=2e-6)
+        flat_r, metrics_r = base_step(spec1.flatten(wp1), batch1, keys[r],
+                                      chan_r, Ws[r])
+        np.testing.assert_allclose(np.asarray(flat_f[r]), np.asarray(flat_r),
+                                   rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(float(metrics_f["loss"][r]),
                                    float(metrics_r["loss"]), rtol=1e-5)
 
@@ -162,7 +165,8 @@ def test_fleet_zero_retrace_across_replicate_batches():
     fleet = FleetEngine(proto)
     cfg, _wp1, _batch1, wpR, batchR = _tiny_model()
     traces = {"n": 0}
-    _round = fleet.make_fleet_round(cfg)
+    specR = X.make_flat_spec(wpR, lead_axes=2)
+    _round = fleet.make_fleet_round(cfg, spec=specR)
 
     def counted(k, states, wp, batch):
         traces["n"] += 1
@@ -170,7 +174,7 @@ def test_fleet_zero_retrace_across_replicate_batches():
 
     fleet_round = jax.jit(counted)
     states = fleet.init(jax.random.PRNGKey(8))
-    wp = wpR
+    wp = specR.flatten(wpR)
     for t in range(4):
         states, wp, _m, _c, _w = fleet_round(
             jax.random.fold_in(jax.random.PRNGKey(9), t), states, wp, batchR)
@@ -217,14 +221,14 @@ def test_fleet_sharded_matches_vmapped():
     _, chans, _, Ws = fleet.round(jax.random.PRNGKey(15), states)
     keys = fleet.split_keys(jax.random.PRNGKey(16))
 
-    plain = jax.jit(fleet.make_fleet_step(cfg))
-    sharded = jax.jit(fleet.make_fleet_step(cfg, mesh=mesh))
-    wp_a, m_a = plain(wpR, batchR, keys, chans, Ws)
-    wp_b, m_b = sharded(wpR, batchR, keys, chans, Ws)
-    for a, b in zip(jax.tree_util.tree_leaves(wp_a),
-                    jax.tree_util.tree_leaves(wp_b)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
+    specR = X.make_flat_spec(wpR, lead_axes=2)
+    flat = specR.flatten(wpR)
+    plain = jax.jit(fleet.make_fleet_step(cfg, spec=specR))
+    sharded = jax.jit(fleet.make_fleet_step(cfg, mesh=mesh, spec=specR))
+    flat_a, m_a = plain(flat, batchR, keys, chans, Ws)
+    flat_b, m_b = sharded(flat, batchR, keys, chans, Ws)
+    np.testing.assert_allclose(np.asarray(flat_a), np.asarray(flat_b),
+                               rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(m_a["loss"]),
                                np.asarray(m_b["loss"]), rtol=1e-5)
 
@@ -238,8 +242,9 @@ def test_fleet_sharded_rejects_indivisible():
         axis_names = ("replicas",)
         devices = np.empty((2,), object)
 
-    with pytest.raises(ValueError):
-        fleet.make_fleet_step(None, mesh=FakeMesh())
+    with pytest.raises(ValueError, match="divisible"):
+        fleet.make_fleet_step(None, mesh=FakeMesh(),
+                              unravel_row=lambda v: v)
 
 
 def test_stack_rounds_layout():
@@ -279,8 +284,7 @@ def test_scenario_grid_runs(tmp_path):
 def test_fleet_flat_buffer_round():
     """ISSUE 3: the flat-buffer fleet path — [R, W, d] persistent buffer,
     vmapped fused dp_mix round — runs, keeps the replicate axis intact,
-    and its unraveled params match the tree path's structure."""
-    from repro.core import exchange as X
+    and its unraveled params keep the model's structure."""
     proto = _proto()
     fleet = FleetEngine(proto)
     cfg, wp1, batch1, wpR, batchR = _tiny_model()
@@ -298,7 +302,7 @@ def test_fleet_flat_buffer_round():
     tree = unravel(flat)
     for leaf in jax.tree_util.tree_leaves(tree):
         assert leaf.shape[:2] == (R, N)
-    fleet_round = jax.jit(fleet.make_fleet_round(cfg, flat=True,
+    fleet_round = jax.jit(fleet.make_fleet_round(cfg,
                                                  unravel_row=unravel_row))
     states = fleet.init(key)
     states, flat2, metrics, chans, Ws = fleet_round(
@@ -307,9 +311,9 @@ def test_fleet_flat_buffer_round():
     assert bool(jnp.isfinite(flat2).all())
     assert metrics["loss"].shape == (R,)
     assert np.isfinite(np.asarray(metrics["loss"])).all()
-    # flat=True without the unraveler is a loud error, not a silent break
+    # no unraveler (and no spec) is a loud error, not a silent break
     with pytest.raises(ValueError):
-        fleet.make_fleet_step(cfg, flat=True)
+        fleet.make_fleet_step(cfg)
 
 
 def test_mean_ci():

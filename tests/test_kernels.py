@@ -5,72 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.dp_perturb import ops as dp_ops
-from repro.kernels.dp_perturb import ref as dp_ref
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.models.ssm import ssd_chunked
 
 KEY = jax.random.PRNGKey(0)
-
-
-# ---------------------------------------------------------------------------
-# dp_perturb
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("shape", [(64,), (1000, 37), (3, 17, 29), (256, 128)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_dp_perturb_deterministic_path(shape, dtype):
-    p = jax.random.normal(KEY, shape).astype(dtype)
-    g = jax.random.normal(jax.random.fold_in(KEY, 1), shape).astype(dtype)
-    got = dp_ops.sgd_update(p, g, 0.05)
-    want = dp_ref.sgd_update_ref(p, g, 0.05)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6,
-                               atol=1e-2 if dtype == jnp.bfloat16 else 1e-6)
-
-
-def test_dp_perturb_noise_moments():
-    shape = (512, 256)
-    p = jax.random.normal(KEY, shape)
-    g = jax.random.normal(jax.random.fold_in(KEY, 1), shape)
-    sigma, s_sig, s_noise = 2.0, 3.0, 1.5
-    x, xt = dp_ops.dp_perturb(p, g, 7, gamma=0.1, sigma=sigma,
-                              s_sig=s_sig, s_noise=s_noise)
-    want_x = dp_ref.sgd_update_ref(p, g, 0.1)
-    np.testing.assert_allclose(np.asarray(x), np.asarray(want_x), atol=1e-6)
-    resid = np.asarray(xt, np.float64) - s_sig * np.asarray(want_x, np.float64)
-    n = resid.size
-    assert abs(resid.mean()) < 5 * sigma * s_noise / np.sqrt(n)
-    assert resid.std() == pytest.approx(sigma * s_noise, rel=0.03)
-    # different seeds give different noise
-    _, xt2 = dp_ops.dp_perturb(p, g, 8, gamma=0.1, sigma=sigma,
-                               s_sig=s_sig, s_noise=s_noise)
-    assert float(jnp.max(jnp.abs(xt - xt2))) > 0.1
-
-
-def test_dp_perturb_bf16_parity():
-    """Satellite (ISSUE 3): dtype contract — bf16 in, bf16 out, on BOTH
-    returns, with the noise statistics of ref.py preserved through the
-    bf16 round-trip."""
-    shape = (512, 256)
-    p = jax.random.normal(KEY, shape).astype(jnp.bfloat16)
-    g = jax.random.normal(jax.random.fold_in(KEY, 1), shape).astype(jnp.bfloat16)
-    sigma, s_sig, s_noise = 2.0, 1.0, 1.5
-    x, xt = dp_ops.dp_perturb(p, g, 11, gamma=0.1, sigma=sigma,
-                              s_sig=s_sig, s_noise=s_noise)
-    assert x.dtype == jnp.bfloat16 and xt.dtype == jnp.bfloat16
-    want_x, _ = dp_ref.dp_perturb_ref(p, g, KEY, gamma=0.1, sigma=sigma,
-                                      s_sig=s_sig, s_noise=s_noise)
-    np.testing.assert_allclose(np.asarray(x, np.float32),
-                               np.asarray(want_x, np.float32),
-                               rtol=1e-2, atol=1e-2)
-    resid = np.asarray(xt, np.float64) - s_sig * np.asarray(x, np.float64)
-    # bf16 quantization adds ~0.4% relative noise on top of sigma*s_noise
-    assert resid.std() == pytest.approx(sigma * s_noise, rel=0.05)
-    assert abs(resid.mean()) < 5 * sigma * s_noise / np.sqrt(resid.size)
 
 
 # ---------------------------------------------------------------------------
@@ -107,6 +47,34 @@ def test_dp_mix_deterministic_matches_matrix_reference(impl):
         np.asarray(p), np.asarray(g), np.zeros((N, d)), np.zeros((N, d)),
         chan, gamma, eta)
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("N,d", [(2, 1), (3, 129), (10, 16_385),
+                                 (16, 40_000)])
+def test_dp_mix_ragged_shapes_match_reference(N, d):
+    """Shapes whose last column tile is ragged and whose worker rows pad
+    to the sublane multiple: on the deterministic path both lowerings
+    match ref.py, and with noise they draw the same fields into every
+    real row (equal to f32 rounding: the mix sums each tile as wide as
+    its lowering cuts it)."""
+    chan, p, g, plan = _mix_setup(N, d)
+    kw = dict(gamma=0.05, eta=0.4, m_scale=plan.m_scale)
+    want = np.asarray(mix_ref.dp_mix_round_ref(
+        p, g, KEY, plan.W, 0.0 * plan.amp, plan.c, 0.0, **kw))
+    noisy = {}
+    for impl in ("jnp", "pallas_interpret"):
+        det = mix_ops.dp_mix_round(p, g, 7, plan.W, 0.0 * plan.amp, plan.c,
+                                   0.0, impl=impl, **kw)
+        np.testing.assert_allclose(np.asarray(det), want, rtol=1e-5,
+                                   atol=1e-6)
+        noisy[impl] = np.asarray(mix_ops.dp_mix_round(
+            p, g, 7, plan.W, plan.amp, plan.c, chan.awgn_sigma, impl=impl,
+            **kw))
+        assert noisy[impl].shape == (N, d)
+        assert np.all(np.isfinite(noisy[impl]))
+    np.testing.assert_allclose(noisy["jnp"], noisy["pallas_interpret"],
+                               rtol=1e-6, atol=1e-6)
+    assert np.all(np.abs(noisy["jnp"] - want).max(axis=1) > 1e-3)
 
 
 def test_dp_mix_jnp_lowering_bitwise_matches_interpret():
@@ -152,7 +120,7 @@ def test_dp_mix_noise_moments():
 
 def test_dp_mix_seed_sensitivity_and_dtype():
     """Different seeds → different noise; bf16 buffer in → bf16 out (the
-    dp_perturb dtype contract)."""
+    kernel's dtype contract)."""
     chan, p, g, plan = _mix_setup()
     a = mix_ops.dp_mix_round_plan(p, g, 7, plan, gamma=0.05, eta=0.4)
     b = mix_ops.dp_mix_round_plan(p, g, 8, plan, gamma=0.05, eta=0.4)

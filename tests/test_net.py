@@ -10,6 +10,7 @@ import pytest
 from repro.core import dwfl, privacy
 from repro.core import protocol as P
 from repro.core.channel import ChannelConfig
+from repro.core.exchange import make_flat_spec
 from repro.net import (ChurnConfig, FadingConfig, GeometryConfig,
                        NetworkSimulator, SCENARIOS, TracedChannelState,
                        complete_mixing, get_scenario, rho_from_doppler)
@@ -84,17 +85,19 @@ def test_zero_retrace_across_channel_draws():
     sim = proto.simulator()
 
     traces = {"n": 0}
-    inner = P.make_dynamic_train_step(cfg, proto)
+    key = jax.random.PRNGKey(0)
+    params = mlp.init(key, cfg, input_dim=24)
+    tree = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
+    spec = make_flat_spec(tree)
+    inner = P.make_dynamic_flat_train_step(cfg, proto, spec.unravel_row)
 
     def counted(wp, batch, key, chan, W):
         traces["n"] += 1
         return inner(wp, batch, key, chan, W)
 
     step = jax.jit(counted)
-    key = jax.random.PRNGKey(0)
-    params = mlp.init(key, cfg, input_dim=24)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
+    wp = spec.flatten(tree)
     batch = {"x": jax.random.normal(key, (N, 8, 24)),
              "y": jnp.zeros((N, 8), jnp.int32)}
 
@@ -107,7 +110,7 @@ def test_zero_retrace_across_channel_draws():
         net_state, chan, mask, W = net_round(k1, net_state)
         wp2, metrics = step(wp, batch, k2, chan, W)
         cs.append(float(chan.c))
-        outs.append(np.asarray(jax.tree_util.tree_leaves(wp2)[0]))
+        outs.append(np.asarray(wp2))
         assert np.isfinite(float(metrics["loss"]))
     assert traces["n"] == 1, f"retraced {traces['n']} times"
     assert len(set(np.round(cs, 6))) >= 3, cs       # channels really differ
@@ -424,19 +427,22 @@ def test_dynamic_protocol_trains():
     bat = FederatedBatcher(x, y, dirichlet_partition(y, N, alpha=0.5, seed=0),
                            batch_size=32, seed=0)
     params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=256)
-    wp = jax.tree_util.tree_map(
+    tree = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (N,) + a.shape), params)
-    step = jax.jit(P.make_dynamic_train_step(cfg, proto))
+    spec = make_flat_spec(tree)
+    wp = spec.flatten(tree)
+    step = jax.jit(P.make_dynamic_flat_train_step(cfg, proto,
+                                                  spec.unravel_row))
     net_round = jax.jit(sim.round)
     evaluate = jax.jit(P.make_eval_fn(cfg))
-    _, acc0 = evaluate(wp, bat.full(256))
+    _, acc0 = evaluate(spec.unravel(wp), bat.full(256))
     st = sim.init(jax.random.PRNGKey(1))
     k = jax.random.PRNGKey(2)
     for t in range(120):
         k, k1, k2 = jax.random.split(k, 3)
         st, chan, mask, W = net_round(k1, st)
         wp, metrics = step(wp, bat.next(), k2, chan, W)
-    loss, acc = evaluate(wp, bat.full(256))
+    loss, acc = evaluate(spec.unravel(wp), bat.full(256))
     assert np.isfinite(float(loss))
     assert float(acc) > max(float(acc0), 0.1) + 0.05, (float(acc0), float(acc))
 

@@ -449,7 +449,7 @@ def _flat_chunk_runner():
     cfg = get_arch("dwfl-paper").replace(d_model=8)
     proto = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
                              eta=0.4, clip=1.0, p_dbm=60.0, sigma=0.7,
-                             sigma_m=0.5, flat_buffer=True)
+                             sigma_m=0.5)
     rng = np.random.default_rng(0)
     x = rng.normal(size=(80, dim)).astype(np.float32)
     y = rng.integers(0, 10, 80).astype(np.int32)
@@ -459,8 +459,7 @@ def _flat_chunk_runner():
     wp = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (W,) + a.shape), params)
     _unravel, unravel_row = X.worker_unravelers(wp)
-    body = TJ.make_round_body(cfg, proto, store, flat=True,
-                              unravel_row=unravel_row,
+    body = TJ.make_round_body(cfg, proto, store, unravel_row=unravel_row,
                               telemetry=obs.TelemetrySpec())
     carry = TJ.TrajCarry(jax.random.PRNGKey(1), X.flatten_worker_tree(wp),
                          None, obs.init_eps_moments())

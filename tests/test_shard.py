@@ -245,8 +245,8 @@ def test_fleet_logical_sharded_step_ulp_close():
     keys = fleet.split_keys(jax.random.PRNGKey(7))
     batch = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), _batch())
-    plain = jax.jit(fleet.make_fleet_step(cfg, flat=True, spec=spec0))
-    sharded = jax.jit(fleet.make_fleet_step(cfg, flat=True, spec=spec2))
+    plain = jax.jit(fleet.make_fleet_step(cfg, spec=spec0))
+    sharded = jax.jit(fleet.make_fleet_step(cfg, spec=spec2))
     f_a, m_a = plain(flat0, batch, keys, chans, Ws)
     f_b, m_b = sharded(flat2, batch, keys, chans, Ws)
     assert f_b.shape == (R, W, spec2.width)
@@ -262,7 +262,7 @@ def test_trajectory_sharded_scan_bitwise_and_chunk_invariant():
     canonical columns — sharding composes with chunking without touching
     the PRNG stream."""
     cfg = _cfg()
-    proto = _proto(flat_buffer=True)
+    proto = _proto()
     wp = _wp(cfg)
     store = _store()
     spec0 = X.make_flat_spec(wp)
@@ -453,9 +453,8 @@ _MESH_SCRIPT = textwrap.dedent("""
     flatm = jax.device_put(
         spec2f.flatten(wpR),
         flat_buffer_sharding(spec2f, mesh2, replicate_axis="replicas"))
-    plain = jax.jit(fleet.make_fleet_step(cfg, flat=True, spec=spec0f))
-    shard2d = jax.jit(fleet.make_fleet_step(cfg, mesh=mesh2, flat=True,
-                                            spec=spec2f))
+    plain = jax.jit(fleet.make_fleet_step(cfg, spec=spec0f))
+    shard2d = jax.jit(fleet.make_fleet_step(cfg, mesh=mesh2, spec=spec2f))
     fa, ma = plain(flat0f, batchR, keys, chans, Ws)
     fb, mb = shard2d(flatm, batchR, keys, chans, Ws)
     np.testing.assert_allclose(np.asarray(spec2f.unpad(fb)),

@@ -241,7 +241,7 @@ def test_sparse_round_matches_dense_reference_sweep():
 def _proto(**kw):
     base = dict(scheme="dwfl", n_workers=8, gamma=0.05, eta=0.4, clip=1.0,
                 p_dbm=60.0, sigma=0.7, sigma_m=0.5, channel_model="dynamic",
-                scenario="iot_dense", flat_buffer=True)
+                scenario="iot_dense")
     base.update(kw)
     return P.ProtocolConfig(**base)
 
@@ -412,8 +412,7 @@ _WORKER_SCRIPT = textwrap.dedent("""
     proto = P.ProtocolConfig(scheme="dwfl", n_workers=W, gamma=0.05,
                              eta=0.4, clip=1.0, p_dbm=60.0, sigma=0.7,
                              sigma_m=0.5, channel_model="dynamic",
-                             scenario="iot_dense", flat_buffer=True,
-                             sparse_neighbors=3)
+                             scenario="iot_dense", sparse_neighbors=3)
     params = mlp.init(jax.random.PRNGKey(0), cfg, input_dim=DIM)
     wp = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (W,) + a.shape), params)

@@ -104,7 +104,8 @@ def test_topology_privacy_interpolates():
 
 
 def test_protocol_with_ring_topology_runs():
-    from repro.core.protocol import ProtocolConfig, make_train_step
+    from repro.core.exchange import make_flat_spec
+    from repro.core.protocol import ProtocolConfig, make_flat_train_step
     from repro.configs.registry import get_arch
     import repro.models.mlp as mlp
     cfg = get_arch("dwfl-paper").replace(d_model=32)
@@ -114,11 +115,13 @@ def test_protocol_with_ring_topology_runs():
     params = mlp.init(key, cfg, input_dim=24)
     wp = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (6,) + a.shape), params)
-    step = jax.jit(make_train_step(cfg, proto))
+    spec = make_flat_spec(wp)
+    step = jax.jit(make_flat_train_step(cfg, proto, spec.unravel_row))
     batch = {"x": jax.random.normal(key, (6, 8, 24)),
              "y": jnp.zeros((6, 8), jnp.int32)}
-    wp2, metrics = step(wp, batch, key)
+    flat2, metrics = step(spec.flatten(wp), batch, key)
     assert np.isfinite(float(metrics["loss"]))
+    assert bool(jnp.all(jnp.isfinite(flat2)))
 
 
 @settings(max_examples=15, deadline=None)

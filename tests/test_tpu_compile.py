@@ -24,8 +24,6 @@ from repro.shard.layout import ShardLayout
 
 D_PAPER = 855_050       # dwfl-paper: MLP 3072-256-256-10, params per worker
 D_SPARSE = 150_346      # the same MLP at hidden 48 (chip_smoke.py phase d)
-# dwfl-paper's leaves (the tree layout): 3072-256-256-10, weights and biases
-PAPER_LEAVES = [(3072, 256), (256,), (256, 256), (256,), (256, 10), (10,)]
 
 
 @pytest.fixture(scope="module")
@@ -174,17 +172,23 @@ def test_dense_vmem_ceiling_matches_compiler(S, noisy, past):
         assert "tpu_custom_call" in _hlo(kernel, *args)
 
 
-def test_tree_round_mixes_at_f32_precision(S):
-    """The tree layout's exchange (no --flat-buffer) at the paper's width:
-    its mixing contraction cancels n/c against the self-correction term,
-    so every dot it compiles to runs at full f32 precision, not TPU's
+@pytest.mark.parametrize("scheme", ["orthogonal", "centralized"])
+def test_baseline_flat_round_mixes_at_f32_precision(S, scheme):
+    """The baselines' exchange on the flat [10, d] buffer at the paper's
+    width (their specs have no fused plan, so it runs as XLA ops): it
+    cancels noise terms against the signal it rides on, so it compiles
+    with no bf16 convert and every dot at full f32 precision, not TPU's
     default single bf16 pass."""
-    N = 10
-    X = [S((N,) + s) for s in PAPER_LEAVES]
+    from repro.core.protocol import ProtocolConfig
+    proto = ProtocolConfig(scheme=scheme, n_workers=10, target_epsilon=1.0)
+    spec = exchange.resolve_spec(proto)
+    assert spec.plan is None
+    chan = proto.channel()
 
-    def step(X, n, m, W, c):
-        return exchange.mix_exchange(X, n, m, c, 0.4, W)
-    text = _hlo(step, X, X, X, S((N, N)), S(()))
+    def step(flat, key):
+        return spec.run(flat, jax.random.split(key, 3), chan, proto)
+    text = _hlo(step, S((10, D_PAPER)), S((2,), jnp.uint32))
+    assert "bf16" not in text
     dots = [ln for ln in text.splitlines() if " dot(" in ln
             or "convolution(" in ln]
     assert dots and all("operand_precision={highest,highest}" in ln

@@ -1,8 +1,8 @@
 """Scan-fused trajectory engine (ISSUE 4 tentpole).
 
 The load-bearing guarantee: chunking is INVISIBLE to the computation.
-For every driver path (static, dynamic, fleet) and both parameter layouts
-(worker tree, flat dp_mix buffer), running T rounds as K-chunked
+For every driver path (static, dynamic, fleet, and the baselines' planless
+round), running T rounds on the flat [W, d] buffer as K-chunked
 ``lax.scan`` programs produces BITWISE-identical final params, channel
 trajectories, mixing-matrix logs and metrics to the per-round
 one-dispatch-per-round loop over the same body — and the realized PRNG
@@ -58,6 +58,12 @@ def _wp(cfg, key=None):
         lambda a: jnp.broadcast_to(a[None], (W,) + a.shape), params)
 
 
+def _flat(wp, lead_axes=1):
+    """(flat buffer, FlatSpec) of a worker-stacked tree."""
+    spec = X.make_flat_spec(wp, lead_axes=lead_axes)
+    return spec.flatten(wp), spec
+
+
 def _proto(**kw):
     base = dict(scheme="dwfl", n_workers=W, gamma=0.05, eta=0.4, clip=1.0,
                 p_dbm=60.0, sigma=0.7, sigma_m=0.5)
@@ -102,90 +108,40 @@ def _run_chunked(body, carry, partition):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("flat", [False, True], ids=["tree", "flat"])
-def test_static_scan_equals_loop(flat):
+def test_static_scan_equals_loop():
     cfg = _cfg()
-    proto = _proto(flat_buffer=flat)
-    wp = _wp(cfg)
-    unravel_row = None
-    if flat:
-        _unravel, unravel_row = X.worker_unravelers(wp)
-        wp = X.flatten_worker_tree(wp)
-    body = TJ.make_round_body(cfg, proto, _store(), flat=flat,
-                              unravel_row=unravel_row)
+    wp, spec = _flat(_wp(cfg))
+    body = TJ.make_round_body(cfg, _proto(), _store(), spec=spec)
     carry0 = TJ.TrajCarry(jax.random.PRNGKey(3), wp)
     c_loop, out_loop = TJ.run_per_round(body, carry0, 7)
     c_scan, out_scan = _run_chunked(body, carry0, (3, 3, 1))
     _assert_tree_equal(c_loop.key, c_scan.key, "carry key")
-    if flat:
-        _assert_tree_equal(c_loop.params, c_scan.params, "final params")
-        _assert_tree_equal(out_loop["metrics"], out_scan["metrics"],
-                           "metrics")
-        return
-    # Tree layout: the keys and the standard-normal draws are bitwise
-    # (test_static_tree_noise_draws_bitwise_in_scan), but the static
-    # channel's per-worker amplitude is a compile-time constant that XLA
-    # folds into the inverse-CDF's sqrt(2) in some programs and not in
-    # others, so amp*G rounds differently by <= 2 ULP. Those noise terms
-    # reach |n/c| ~ 70, so the buffers agree to 2 ULP of 64 in absolute
-    # terms, and the metric means at the mesh-mean tolerance.
-    atol = 2 * float(np.spacing(np.float32(64.0)))
-    for x1, x2 in zip(jax.tree_util.tree_leaves(c_loop.params),
-                      jax.tree_util.tree_leaves(c_scan.params)):
-        np.testing.assert_allclose(np.asarray(x1), np.asarray(x2),
-                                   rtol=5e-6, atol=atol,
-                                   err_msg="final params")
-    for k in ("loss", "grad_norm", "param_norm"):
-        np.testing.assert_allclose(np.asarray(out_loop["metrics"][k]),
-                                   np.asarray(out_scan["metrics"][k]),
-                                   rtol=1e-6, err_msg=k)
+    _assert_tree_equal(c_loop.params, c_scan.params, "final params")
+    _assert_tree_equal(out_loop["metrics"], out_scan["metrics"], "metrics")
 
 
-def test_static_tree_noise_draws_bitwise_in_scan():
-    """The tree round's noise draws do not depend on the program they
-    are traced into: the per-leaf Gaussians are bitwise the same inside a
-    scan body and in a plain jit, and a field scaled by a power of two
-    (channel noise at sigma_m = 0.5) stays bitwise. Only the static
-    per-worker DP amplitude, a non-power-of-two constant, may round
-    differently (<= 2 ULP) when XLA folds it with sqrt(2)."""
+@pytest.mark.parametrize("scheme", ["orthogonal", "centralized"])
+def test_baseline_scan_equals_loop(scheme):
+    """The baselines' planless round (local step, then their exchange on
+    the [W, d] buffer as one leaf) keeps the chunk-invariance contract."""
     cfg = _cfg()
-    proto = _proto()
-    wp = _wp(cfg)
-    chan = proto.channel()
-
-    def draw(key):
-        k1, k2 = jax.random.split(key)
-        return (X.dp_noise(k1, wp, chan),
-                X.channel_noise(k2, wp, proto.sigma_m))
-
-    def in_scan(key):
-        return jax.lax.scan(lambda c, _: (c, draw(key)), 0, None,
-                            length=2)[1]
-
-    key = jax.random.PRNGKey(7)
-    dp_j, ch_j = jax.jit(draw)(key)
-    dp_s, ch_s = jax.tree_util.tree_map(lambda a: a[1],
-                                        jax.jit(in_scan)(key))
-    _assert_tree_equal(ch_j, ch_s, "channel noise")
-    for a, b in zip(jax.tree_util.tree_leaves(dp_j),
-                    jax.tree_util.tree_leaves(dp_s)):
-        a, b = np.asarray(a), np.asarray(b)
-        np.testing.assert_array_max_ulp(a, b, maxulp=2)
+    wp, spec = _flat(_wp(cfg))
+    body = TJ.make_round_body(cfg, _proto(scheme=scheme), _store(),
+                              spec=spec)
+    carry0 = TJ.TrajCarry(jax.random.PRNGKey(3), wp)
+    c_loop, out_loop = TJ.run_per_round(body, carry0, 7)
+    c_scan, out_scan = _run_chunked(body, carry0, (3, 3, 1))
+    _assert_tree_equal(c_loop.key, c_scan.key, "carry key")
+    _assert_tree_equal(c_loop.params, c_scan.params, "final params")
+    _assert_tree_equal(out_loop["metrics"], out_scan["metrics"], "metrics")
 
 
-@pytest.mark.parametrize("flat", [False, True], ids=["tree", "flat"])
-def test_dynamic_scan_equals_loop(flat):
+def test_dynamic_scan_equals_loop():
     cfg = _cfg()
-    proto = _proto(channel_model="dynamic", scenario="iot_dense",
-                   flat_buffer=flat)
+    proto = _proto(channel_model="dynamic", scenario="iot_dense")
     sim = proto.simulator()
-    wp = _wp(cfg)
-    unravel_row = None
-    if flat:
-        _unravel, unravel_row = X.worker_unravelers(wp)
-        wp = X.flatten_worker_tree(wp)
-    body = TJ.make_round_body(cfg, proto, _store(), sim=sim, flat=flat,
-                              unravel_row=unravel_row)
+    wp, spec = _flat(_wp(cfg))
+    body = TJ.make_round_body(cfg, proto, _store(), sim=sim, spec=spec)
     net0 = sim.init(jax.random.PRNGKey(4))
     carry0 = TJ.TrajCarry(jax.random.PRNGKey(5), wp, net0)
     c_loop, out_loop = TJ.run_per_round(body, carry0, 6)
@@ -198,31 +154,24 @@ def test_dynamic_scan_equals_loop(flat):
     assert out_scan["W"].shape == (6, W, W)
 
 
-@pytest.mark.parametrize("flat", [False, True], ids=["tree", "flat"])
-def test_fleet_scan_equals_loop(flat):
+def test_fleet_scan_equals_loop():
     from repro.fleet import FleetEngine
     cfg = _cfg()
     proto = _proto(channel_model="dynamic", scenario="iot_dense",
-                   replicates=R, flat_buffer=flat)
+                   replicates=R)
     fleet = FleetEngine(proto)
     wp1 = _wp(cfg)
-    wp = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), wp1)
-    unravel_row = None
-    if flat:
-        _unravel, unravel_row = X.worker_unravelers(wp, lead_axes=2)
-        wp = X.flatten_worker_tree(wp, lead_axes=2)
-    body = TJ.make_round_body(cfg, proto, _store(), fleet=fleet, flat=flat,
-                              unravel_row=unravel_row)
+    wp, spec = _flat(jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (R,) + a.shape), wp1),
+        lead_axes=2)
+    body = TJ.make_round_body(cfg, proto, _store(), fleet=fleet, spec=spec)
     net0 = fleet.init(jax.random.PRNGKey(6))
     carry0 = TJ.TrajCarry(jax.random.PRNGKey(7), wp, net0)
     c_loop, out_loop = TJ.run_per_round(body, carry0, 5)
     c_scan, out_scan = _run_chunked(body, carry0, (2, 2, 1))
-    # channel/W streams are pure PRNG functions — bitwise in EVERY config;
-    # params are bitwise on the tree path, ULP-close on the flat path
-    # (per-program FMA contraction of the vmapped dp_mix matmul)
-    assert_params = _assert_tree_ulp_close if flat else _assert_tree_equal
-    assert_params(c_loop.params, c_scan.params, "final params")
+    # channel/W streams are pure PRNG functions — bitwise; params are
+    # ULP-close (per-program FMA contraction of the vmapped dp_mix matmul)
+    _assert_tree_ulp_close(c_loop.params, c_scan.params, "final params")
     _assert_tree_equal(out_loop["chan"], out_scan["chan"], "chan trajectory")
     _assert_tree_equal(out_loop["W"], out_scan["W"], "W log")
     assert out_scan["chan"].h.shape == (5, R, W)
@@ -247,9 +196,10 @@ def test_chunk_partition_preserves_prng_stream(k):
     cfg = _cfg()
     proto = _proto(channel_model="dynamic", scenario="iot_dense")
     sim = proto.simulator()
-    body = TJ.make_round_body(cfg, proto, _store(), sim=sim)
+    wp, spec = _flat(_wp(cfg))
+    body = TJ.make_round_body(cfg, proto, _store(), sim=sim, spec=spec)
     net0 = sim.init(jax.random.PRNGKey(8))
-    carry0 = TJ.TrajCarry(jax.random.PRNGKey(9), _wp(cfg), net0)
+    carry0 = TJ.TrajCarry(jax.random.PRNGKey(9), wp, net0)
     T = 8
     ref_carry, ref_out = _run_chunked(body, carry0, (T,))
     partition = [k] * (T // k) + ([T % k] if T % k else [])
@@ -393,7 +343,7 @@ def test_store_from_batcher_roundtrip():
 def _flat_setup(proto_kw, fleet_engine=None):
     """Shared flat-buffer trajectory setup for the telemetry tests."""
     cfg = _cfg()
-    proto = _proto(flat_buffer=True, **proto_kw)
+    proto = _proto(**proto_kw)
     wp = _wp(cfg)
     lead = 1
     if fleet_engine is not None:
@@ -415,7 +365,7 @@ def test_static_telemetry_bitwise_invisible_and_consistent():
     cfg, proto, flat, unravel_row = _flat_setup({})
     tele = obs.TelemetrySpec()
     store = _store()
-    mk = lambda t: TJ.make_round_body(cfg, proto, store, flat=True,
+    mk = lambda t: TJ.make_round_body(cfg, proto, store,
                                       unravel_row=unravel_row, telemetry=t)
     key = jax.random.PRNGKey(11)
     T = 6
@@ -463,7 +413,7 @@ def test_telemetry_consensus_is_preround_params():
     from repro.obs import telemetry as tl
     cfg, proto, flat, unravel_row = _flat_setup({})
     tele = obs.TelemetrySpec()
-    body = TJ.make_round_body(cfg, proto, _store(), flat=True,
+    body = TJ.make_round_body(cfg, proto, _store(),
                               unravel_row=unravel_row, telemetry=tele)
     carry = TJ.TrajCarry(jax.random.PRNGKey(12), flat,
                          eps=obs.init_eps_moments())
@@ -491,7 +441,7 @@ def test_dynamic_telemetry_matches_host_recompute():
     sim = proto.simulator()
     tele = obs.TelemetrySpec()
     store = _store()
-    mk = lambda t: TJ.make_round_body(cfg, proto, store, sim=sim, flat=True,
+    mk = lambda t: TJ.make_round_body(cfg, proto, store, sim=sim,
                                       unravel_row=unravel_row, telemetry=t)
     net0 = sim.init(jax.random.PRNGKey(13))
     key = jax.random.PRNGKey(14)
@@ -556,7 +506,7 @@ def test_fleet_telemetry_shape_and_host_recompute():
     fleet = FleetEngine(proto)
     tele = obs.TelemetrySpec()
     mk = lambda t: TJ.make_round_body(cfg, proto, _store(), fleet=fleet,
-                                      flat=True, unravel_row=unravel_row,
+                                      unravel_row=unravel_row,
                                       telemetry=t)
     net0 = fleet.init(jax.random.PRNGKey(15))
     key = jax.random.PRNGKey(16)
@@ -603,7 +553,7 @@ def test_telemetry_field_subset_layout():
     tele = obs.TelemetrySpec(grad_norm=False, snr_db=False, epsilon=False)
     assert tele.fields == ("loss", "consensus", "deep_fade",
                            "participation")
-    body = TJ.make_round_body(cfg, proto, _store(), flat=True,
+    body = TJ.make_round_body(cfg, proto, _store(),
                               unravel_row=unravel_row, telemetry=tele)
     carry, out = TJ.ChunkRunner(body, donate=False).run(
         TJ.TrajCarry(jax.random.PRNGKey(17), flat), 3)
@@ -621,8 +571,8 @@ def test_lm_round_body_runs():
     store = LMStore.build(toks, W, 2, 8)
     proto = _proto()
     key = jax.random.PRNGKey(10)
-    wp = P.init_worker_params(key, cfg, W)
-    body = TJ.make_round_body(cfg, proto, store)
+    wp, spec = _flat(P.init_worker_params(key, cfg, W))
+    body = TJ.make_round_body(cfg, proto, store, spec=spec)
     runner = TJ.ChunkRunner(body, donate=False)
     carry, out = runner.run(TJ.TrajCarry(key, wp), 3)
     assert out["metrics"]["loss"].shape == (3,)
